@@ -929,8 +929,11 @@ def main(argv=None) -> int:
 
 def _run_serve(args) -> int:
     from photon_ml_tpu.telemetry import flight
-    from photon_ml_tpu.utils.jax_cache import enable_persistent_cache
-    enable_persistent_cache()
+    from photon_ml_tpu.utils.devices import device_memory
+    from photon_ml_tpu.utils.jax_cache import (CompileTimeTracker,
+                                               enable_persistent_cache)
+    compile_tracker = CompileTimeTracker().install()
+    cache_dir = enable_persistent_cache()
     t0 = time.perf_counter()
     service = _build_service(args)
     load_s = time.perf_counter() - t0
@@ -981,6 +984,12 @@ def _run_serve(args) -> int:
         "model_dir": args.model_dir,
         "model_version": service.model_version,
         "model_load_s": round(load_s, 3),
+        # the device the warmed tables live on, read off the arrays, and
+        # what loading + warm-up compiled (near zero on a warm cache)
+        "device": service.registry.scorer.device_summary(),
+        "device_memory": device_memory(),
+        "compile_s": round(compile_tracker.seconds, 2),
+        "compile_cache": cache_dir,
         "buckets": service.registry.scorer.bucket_sizes(),
         "updates_enabled": service.updater is not None,
         "health_enabled": service.health is not None,

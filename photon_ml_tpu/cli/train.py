@@ -178,7 +178,8 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--no-compile-cache", action="store_true",
                    help="disable the persistent XLA compilation cache (on "
                         "by default so repeat invocations skip compiles; "
-                        "cache dir: <repo>/.jax_cache or $PHOTON_JAX_CACHE)")
+                        "cache dir: $JAX_COMPILATION_CACHE_DIR, else "
+                        "<checkout>/.jax_cache)")
     p.add_argument("--model-format", default="npz",
                    choices=["npz", "avro", "reference"],
                    help="best-model output format; avro writes the "
@@ -404,6 +405,14 @@ def _load_dataset(path: str, task: str, args=None, train_dataset=None,
             "requires Avro training input; an npz GameDataset already "
             "carries its feature spaces")
     return load_game_dataset(path)
+
+
+def _model_arrays(model):
+    """Every device array of a GameModel's coordinates."""
+    import jax
+    return [v for m in model.coordinates.values()
+            for v in jax.tree_util.tree_leaves(vars(m))
+            if isinstance(v, jax.Array)]
 
 
 def main(argv=None) -> int:
@@ -768,11 +777,18 @@ def _run(args, log) -> int:
         # carried iterations + ConvergenceReason; the fit summary now
         # surfaces them instead of dropping them on the floor)
         solver_diag = best.descent.solver_diagnostics()
+        from photon_ml_tpu.utils.devices import device_memory, device_summary
         summary = {
             "task": args.task,
+            # the device the fit ran on, read off the trained model's own
+            # arrays (a caller that started this process as a child — one
+            # process per chip — cannot ask jax itself)
+            "device": device_summary(_model_arrays(best.model)),
+            "device_memory": device_memory(),
             "train_rows": train.num_rows,
             "ingest_s": round(ingest_s, 2),
             "num_configs": len(results),
+            "objective_history": [float(v) for v in best.objective_history],
             "final_objective": best.objective_history[-1],
             "validation": best.validation,
             "solver_iterations_total": best.descent.total_iterations(),
@@ -806,6 +822,11 @@ def _run(args, log) -> int:
             "host_blocked_s": round(
                 getattr(getattr(best.descent, "timings", None),
                         "host_blocked_total", lambda: 0.0)(), 3),
+            # the contiguous phase spans of the best fit (build, init,
+            # per-coordinate solve/score, validation, checkpoint)
+            "phase_timings_s": {
+                name: round(t, 3) for name, t in
+                getattr(best.descent, "timings", {}).items()},
             "compile_s": round(compile_tracker.seconds, 2),
             "compile_count": compile_tracker.count,
             "compile_cache": cache_dir,

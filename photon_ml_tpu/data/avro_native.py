@@ -20,13 +20,17 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
+import logging
 import os
 import subprocess
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
+from photon_ml_tpu import telemetry
 from photon_ml_tpu.data.avro_codec import iter_raw_blocks
+
+logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "native")
@@ -45,41 +49,71 @@ _PRIMITIVE_OPS = {"long": (OP_LONG, KIND_I64), "int": (OP_LONG, KIND_I64),
                   "bytes": (OP_STRING, KIND_STR)}
 
 _lib = None
-_lib_failed = False
+_lib_error: Optional[str] = None  # why the native decoder is unavailable
 
 
 def _load_lib():
-    """Compile (if stale) and load the shared library; None if unavailable."""
-    global _lib, _lib_failed
-    if _lib is not None or _lib_failed:
+    """Compile (if stale) and load the shared library; None if unavailable.
+    The library is a build product (ignored by git): a clean checkout
+    builds it from native/avro_decode.c on first use.  A failed build or
+    load is remembered in `_lib_error` and logged once — the pure-Python
+    codec is the stated fallback, never a silent one (`native_status()`,
+    and the `avro.decode.*` counters say which decoder ran)."""
+    global _lib, _lib_error
+    if _lib is not None or _lib_error is not None:
         return _lib
     try:
         if (not os.path.exists(_SO)
                 or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            subprocess.run(["cc", "-O3", "-shared", "-fPIC", _SRC, "-o", _SO],
-                           check=True, capture_output=True, timeout=120)
+            # build beside the target, then rename: concurrent first uses
+            # (pytest-xdist workers, CLI children) never load a half-written
+            # file
+            tmp = f"{_SO}.{os.getpid()}.tmp"
+            try:
+                subprocess.run(["cc", "-O3", "-shared", "-fPIC", _SRC,
+                                "-o", tmp], check=True, capture_output=True,
+                               timeout=120)
+                os.replace(tmp, _SO)
+            finally:
+                if os.path.exists(tmp):
+                    os.unlink(tmp)
         lib = ctypes.CDLL(_SO)
-        lib.avrodec_decode_block.restype = ctypes.c_int64
-        lib.avrodec_decode_block.argtypes = [
-            ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
-            ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
-            ctypes.c_void_p, ctypes.c_int32]
-        lib.avrodec_alloc_cols.restype = ctypes.c_void_p
-        lib.avrodec_alloc_cols.argtypes = [ctypes.c_int32,
-                                           ctypes.POINTER(ctypes.c_int32)]
-        lib.avrodec_free_cols.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-        for name, restype in (("avrodec_col_len", ctypes.c_int64),
-                              ("avrodec_col_blob_len", ctypes.c_int64),
-                              ("avrodec_col_i64", ctypes.POINTER(ctypes.c_int64)),
-                              ("avrodec_col_f64", ctypes.POINTER(ctypes.c_double)),
-                              ("avrodec_col_blob", ctypes.POINTER(ctypes.c_uint8))):
-            fn = getattr(lib, name)
-            fn.restype = restype
-            fn.argtypes = [ctypes.c_void_p, ctypes.c_int32]
-        _lib = lib
-    except Exception:
-        _lib_failed = True
+    except subprocess.CalledProcessError as e:
+        _lib_error = (f"cc failed (rc={e.returncode}): "
+                      f"{e.stderr.decode(errors='replace')[-500:]}")
+    except (OSError, subprocess.TimeoutExpired) as e:
+        _lib_error = f"{type(e).__name__}: {e}"
+    if _lib_error is not None:
+        logger.warning("native Avro decoder unavailable, using the Python "
+                       "codec: %s", _lib_error)
+        return None
+    lib.avrodec_decode_block.restype = ctypes.c_int64
+    lib.avrodec_decode_block.argtypes = [
+        ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+        ctypes.POINTER(ctypes.c_int32), ctypes.c_int64,
+        ctypes.c_void_p, ctypes.c_int32]
+    lib.avrodec_alloc_cols.restype = ctypes.c_void_p
+    lib.avrodec_alloc_cols.argtypes = [ctypes.c_int32,
+                                       ctypes.POINTER(ctypes.c_int32)]
+    lib.avrodec_free_cols.argtypes = [ctypes.c_void_p, ctypes.c_int32]
+    for name, restype in (("avrodec_col_len", ctypes.c_int64),
+                          ("avrodec_col_blob_len", ctypes.c_int64),
+                          ("avrodec_col_i64", ctypes.POINTER(ctypes.c_int64)),
+                          ("avrodec_col_f64", ctypes.POINTER(ctypes.c_double)),
+                          ("avrodec_col_blob", ctypes.POINTER(ctypes.c_uint8))):
+        fn = getattr(lib, name)
+        fn.restype = restype
+        fn.argtypes = [ctypes.c_void_p, ctypes.c_int32]
+    _lib = lib
     return _lib
+
+
+def native_status() -> Dict[str, Optional[str]]:
+    """{"decoder": "native" | "python", "reason": why not native} — builds
+    and loads the library if no read has tried yet."""
+    _load_lib()
+    return {"decoder": "native" if _lib is not None else "python",
+            "reason": _lib_error}
 
 
 @dataclasses.dataclass
@@ -336,13 +370,24 @@ def compile_schema(schema_json, decode_maps: bool = False
 
 def read_columnar(path: str, decode_maps: bool = False):
     """Decode a container file into columns, or None when the native path
-    is unavailable / the schema is unsupported (callers fall back)."""
+    is unavailable / the schema is unsupported (callers fall back to the
+    Python codec).  Which one ran is counted per file in the telemetry
+    registry (`avro.decode.native` / `avro.decode.python`)."""
+    cols = _read_columnar_native(path, decode_maps)
+    telemetry.counter("avro.decode.native" if cols is not None
+                      else "avro.decode.python").inc()
+    return cols
+
+
+def _read_columnar_native(path: str, decode_maps: bool):
     lib = _load_lib()
     if lib is None:
         return None
     schema_json, blocks = iter_raw_blocks(path)
     plan = compile_schema(schema_json, decode_maps=decode_maps)
     if plan is None:
+        logger.info("%s: schema shape outside the native decoder's "
+                    "support, using the Python codec", path)
         return None
 
     ncols = len(plan.columns)

@@ -257,8 +257,8 @@ def _csc_segment_sum(vals: jax.Array, rows: jax.Array, end: jax.Array,
 
     Formulated as gather -> multiply -> CHUNKED prefix-scan -> boundary
     gather — every op is a TPU-parallel primitive; the scatter-add this
-    replaces serializes on TPU (measured ~0.1% of HBM roofline, BENCH_r04
-    config 6).
+    replaces serializes on TPU (an earlier round's record put it near 0.1%
+    of the HBM roofline; not re-measured on this round's chip, ROADMAP S5).
 
     Chunking is a precision device, not a speed one: a single global
     cumsum accumulates ~eps*sqrt(nnz) rounding noise into every boundary

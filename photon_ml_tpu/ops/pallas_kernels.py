@@ -35,6 +35,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from photon_ml_tpu.ops.losses import PointwiseLoss
 
@@ -42,18 +44,8 @@ _TILE_ROWS = 2048
 _LANE = 128
 
 
-def available() -> bool:
-    try:
-        from jax.experimental import pallas as pl  # noqa: F401
-        from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-    except ImportError:  # pragma: no cover
-        return False
-    return True
-
-
 def _kernel(loss: PointwiseLoss, with_offsets: bool):
     def kernel(*refs):
-        from jax.experimental import pallas as pl
         if with_offsets:
             x_ref, y_ref, w_ref, o_ref, c_ref, val_ref, grad_ref = refs
         else:
@@ -113,9 +105,6 @@ def fused_value_and_gradient(
 
     Matches ops/aggregators.value_and_gradient for dense inputs (no
     normalization/mask arguments — the XLA path covers those)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     n, d = x.shape
     d_pad = -(-d // _LANE) * _LANE
     # adapt tile rows to width: the [T, d] tile plus copies must fit in the

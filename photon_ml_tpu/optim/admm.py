@@ -391,10 +391,17 @@ def make_init(loss: PointwiseLoss, has_l1: bool, ops: ADMMOperands,
     return jax.jit(init, static_argnums=(3,))(ops, w0, rho0, ceil)
 
 
+# The result type is one `dtype[dims]{layout}` or, where XLA's combiner
+# merged several reductions into one op, a tuple of them — possibly with
+# `/*index=N*/` comments between elements and, on TPU, tiled layouts such as
+# `{0:T(128)}`.  `-start` is the async form the TPU compiler emits (same
+# result type).
 _ALLREDUCE_RE = re.compile(
-    r"(?P<dtype>[a-z]+\d+)\[(?P<dims>[\d,]*)\][^ ]* all-reduce\("
+    r"= (?P<shape>\((?:[^()]|\([^()]*\))*\)|[a-z]+\d+\[[\d,]*\][^ ]*) "
+    r"all-reduce(?:-start)?\("
     r".*?replica_groups=(?P<groups>\{\{[^}]*(?:\},\{[^}]*)*\}\}|"
     r"\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?)")
+_ELEMENT_RE = re.compile(r"(?P<dtype>[a-z]+\d+)\[(?P<dims>[\d,]*)\]")
 
 _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s32": 4,
                 "u32": 4, "s64": 8, "u64": 8, "pred": 1}
@@ -438,20 +445,23 @@ def collective_summary(compiled_text: str, mesh) -> dict:
     data_groups = {tuple(int(v) for v in col) for col in grid.T}
     out = {"feature": [], "data": [], "global": [], "other": []}
     for m in _ALLREDUCE_RE.finditer(compiled_text):
-        dims = [int(t) for t in m.group("dims").split(",") if t]
-        nbytes = int(np.prod(dims or [1])) * _DTYPE_BYTES.get(
-            m.group("dtype"), 8)
         groups = {g for g in _decode_replica_groups(m.group("groups"))
                   if len(g) > 1}
-        entry = (len(dims), nbytes)
         if not groups:
             continue  # trivial single-device groups: no wire traffic
         if groups <= feature_groups:
-            out["feature"].append(entry)
+            lane = out["feature"]
         elif groups <= data_groups:
-            out["data"].append(entry)
+            lane = out["data"]
         elif len(next(iter(groups))) == grid.size:
-            out["global"].append(entry)
+            lane = out["global"]
         else:
-            out["other"].append(entry)
+            lane = out["other"]
+        # one entry per ELEMENT of a combined (tuple-shaped) all-reduce:
+        # the payload on the wire is the same as that many separate ops
+        for e in _ELEMENT_RE.finditer(m.group("shape")):
+            dims = [int(t) for t in e.group("dims").split(",") if t]
+            nbytes = int(np.prod(dims or [1])) * _DTYPE_BYTES.get(
+                e.group("dtype"), 8)
+            lane.append((len(dims), nbytes))
     return out

@@ -2,8 +2,9 @@
 
 The GAME outer loop re-perturbs every coordinate's problem on the next
 visit, so paying full-tolerance convergence on early visits is wasted work
-— BENCH_r05 measured a 398s cold factored-MF solve inside a 522s fit whose
-warm revisit cost 7.8s.  Running inner solves inexactly early and
+— an earlier round's chip record (since deleted; not re-measured on this
+round's chip, ROADMAP S3) showed a cold factored-MF solve taking three
+quarters of a fit whose warm revisit cost a fiftieth of it.  Running inner solves inexactly early and
 tightening geometrically toward the end is the standard cure (Trofimov &
 Genkin, arXiv:1611.02101; Snap ML's hierarchical local solvers,
 arXiv:1803.06333).

@@ -78,8 +78,8 @@ def principal_subspace_projection(w: jax.Array,
     Rows = the top-k right singular vectors of w (an [E, d] plain
     random-effect coefficient matrix): the directions per-entity effects
     ACTUALLY vary in, instead of the cold Gaussian start whose subspace the
-    first alternation must discover from noise (BENCH_r05: the cold first
-    MF solve was 398s of a 522s fit; warm revisits 7.8s).  The latent
+    first alternation must discover from noise (the cold first MF solve is
+    the cost ROADMAP S3 chases).  The latent
     factors stay zero, so the coordinate's initial score — and therefore
     the descent state — is unperturbed.  `fallback` (the existing Gaussian
     projection) fills rows beyond w's rank and takes over entirely for a

@@ -49,6 +49,7 @@ from photon_ml_tpu.models.game import (
 )
 from photon_ml_tpu.ops import losses as L
 from photon_ml_tpu.parallel.random_effect import score_by_entity
+from photon_ml_tpu.utils.devices import device_summary
 from photon_ml_tpu.utils.math import ceil_pow2
 
 
@@ -302,6 +303,10 @@ class CompiledScorer:
         if warmup:
             scorer.warmup()
         return scorer
+
+    def device_summary(self) -> Dict[str, object]:
+        """{platform, kind, count} of the devices holding the tables."""
+        return device_summary(self._tables)
 
     def bucket_sizes(self) -> List[int]:
         out, b = [], self.min_bucket

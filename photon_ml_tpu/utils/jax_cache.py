@@ -3,13 +3,14 @@
 The GAME product path compiles one program per (bucket shape, coordinate)
 pair; on a cold process that compile wall-clock dominates small fits.  The
 reference has no equivalent cost (JVM/Breeze interprets), so we keep the
-cache warm across processes with JAX's persistent compilation cache, stored
-inside the repo (the only writable project location).
+cache warm across processes with JAX's persistent compilation cache: at
+$JAX_COMPILATION_CACHE_DIR when that is set, else `<checkout>/.jax_cache`.
 """
 from __future__ import annotations
 
 import os
 
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 _DEFAULT = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), ".jax_cache")
 
@@ -37,19 +38,21 @@ class CompileTimeTracker:
         return self
 
 
-def enable_persistent_cache(path: str | None = None) -> str:
-    """Idempotent; returns the cache directory in use."""
+def enable_persistent_cache() -> str:
+    """Idempotent; returns the cache directory in use.
+
+    One rule, shared with tests/conftest.py: where JAX_COMPILATION_CACHE_DIR
+    is set JAX has already read it and no directory is set in code;
+    otherwise the cache lives at the fixed path `<checkout>/.jax_cache` (the
+    path is part of the cache key, so a directory that moves never hits)."""
     import jax
 
-    path = path or os.environ.get("PHOTON_JAX_CACHE", _DEFAULT)
-    os.makedirs(path, exist_ok=True)
-    try:
-        jax.config.update("jax_compilation_cache_dir", path)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        # persist EVERY program: the GAME path compiles dozens of small
-        # per-bucket programs whose compile times individually sit under
-        # any threshold but sum to the cold-start cost we want gone
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:  # older jax without these flags: cache is best-effort
-        pass
-    return path
+    if not os.environ.get(CACHE_DIR_ENV):
+        os.makedirs(_DEFAULT, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # persist EVERY program: the GAME path compiles dozens of small
+    # per-bucket programs whose compile times individually sit under
+    # any threshold but sum to the cold-start cost we want gone
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir

@@ -303,6 +303,33 @@ def test_one_feature_axis_reduction_per_iteration(rng):
     assert all(e[0] == 0 for e in summary["global"]), summary
 
 
+_HLO_GROUPS = "replica_groups=[2,4]<=[8], use_global_device_ids=true"
+
+
+@pytest.mark.parametrize("line,expected", [
+    # one op, one array (what jax < 0.9 emitted for the margin psum)
+    ("%ar = f64[128]{0} all-reduce(%x), channel_id=1, " + _HLO_GROUPS,
+     [(1, 1024)]),
+    # the combiner merged the [n_local] vector with a scalar: every
+    # element counts, not only the last one
+    ("%ar.10 = (f64[128]{0}, f64[]) all-reduce(%x, %y), channel_id=3, "
+     + _HLO_GROUPS, [(1, 1024), (0, 8)]),
+    # index comments and TPU tiled layouts inside the tuple; async form
+    ("%ar.11 = (f32[8,4]{1,0:T(8,128)}, f32[]{:T(128)}, /*index=2*/"
+     "bf16[16]{0:T(128)(2,1)}) all-reduce-start(%a, %b, %c), channel_id=5, "
+     + _HLO_GROUPS, [(2, 128), (0, 4), (1, 32)]),
+    # a tuple-shaped op that is no all-reduce is not counted
+    ("%f = (f64[128]{0}, f64[]) fusion(%x, %y), kind=kLoop, calls=%c",
+     []),
+], ids=["single", "tuple", "tuple-tiled-async", "tuple-not-allreduce"])
+def test_collective_summary_reads_combined_all_reduces(line, expected):
+    """A hand-written HLO line per result-type form: a combined
+    (tuple-shaped) all-reduce is read element by element."""
+    summary = collective_summary(line, _mesh(2, 4))
+    assert summary["feature"] == expected, summary
+    assert not (summary["data"] or summary["global"] or summary["other"])
+
+
 # ---------------------------------------------------------------------------
 # make_mesh feature axis + shardings (satellite: direct unit tests)
 # ---------------------------------------------------------------------------

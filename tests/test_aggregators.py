@@ -31,7 +31,7 @@ def _norm_ctx(x, kind, intercept=None):
     )
 
 
-@pytest.mark.parametrize("loss,task", LOSS_TASK, ids=lambda p: str(p))
+@pytest.mark.parametrize("loss,task", LOSS_TASK, ids=lambda p: getattr(p, "name", str(p)))
 def test_value_and_gradient_matches_autodiff(loss, task, rng):
     x, y, w, _ = make_glm_data(rng, n=128, d=7, task=task, weight_range=(0.5, 2.0))
     offsets = rng.normal(size=128) * 0.3
@@ -47,7 +47,7 @@ def test_value_and_gradient_matches_autodiff(loss, task, rng):
 
 
 @pytest.mark.parametrize("loss,task", [p for p in LOSS_TASK if p[0].twice_differentiable],
-                         ids=lambda p: str(p))
+                         ids=lambda p: getattr(p, "name", str(p)))
 def test_hessian_vector_matches_autodiff(loss, task, rng):
     x, y, w, _ = make_glm_data(rng, n=96, d=6, task=task, weight_range=(0.5, 2.0))
     c = jnp.asarray(rng.normal(size=6) * 0.5)

@@ -18,6 +18,45 @@ from photon_ml_tpu.data.avro_native import compile_schema, read_columnar
 from photon_ml_tpu.data.index_map import build_index_map
 
 
+def test_native_build_failure_is_recorded_not_silent(tmp_path, monkeypatch,
+                                                     caplog):
+    """A decoder that cannot be built falls back to the Python codec — and
+    says so: `native_status()` carries the reason, a warning is logged, and
+    the per-file counter names the decoder that ran.  The build itself goes
+    to a clean path (the .so is a build product, never a committed file)."""
+    from photon_ml_tpu import telemetry
+    from photon_ml_tpu.data import avro_native
+
+    p = str(tmp_path / "tricky.avro")
+    _write_tricky(p)
+    monkeypatch.setattr(avro_native, "_lib", None)
+    monkeypatch.setattr(avro_native, "_lib_error", None)
+    monkeypatch.setattr(avro_native, "_SO", str(tmp_path / "libavrodec.so"))
+
+    # a clean target builds from the committed C source and decodes
+    native0 = telemetry.counter("avro.decode.native").value
+    assert avro_native.native_status() == {"decoder": "native",
+                                           "reason": None}
+    assert read_columnar(p) is not None
+    assert telemetry.counter("avro.decode.native").value == native0 + 1
+
+    # a source that does not compile: stated fallback, with the reason
+    broken = tmp_path / "broken.c"
+    broken.write_text("this is not C\n")
+    monkeypatch.setattr(avro_native, "_lib", None)
+    monkeypatch.setattr(avro_native, "_lib_error", None)
+    monkeypatch.setattr(avro_native, "_SRC", str(broken))
+    monkeypatch.setattr(avro_native, "_SO", str(tmp_path / "libbroken.so"))
+    python0 = telemetry.counter("avro.decode.python").value
+    with caplog.at_level("WARNING", logger=avro_native.logger.name):
+        assert read_columnar(p) is None
+    status = avro_native.native_status()
+    assert status["decoder"] == "python" and "cc failed" in status["reason"]
+    assert "native Avro decoder unavailable" in caplog.text
+    assert telemetry.counter("avro.decode.python").value == python0 + 1
+    assert not (tmp_path / "libbroken.so").exists()
+
+
 def _write_tricky(path, n=60, seed=3):
     """Records exercising null unions, empty feature lists, and both codecs'
     varint edge cases (negative longs via zigzag doubles etc.)."""

@@ -225,7 +225,7 @@ def test_faults_smoke(tmp_path, monkeypatch):
     a poisoned coordinate quarantined and re-run — cannot rot without
     failing the normal test run.  Every leg is parity-gated against its
     fault-free trajectory at the 1e-4 gate."""
-    monkeypatch.setenv("PHOTON_JAX_CACHE", str(tmp_path / "jaxcache"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jaxcache"))
     bench = _load_bench()
     out = tmp_path / "BENCH_faults.json"
     result = bench.faults_bench(str(out), smoke=True)
@@ -765,7 +765,8 @@ def test_max_wall_truncates_and_exits_cleanly(tmp_path, monkeypatch):
     harness timeout killing the run at rc=124 with the JSON lost."""
     bench = _load_bench()
     monkeypatch.chdir(tmp_path)
-    result = bench.main(max_wall=0.0)
+    result = bench.main(max_wall=0.0, platform="cpu")  # CPU on purpose
+    assert result["detail"]["device"]["platform"] == "cpu"
     assert result["detail"]["truncated"]          # every config skipped
     assert result["detail"]["configs"] == {}
     assert result["detail"]["max_wall_s"] == 0.0
@@ -776,6 +777,24 @@ def test_max_wall_truncates_and_exits_cleanly(tmp_path, monkeypatch):
     r = bench.inexact_bench(str(out), smoke=False, max_wall=0.0)
     assert r["detail"]["truncated"]
     assert r["detail"]["entries"] == []
+
+
+def test_default_run_refuses_a_device_it_was_not_asked_for(tmp_path,
+                                                           monkeypatch):
+    """The default bench run times the attached TPU: on a machine where
+    JAX finds only the CPU it refuses (no silent fallback) unless the CPU
+    is asked for on purpose, and the HBM roofline share errors on a
+    device kind that has no recorded peak instead of dividing by the
+    v5e's."""
+    import pytest
+    bench = _load_bench()
+    monkeypatch.chdir(tmp_path)
+    with pytest.raises(SystemExit, match="pass --cpu"):
+        bench.main(max_wall=0.0)
+    assert not (tmp_path / "BENCH.json").exists()
+    with pytest.raises(KeyError, match="no HBM peak recorded"):
+        bench._hbm_fields(1.0)
+    assert bench.HBM_PEAK_GBPS == {"TPU v5 lite": 819.0}
 
 
 def test_bench_smoke_writes_no_repo_state(tmp_path, monkeypatch):

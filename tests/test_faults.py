@@ -526,7 +526,7 @@ def test_exit_preempted_is_distinct():
 
 def _run_child(tmp_path, ckpt=None, plan=None, expect_kill=False):
     env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_ENABLE_X64="1",
-               PHOTON_JAX_CACHE=str(tmp_path / "jaxcache"))
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jaxcache"))
     env.pop("XLA_FLAGS", None)
     env.pop("PHOTON_FAULT_PLAN", None)
     if plan is not None:

@@ -11,10 +11,7 @@ import pytest
 import jax.numpy as jnp
 
 from photon_ml_tpu.ops import LOGISTIC, POISSON, SQUARED, aggregators
-from photon_ml_tpu.ops.pallas_kernels import available, fused_value_and_gradient
-
-pytestmark = pytest.mark.skipif(not available(),
-                                reason="jax.experimental.pallas unavailable")
+from photon_ml_tpu.ops.pallas_kernels import fused_value_and_gradient
 
 
 @pytest.mark.parametrize("loss", [LOGISTIC, SQUARED, POISSON],

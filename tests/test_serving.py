@@ -32,6 +32,7 @@ from photon_ml_tpu.serving import (BatcherConfig, CompiledScorer,
 from photon_ml_tpu.utils.events import (EventEmitter, EventListener,
                                         ModelSwapEvent, ScoringBatchEvent)
 from photon_ml_tpu.utils.math import ceil_pow2
+from tests.test_io_cli import child_env
 
 D_G, D_U, N_ENT = 6, 4, 20
 
@@ -443,11 +444,8 @@ def test_cli_score_predict_avro_is_an_error(tmp_path):
 # -- cli.serve end-to-end --------------------------------------------------
 
 def _run_cli(module, argv):
-    env = {"PYTHONPATH": "/root/repo", "PATH": "/usr/bin:/bin:/usr/local/bin",
-           "JAX_PLATFORMS": "cpu", "HOME": "/root",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
     return subprocess.run([sys.executable, "-m", module] + argv,
-                          capture_output=True, text=True, env=env,
+                          capture_output=True, text=True, env=child_env(),
                           timeout=420)
 
 
@@ -495,9 +493,7 @@ def test_cli_serve_http_roundtrip(served_model):
     import urllib.request
 
     model_dir, data_p, _ = served_model
-    env = {"PYTHONPATH": "/root/repo", "PATH": "/usr/bin:/bin:/usr/local/bin",
-           "JAX_PLATFORMS": "cpu", "HOME": "/root",
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
+    env = child_env()
     proc = subprocess.Popen(
         [sys.executable, "-m", "photon_ml_tpu.cli.serve",
          "--model-dir", model_dir, "--port", "0", "--max-batch", "32",

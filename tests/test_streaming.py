@@ -195,15 +195,15 @@ def test_chunked_rejects_sparse(rng):
 # host-stepped solvers: parity with the resident lax.while_loop solvers
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("opt,reg,weight", [
-    (OptimizerConfig(max_iterations=100, tolerance=1e-9), L2, 1.0),
+@pytest.mark.parametrize("opt,reg,weight,count_slack", [
+    (OptimizerConfig(max_iterations=100, tolerance=1e-9), L2, 1.0, 0),
     (OptimizerConfig(optimizer=OptimizerType.TRON, max_iterations=30,
-                     tolerance=1e-9), L2, 1.0),
+                     tolerance=1e-9), L2, 1.0, 1),
     (OptimizerConfig(max_iterations=150, tolerance=1e-10),
      RegularizationContext(RegularizationType.ELASTIC_NET,
-                           elastic_net_alpha=0.5), 0.1),
+                           elastic_net_alpha=0.5), 0.1, 1),
 ])
-def test_solve_streamed_matches_resident(rng, opt, reg, weight):
+def test_solve_streamed_matches_resident(rng, opt, reg, weight, count_slack):
     x, y, _, _ = _problem(rng)
     d = x.shape[1]
     plan = ChunkPlan.build(len(y), chunk_rows=1024)
@@ -212,15 +212,24 @@ def test_solve_streamed_matches_resident(rng, opt, reg, weight):
     rs = solve(robj, jnp.zeros(d), opt, reg, weight)
     ss = solve_streamed(cobj, jnp.zeros(d), opt, reg, weight)
     # identical iteration trajectory in f64 (same algorithm, same
-    # constants; the streamed oracle differs only by summation order)
-    assert int(ss.iterations) == int(rs.iterations)
+    # constants; the streamed oracle differs only by summation order).
+    # `count_slack`: the TRON and OWLQN cases run at tolerance 1e-9/1e-10
+    # until the objective sits at its float64 resolution (the two value
+    # histories differ by exactly one ulp, 2.3e-13 on 1.2e3, from the
+    # fifth iteration on).  There a trust-region accept/reject and a
+    # line-search backtrack are decided by the sign of a sub-ulp
+    # difference, which chunked vs single-sum order flips (under jax
+    # 0.9.0's XLA:CPU reduction order it does): TRON stops after 15 vs 14
+    # iterations (hv 20 vs 19), OWLQN takes 15 vs 14 evaluations.  The
+    # counts may differ by that one step; values and coefficients may not.
+    assert abs(int(ss.iterations) - int(rs.iterations)) <= count_slack
     np.testing.assert_allclose(float(ss.value), float(rs.value), rtol=1e-9)
     np.testing.assert_allclose(np.asarray(ss.x), np.asarray(rs.x),
                                rtol=1e-6, atol=1e-9)
     if rs.fg_count is not None:
-        assert int(ss.fg_count) == int(rs.fg_count)
+        assert abs(int(ss.fg_count) - int(rs.fg_count)) <= count_slack
     if rs.hv_count is not None:
-        assert int(ss.hv_count) == int(rs.hv_count)
+        assert abs(int(ss.hv_count) - int(rs.hv_count)) <= count_slack
 
 
 def test_solve_streamed_box_constraints(rng):

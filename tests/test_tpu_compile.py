@@ -1,0 +1,239 @@
+"""The main path's programs compile for a TPU v5e, at real shapes.
+
+No chip is attached here; the TPU's compiler is.  It compiles for a chip
+that is described (`v5e:2x2`), and raises what the chip's compiler would
+raise: an unaligned slice, a kernel over its fast-memory budget, a program
+that does not fit 16 GB.  Nothing runs, so these tests say nothing about
+results or times — chip_smoke.py does that on the chip.
+
+What is compiled is what `python chip_smoke.py` runs (the GLMix fit through
+cli.train and its model through cli.serve) plus BASELINE config 1's solve
+and the Pallas kernel: the jitted callables the product itself builds,
+lowered from `jax.ShapeDtypeStruct`s.  The shapes of the GLMix fit were read
+off the real fit (seed 11: 950,051 training rows; per-user buckets E x S of
+3663 x 512, 2232 x 64, 141 x 8, 2 x 1).
+
+Rules of this file (on-chip-measurement guide, section 2): the topology is
+described inside a module-scoped fixture — never at import, never in
+conftest.py, not autouse — everything compiles in the test's own process,
+and these tests stay in this one file: a process keeps the TPU library, and
+its lock, until it exits.
+"""
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from photon_ml_tpu.ops import LOGISTIC
+from photon_ml_tpu.ops.objective import GLMObjective
+from photon_ml_tpu.optim import (OptimizerConfig, OptimizerType,
+                                 RegularizationContext, RegularizationType)
+from photon_ml_tpu.optim.admm import collective_summary
+from photon_ml_tpu.parallel.mesh import DATA_AXIS, FEATURE_AXIS
+
+F32 = jnp.float32
+L2 = RegularizationContext(RegularizationType.L2)
+GLMIX_ROWS, D_GLOBAL, D_USER, USERS = 950_051, 31, 19, 6040
+CONFIG1_SHAPE = (1_643_520, 124)
+V5E_HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no compiler logs
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache out of it.
+    # The product runs float32 (no --x64), so these compiles do too.
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            yield topo
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache_was_on)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_float32(compiled):
+    """float64 must not leak in from host defaults: a TPU has no f64 unit
+    and the product path is float32 end to end."""
+    assert "f64[" not in compiled.as_text()
+
+
+def _fits(compiled) -> int:
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES, m
+    return m.argument_size_in_bytes
+
+
+def _trainer_objective(n, d, rows, replicated):
+    """The objective FixedEffectCoordinate.update hands fit_fixed_effect on
+    the mesh path (no weights or normalization in the GLMix fit; offsets
+    are the other coordinates' scores, the mask marks real rows)."""
+    return GLMObjective(LOGISTIC, _sds((n, d), F32, rows(2)),
+                        _sds((n,), F32, rows(1)), weights=None,
+                        offsets=_sds((n,), F32, rows(1)),
+                        mask=_sds((n,), F32, rows(1)), norm=None,
+                        l2_weight=0.0), \
+        _sds((d,), F32, replicated), _sds((), F32, replicated)
+
+
+@pytest.mark.parametrize("optimizer", [OptimizerType.LBFGS,
+                                       OptimizerType.TRON],
+                         ids=lambda o: o.value)
+@pytest.mark.parametrize("shape", [CONFIG1_SHAPE, (GLMIX_ROWS, D_GLOBAL)],
+                         ids=["config1-1643520x124", "glmix-950051x31"])
+def test_fixed_effect_solve_compiles(one_chip, shape, optimizer):
+    """The FE solve the trainer jits (parallel/fixed_effect._cached_solver),
+    LBFGS and TRON, at config 1's a1a x 1024 shape and the GLMix global
+    shard."""
+    from photon_ml_tpu.parallel.fixed_effect import _cached_solver
+    obj, x0, lam = _trainer_objective(*shape, lambda ndim: one_chip, one_chip)
+    cfg = OptimizerConfig(optimizer=optimizer, max_iterations=100)
+    compiled = _cached_solver(cfg, L2).lower(obj, x0, lam).compile()
+    _assert_float32(compiled)
+    args = _fits(compiled)
+    n, d = shape
+    assert args >= n * d * 4          # the design matrix is an argument
+
+
+@pytest.mark.parametrize("entities,samples",
+                         [(3663, 512), (2232, 64), (141, 8), (2, 1)])
+def test_random_effect_bucket_solve_compiles(one_chip, entities, samples):
+    """The vmapped per-entity solver (parallel/random_effect.
+    _cached_batched_solver) at every S-bucket of the GLMix fit: rows capped
+    at active_data_upper_bound=512, per-user width 19."""
+    from photon_ml_tpu.parallel.random_effect import _cached_batched_solver
+    E, S, d = entities, samples, D_USER
+    cells = _sds((E, S), F32, one_chip)
+    solver = _cached_batched_solver(
+        LOGISTIC, OptimizerConfig(max_iterations=100), L2, True, True)
+    compiled = solver.lower(
+        _sds((E, S, d), F32, one_chip), cells, cells, cells, cells,
+        _sds((E, d), F32, one_chip), _sds((), F32, one_chip), None).compile()
+    _assert_float32(compiled)
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("bucket", [8, 1024], ids=["smallest", "largest"])
+def test_scorer_bucket_program_compiles(one_chip, bucket):
+    """The serving scorer's one fused program per bucket (serving/scorer.
+    CompiledScorer._program) for the GLMix model: FE matvec + per-user
+    gather-dot, at the smallest and the largest default bucket."""
+    from photon_ml_tpu.models.coefficients import Coefficients
+    from photon_ml_tpu.models.game import (FixedEffectModel, GameModel,
+                                           RandomEffectModel)
+    from photon_ml_tpu.models.glm import model_for_task
+    from photon_ml_tpu.serving.scorer import CompiledScorer
+    task = "logistic_regression"
+    model = GameModel({
+        "fixed": FixedEffectModel(model_for_task(task, Coefficients(
+            jnp.zeros(D_GLOBAL, F32))), "global"),
+        "perUser": RandomEffectModel(
+            "userId", "per_user", task, jnp.zeros((USERS, D_USER), F32),
+            np.arange(USERS).astype(str).astype(object), None, D_USER),
+    }, task)
+    scorer = CompiledScorer(model)
+    assert scorer.bucket_sizes()[0] == 8 and scorer.bucket_sizes()[-1] == 1024
+    tables = tuple(_sds(t.shape, t.dtype, one_chip) for t in scorer._tables)
+    assert [t.shape for t in tables] == [(D_GLOBAL,), (USERS, D_USER)]
+    xs = {"global": _sds((bucket, D_GLOBAL), F32, one_chip),
+          "per_user": _sds((bucket, D_USER), F32, one_chip)}
+    lanes = {"perUser": _sds((bucket,), jnp.int32, one_chip)}
+    compiled = scorer._program.lower(tables, xs, lanes).compile()
+    _assert_float32(compiled)
+    _fits(compiled)
+
+
+@pytest.mark.parametrize("n,d", [CONFIG1_SHAPE, (200_000, 2048), (700, 37)],
+                         ids=["1643520x124", "200000x2048",
+                              "unaligned-700x37"])
+def test_pallas_fused_value_and_gradient_compiles(one_chip, n, d):
+    """ops/pallas_kernels.fused_value_and_gradient as a real Mosaic kernel
+    (interpret=False) at the two shapes its docstring quotes and at the
+    unaligned shape its interpret-mode test uses."""
+    from photon_ml_tpu.ops.pallas_kernels import fused_value_and_gradient
+    row = _sds((n,), F32, one_chip)
+    compiled = fused_value_and_gradient.lower(
+        LOGISTIC, _sds((n, d), F32, one_chip), row, _sds((d,), F32, one_chip),
+        row, row, False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _assert_float32(compiled)
+    _fits(compiled)
+
+
+def test_fixed_effect_solve_shards_over_four_chips(topo, one_chip):
+    """`chip_smoke.py --multichip`'s program: the GLMix FE solve with rows
+    sharded over a data=4 mesh of the 2x2 chips.  Per device the arguments
+    are about a quarter of the one-chip program's, and the only collectives
+    are data-axis all-reduces no larger than the [d] gradient."""
+    from photon_ml_tpu.parallel.fixed_effect import _cached_solver
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1),
+                (DATA_AXIS, FEATURE_AXIS))
+    rows = lambda ndim: NamedSharding(
+        mesh, P(DATA_AXIS, *([None] * (ndim - 1))))
+    n = GLMIX_ROWS + (-GLMIX_ROWS) % 4      # the residency layer pads
+    solver = _cached_solver(OptimizerConfig(max_iterations=100), L2)
+    with mesh:
+        sharded = solver.lower(*_trainer_objective(
+            n, D_GLOBAL, rows, NamedSharding(mesh, P()))).compile()
+    single = solver.lower(*_trainer_objective(
+        n, D_GLOBAL, lambda ndim: one_chip, one_chip)).compile()
+    _assert_float32(sharded)
+    share = _fits(sharded) / _fits(single)
+    assert 0.24 <= share <= 0.26, share
+
+    summary = collective_summary(sharded.as_text(), mesh)
+    assert summary["data"], summary
+    assert not (summary["feature"] or summary["other"]), summary
+    assert max(nbytes for _, nbytes in summary["data"]) <= D_GLOBAL * 4
+    assert "all-reduce" not in single.as_text()
+
+
+def test_random_effect_bucket_solve_shards_over_four_chips(topo):
+    """The other program of the --multichip fit: one S-bucket's vmapped
+    solves with the ENTITY axis sharded over the data=4 mesh.  Entities are
+    independent, so the program needs no collective at all."""
+    from photon_ml_tpu.parallel.random_effect import _cached_batched_solver
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1),
+                (DATA_AXIS, FEATURE_AXIS))
+    E, S, d = 2232, 64, D_USER
+    lanes = lambda ndim: NamedSharding(
+        mesh, P(DATA_AXIS, *([None] * (ndim - 1))))
+    cells = _sds((E, S), F32, lanes(2))
+    scalar = _sds((), F32, NamedSharding(mesh, P()))
+    solver = _cached_batched_solver(
+        LOGISTIC, OptimizerConfig(max_iterations=100), L2, True, True)
+    with mesh:
+        compiled = solver.lower(
+            _sds((E, S, d), F32, lanes(3)), cells, cells, cells, cells,
+            _sds((E, d), F32, lanes(2)), scalar, None).compile()
+    _assert_float32(compiled)
+    assert _fits(compiled) <= 0.3 * (E * S * (d + 4) + E * d) * 4
+    summary = collective_summary(compiled.as_text(), mesh)
+    assert not any(e for lane in summary.values() for e in lane
+                   if e[0] >= 1), summary
