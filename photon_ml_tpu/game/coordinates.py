@@ -56,6 +56,7 @@ from photon_ml_tpu.parallel.fixed_effect import (
 from photon_ml_tpu.parallel.random_effect import (
     fit_random_effects, score_by_entity,
 )
+from photon_ml_tpu.telemetry import annotate
 
 logger = logging.getLogger(__name__)
 
@@ -467,11 +468,14 @@ class FixedEffectCoordinate:
                 # the solver donates x0 (in-place buffer reuse); the model's
                 # live coefficients may still be referenced by best-model /
                 # checkpoint snapshots, so donate a copy, never the original
-                x0 = jnp.array(x0, copy=True)
-            res = _cached_solver(opt.optimizer, opt.regularization,
-                                 donate=True)(
-                obj, x0, jnp.asarray(opt.regularization_weight, self.x.dtype),
-                budget)
+                with annotate("fe/stage"):
+                    x0 = jnp.array(x0, copy=True)
+            with annotate("fe/dispatch"):
+                res = _cached_solver(opt.optimizer, opt.regularization,
+                                     donate=True)(
+                    obj, x0,
+                    jnp.asarray(opt.regularization_weight, self.x.dtype),
+                    budget)
         c = res.x
         if self.norm is not None:
             c = self.norm.model_to_original_space(c)
@@ -685,21 +689,24 @@ class RandomEffectCoordinate(_EntityCoordinateBase):
             outer_iteration, num_outer_iterations, opt.optimizer))
         results = []
         for bucket in self.red.buckets:
-            blocks = bucket.with_offsets_from_flat(offsets)
-            lo = bucket.lane_start
-            x0 = model.coefficients[lo: lo + bucket.num_entities]
-            if x0 is model.coefficients:
-                # a full-extent slice is returned as-is by jnp (single
-                # bucket spanning every lane): donating it would consume
-                # the model's live buffer, still referenced by best-model /
-                # checkpoint snapshots — donate a copy instead
-                x0 = jnp.array(x0, copy=True)
-            res_b = fit_random_effects(
-                blocks, self.loss, self.mesh, x0=x0,
-                config=opt.optimizer, reg=opt.regularization,
-                reg_weight=opt.regularization_weight, donate_buffers=True,
-                budget=budget,
-                cache_key=(*self._mesh_key(), bucket.lane_start))
+            with annotate("re/offsets"):
+                blocks = bucket.with_offsets_from_flat(offsets)
+            with annotate("re/x0"):
+                lo = bucket.lane_start
+                x0 = model.coefficients[lo: lo + bucket.num_entities]
+                if x0 is model.coefficients:
+                    # a full-extent slice is returned as-is by jnp (single
+                    # bucket spanning every lane): donating it would consume
+                    # the model's live buffer, still referenced by best-model
+                    # / checkpoint snapshots — donate a copy instead
+                    x0 = jnp.array(x0, copy=True)
+            with annotate("re/solve_call"):
+                res_b = fit_random_effects(
+                    blocks, self.loss, self.mesh, x0=x0,
+                    config=opt.optimizer, reg=opt.regularization,
+                    reg_weight=opt.regularization_weight,
+                    donate_buffers=True, budget=budget,
+                    cache_key=(*self._mesh_key(), bucket.lane_start))
             results.append(res_b)
         from photon_ml_tpu.parallel.mesh import concat_rows_safe
         res = (results[0] if len(results) == 1 else jax.tree_util.tree_map(

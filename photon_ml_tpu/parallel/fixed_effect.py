@@ -36,6 +36,7 @@ from photon_ml_tpu.ops import GLMObjective
 from photon_ml_tpu.optim import OptimizerConfig, RegularizationContext, SolveResult, solve
 from photon_ml_tpu.optim.admm import ADMMConfig, ADMMOperands, admm_solve
 from photon_ml_tpu.parallel.mesh import DATA_AXIS, FEATURE_AXIS, data_sharding, replicated
+from photon_ml_tpu.telemetry import annotate
 
 
 def pad_batch_to_mesh(objective: GLMObjective, mesh: Mesh) -> GLMObjective:
@@ -148,11 +149,17 @@ def _cached_solver(config: OptimizerConfig, reg: RegularizationContext,
     `budget` (optim.schedule.SolveBudget) rides in as a TRACED operand:
     one program serves every (iteration cap, tolerance) an inexactness
     schedule produces.  budget=None traces the static-config variant — a
-    separate cache entry, not a per-budget retrace."""
-    return jax.jit(
-        lambda obj, x0, lam, budget=None: solve(obj, x0, config, reg, lam,
-                                                budget=budget),
-        donate_argnums=(1,) if donate else ())
+    separate cache entry, not a per-budget retrace.
+
+    The function's name is the program's: the XLA module is `jit_fe_solve`,
+    which is how a profiler trace tells this layer's device time from
+    every other program's (a module name survives the persistent compile
+    cache; a `jax.named_scope` inside the program does not)."""
+
+    def fe_solve(obj, x0, lam, budget=None):
+        return solve(obj, x0, config, reg, lam, budget=budget)
+
+    return jax.jit(fe_solve, donate_argnums=(1,) if donate else ())
 
 
 def fit_fixed_effect(
@@ -174,18 +181,19 @@ def fit_fixed_effect(
     ONCE per coordinate, so a warm visit moves only offsets and x0.
     Without it (standalone callers) the legacy per-call `shard_objective`
     runs."""
-    if residency_key is not None:
-        from photon_ml_tpu.parallel.mesh_residency import default_residency
-        sharded_obj = stage_objective(objective, mesh, residency_key)
-        x0 = default_residency().stage_update(
-            mesh, x0, spec="feature" if shard_features else "replicated",
-            key=residency_key, field="x0")
-    else:
-        sharded_obj = shard_objective(objective, mesh)
-        coef_sharding = (NamedSharding(mesh, P(FEATURE_AXIS))
-                         if shard_features else replicated(mesh))
-        x0 = jax.device_put(x0, coef_sharding)
-    with mesh:
+    with annotate("fe/stage"):
+        if residency_key is not None:
+            from photon_ml_tpu.parallel.mesh_residency import default_residency
+            sharded_obj = stage_objective(objective, mesh, residency_key)
+            x0 = default_residency().stage_update(
+                mesh, x0, spec="feature" if shard_features else "replicated",
+                key=residency_key, field="x0")
+        else:
+            sharded_obj = shard_objective(objective, mesh)
+            coef_sharding = (NamedSharding(mesh, P(FEATURE_AXIS))
+                             if shard_features else replicated(mesh))
+            x0 = jax.device_put(x0, coef_sharding)
+    with mesh, annotate("fe/dispatch"):
         return _cached_solver(config, reg)(sharded_obj, x0,
                                            jnp.asarray(reg_weight, x0.dtype),
                                            budget)

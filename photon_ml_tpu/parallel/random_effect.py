@@ -32,6 +32,7 @@ from jax.sharding import Mesh
 from photon_ml_tpu.ops import GLMObjective
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim import OptimizerConfig, RegularizationContext, SolveResult, solve
+from photon_ml_tpu.telemetry import annotate
 
 
 @jax.tree_util.register_pytree_node_class
@@ -96,18 +97,22 @@ def _cached_batched_solver(loss: PointwiseLoss, config: OptimizerConfig,
 
     The solve `budget` is an UNMAPPED traced operand (one cap/tolerance
     shared by every vmapped entity solve, like the lambda), so a
-    per-outer-iteration budget schedule reuses this one compiled program."""
+    per-outer-iteration budget schedule reuses this one compiled program.
+
+    The jitted function's name is the program's: the XLA module is
+    `jit_re_bucket_solve`, which is how a profiler trace tells this layer's
+    device time from every other program's (see `_cached_solver`)."""
 
     def solve_one(x, labels, mask, weights, offsets, x0_e, lam, budget):
         obj = GLMObjective(loss, x, labels, weights=weights, offsets=offsets,
                            mask=mask)
         return solve(obj, x0_e, config, reg, lam, budget=budget)
 
-    return jax.jit(jax.vmap(solve_one,
-                            in_axes=(0, 0, 0, 0 if has_weights else None,
-                                     0 if has_offsets else None, 0, None,
-                                     None)),
-                   donate_argnums=(5,) if donate else ())
+    re_bucket_solve = jax.vmap(
+        solve_one, in_axes=(0, 0, 0, 0 if has_weights else None,
+                            0 if has_offsets else None, 0, None, None))
+    re_bucket_solve.__name__ = re_bucket_solve.__qualname__ = "re_bucket_solve"
+    return jax.jit(re_bucket_solve, donate_argnums=(5,) if donate else ())
 
 
 def fit_random_effects(
@@ -167,15 +172,18 @@ def fit_random_effects(
     pad_e = (-E) % mesh.shape[DATA_AXIS]
     key = (cache_key if cache_key is not None
            else ("fit_random_effects", id(blocks.x)))
-    x_dev = res_reg.stage_static(key, "x", mesh, blocks.x, 0.0)
-    labels_dev = res_reg.stage_static(key, "labels", mesh, blocks.labels, 0.5)
-    mask_dev = res_reg.stage_static(key, "mask", mesh, blocks.mask, 0.0)
-    weights_dev = res_reg.stage_static(key, "weights", mesh, blocks.weights,
-                                       0.0)
-    offsets_dev = res_reg.stage_update(mesh, blocks.offsets, 0.0, key=key,
-                                       field="offsets")
-    x0_dev = res_reg.stage_update(mesh, x0, 0.0, key=key, field="x0")
-    with mesh:
+    with annotate("re/stage_static"):
+        x_dev = res_reg.stage_static(key, "x", mesh, blocks.x, 0.0)
+        labels_dev = res_reg.stage_static(key, "labels", mesh, blocks.labels,
+                                          0.5)
+        mask_dev = res_reg.stage_static(key, "mask", mesh, blocks.mask, 0.0)
+        weights_dev = res_reg.stage_static(key, "weights", mesh,
+                                           blocks.weights, 0.0)
+    with annotate("re/stage_update"):
+        offsets_dev = res_reg.stage_update(mesh, blocks.offsets, 0.0, key=key,
+                                           field="offsets")
+        x0_dev = res_reg.stage_update(mesh, x0, 0.0, key=key, field="x0")
+    with mesh, annotate("re/dispatch"):
         res = batched(x_dev, labels_dev, mask_dev, weights_dev, offsets_dev,
                       x0_dev, lam, budget)
     if pad_e:

@@ -8,12 +8,16 @@ Three layers, one import:
     coordinate visits -> inner solves / chunk staging / checkpoint writes
     / serving batches), with `utils.faults.fire()`-style disarm semantics:
     a module-global None check and a shared no-op singleton when off —
-    zero traces, zero device reads, nothing allocated.
+    zero traces, zero device reads, nothing allocated.  PhaseTimings spans
+    and `telemetry.annotate(name)` leaves are also `photon/<name>`
+    annotations in any JAX profiler trace, armed or not, so the host's
+    spans and the device's programs share the profiler's clock.
   * the METRICS REGISTRY (`metrics`) — counters/gauges/bounded-reservoir
     histograms that the existing accounting surfaces (PhaseTimings'
     host-blocked time, StreamStats, TransferStats, ServingMetrics,
     quarantine/containment events, checkpoint/retry counters, the
-    `jax.retraces` fresh-compile counter) publish through, so ONE
+    always-on `jax.traces`/`jax.lowerings`/`jax.backend_compiles` counters
+    and their seconds from utils/jax_cache.py) publish through, so ONE
     `telemetry.snapshot()` returns everything.  Always live (an increment
     costs what the bespoke accumulators already cost).
   * EXPORTERS (`export`) — Chrome-trace/Perfetto JSON (`--trace-out` on
@@ -36,8 +40,8 @@ package (PhaseTimings / `timings.clock()`), never raw
 `time.perf_counter()` — one trace, not thirty stopwatches.
 """
 from photon_ml_tpu.telemetry.core import (  # noqa: F401
-    MAX_RECORDS, NOOP_SPAN, SpanRecord, Tracer, active_tracer, armed,
-    current_span_id, enabled, event, install, last_tracer, pop, push,
+    MAX_RECORDS, NOOP_SPAN, SpanRecord, Tracer, active_tracer, annotate,
+    armed, current_span_id, enabled, event, install, last_tracer, pop, push,
     retrace_count, set_observer, shutdown, span,
 )
 from photon_ml_tpu.telemetry.export import (  # noqa: F401

@@ -93,23 +93,51 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+#: prefix of every annotation this program writes into a profiler trace
+ANNOTATION_PREFIX = "photon/"
+
+# jax.profiler.TraceAnnotation, looked up on the first annotate(); False
+# where JAX cannot be imported
+_TRACE_ANNOTATION = None
+
+
+def annotate(name: str):
+    """Context manager that puts `photon/<name>` on the host plane of a
+    JAX profiler trace, on the profiler's clock and so beside the device's
+    programs.  Always on: a TraceMe is a flag check while no profiler
+    session is active, and it reads host values only."""
+    global _TRACE_ANNOTATION
+    cls = _TRACE_ANNOTATION
+    if cls is None:
+        try:
+            from jax.profiler import TraceAnnotation as cls
+        except ImportError:
+            cls = False
+        _TRACE_ANNOTATION = cls
+    return cls(ANNOTATION_PREFIX + name) if cls else NOOP_SPAN
+
 
 class _Span:
-    """Armed `span()` context manager: push on enter, pop on exit."""
+    """Armed `span()` context manager: push on enter, pop on exit, inside
+    the profiler annotation of the same name (or of `label`: PhaseTimings
+    keys), so a span trace and a profiler trace are one timeline."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_record")
+    __slots__ = ("_tracer", "_name", "_attrs", "_record", "_annotation")
 
-    def __init__(self, tracer, name, attrs):
+    def __init__(self, tracer, name, attrs, label=None):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
+        self._annotation = annotate(name if label is None else label)
 
     def __enter__(self) -> SpanRecord:
+        self._annotation.__enter__()
         self._record = self._tracer.push(self._name, self._attrs)
         return self._record
 
     def __exit__(self, *exc):
         self._tracer.pop(self._record)
+        self._annotation.__exit__(*exc)
         return False
 
 
@@ -310,8 +338,9 @@ class Tracer:
         self._log_record(line)
         self._notify_observer("span", line)
 
-    def span(self, name: str, attrs: Optional[dict] = None) -> _Span:
-        return _Span(self, name, attrs or {})
+    def span(self, name: str, attrs: Optional[dict] = None,
+             label: Optional[str] = None) -> _Span:
+        return _Span(self, name, attrs or {}, label)
 
     # -- instant events ----------------------------------------------------
 

@@ -181,10 +181,6 @@ class FlightRecorder:
 _ACTIVE: Optional[FlightRecorder] = None
 
 
-def active_recorder() -> Optional[FlightRecorder]:
-    return _ACTIVE
-
-
 def armed() -> bool:
     return _ACTIVE is not None
 

@@ -4,8 +4,9 @@ span tracer.
 Moved here from game/coordinate_descent.py: photonlint PH007 forbids raw
 `time.perf_counter()` span timing inside the hot-path modules, and this is
 the ONE sanctioned implementation — every timed phase of a fit lands both
-in the per-fit dict (the cli summary / bench tables, armed or not) and,
-when the tracer is armed, in the hierarchical trace as a named span.
+in the per-fit dict (the cli summary / bench tables, armed or not), in
+any JAX profiler trace as the annotation `photon/<label>` and, when the
+tracer is armed, in the hierarchical trace as a named span.
 
 `clock()` is the sanctioned raw timestamp for hot modules that need a
 bare duration (the disarmed-overhead bench times itself with it too).
@@ -41,7 +42,11 @@ class PhaseTimings(dict):
 
     When the tracer is armed, `span(label, name=..., **attrs)` also emits
     a telemetry span (`name` defaults to the label) so the per-fit dict
-    and the exported timeline are the same measurement, not two."""
+    and the exported timeline are the same measurement, not two.  Either
+    way the region is the profiler annotation `photon/<label>`.  A region
+    INSIDE a span that a profiler trace should name takes a bare
+    `telemetry.annotate(...)`, never a nested key: nested keys would break
+    the sum-equals-wall contract above."""
 
     def __init__(self, *args, **kwargs):
         super().__init__(*args, **kwargs)
@@ -50,10 +55,14 @@ class PhaseTimings(dict):
     @contextlib.contextmanager
     def span(self, label: str, host_blocked: bool = False,
              name: str = None, **attrs):
-        tspan = _core.span(name if name is not None else label, **attrs)
+        # one profiler annotation `photon/<label>` either way: the armed
+        # span enters it itself, under the key the dict is charged with
+        tracer = _core.active_tracer()
+        region = (_core.annotate(label) if tracer is None else tracer.span(
+            name if name is not None else label, attrs, label=label))
         t0 = clock()
         try:
-            with tspan:
+            with region:
                 yield
         finally:
             dt = clock() - t0

@@ -8,38 +8,99 @@ $JAX_COMPILATION_CACHE_DIR when that is set, else `<checkout>/.jax_cache`.
 """
 from __future__ import annotations
 
+import collections
 import os
+import threading
 
 CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
 _DEFAULT = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), ".jax_cache")
 
 
-class CompileTimeTracker:
-    """Accumulates real XLA backend-compile seconds via jax.monitoring.
-    With a warm persistent cache the backend compile never runs, so this
-    reads ~0 on the second identical invocation — the observable proof the
-    cache worked (VERDICT r3: report cold-vs-warm compile seconds)."""
+# JAX's three duration events of a program's first call, and the counter and
+# the seconds each is published under in the default telemetry registry
+_EVENT_PREFIX = "/jax/core/compile/"
+COMPILE_COUNTERS = {
+    _EVENT_PREFIX + "jaxpr_trace_duration": ("jax.traces", "jax.trace_s"),
+    _EVENT_PREFIX + "jaxpr_to_mlir_module_duration": ("jax.lowerings",
+                                                      "jax.lower_s"),
+    _EVENT_PREFIX + "backend_compile_duration": ("jax.backend_compiles",
+                                                 "jax.backend_compile_s"),
+}
+_counters_installed = False
+_finished = threading.local()   # .spans: this thread's (end, seconds), newest last
 
-    _EVENT = "/jax/core/compile/backend_compile_duration"
+
+def _publish(event, start, end, **kw):
+    """Count one trace, lowering or backend compile, and charge its seconds
+    ONCE: tracing a function traces every jitted function it calls, and
+    runs what it can eagerly, so the events nest and their plain sum counts
+    the same second several times.  An event that ends after this one
+    started, on this thread, is inside it and has been charged already."""
+    names = COMPILE_COUNTERS.get(event)
+    if names is None:
+        return
+    from photon_ml_tpu.telemetry import metrics
+    spans = getattr(_finished, "spans", None)
+    if spans is None:
+        spans = _finished.spans = collections.deque(maxlen=4096)
+    inner = 0.0
+    while spans and spans[-1][0] > start:
+        inner += spans.pop()[1]
+    spans.append((end, end - start))
+    metrics.counter(names[0]).inc()
+    metrics.counter(names[1]).inc(max(end - start - inner, 0.0))
+
+
+def install_compile_counters() -> None:
+    """Idempotent: one process-wide listener publishes `jax.traces`,
+    `jax.lowerings`, `jax.backend_compiles` and their seconds
+    (COMPILE_COUNTERS).  It fires only when JAX traces, lowers or compiles
+    (a load from the persistent cache counts as a compile), never in a
+    steady step."""
+    global _counters_installed
+    if _counters_installed:
+        return
+    from jax import monitoring
+    monitoring.register_event_time_span_listener(_publish)
+    _counters_installed = True
+
+
+class CompileTimeTracker:
+    """Real XLA backend-compile seconds and programs since this tracker
+    was made: what the process-wide counters `jax.backend_compile_s` and
+    `jax.backend_compiles` gained (nothing nests inside a backend compile,
+    so these seconds are the events' plain sum).  With a warm persistent
+    cache the backend compile never runs, so this reads ~0 on the second
+    identical invocation — the observable proof the cache worked (VERDICT
+    r3: report cold-vs-warm compile seconds)."""
 
     def __init__(self):
-        self.seconds = 0.0
-        self.count = 0
+        self._seconds0 = _counter_value("jax.backend_compile_s")
+        self._count0 = _counter_value("jax.backend_compiles")
 
-    def _on_event(self, name, duration, **kw):
-        if name == self._EVENT:
-            self.seconds += duration
-            self.count += 1
+    @property
+    def seconds(self) -> float:
+        return _counter_value("jax.backend_compile_s") - self._seconds0
+
+    @property
+    def count(self) -> int:
+        return _counter_value("jax.backend_compiles") - self._count0
 
     def install(self) -> "CompileTimeTracker":
-        from jax import monitoring
-        monitoring.register_event_duration_secs_listener(self._on_event)
+        install_compile_counters()
         return self
 
 
+def _counter_value(name: str) -> float:
+    from photon_ml_tpu.telemetry import metrics
+    return metrics.counter(name).value
+
+
 def enable_persistent_cache() -> str:
-    """Idempotent; returns the cache directory in use.
+    """Idempotent; returns the cache directory in use.  Also installs the
+    always-on trace/lower/compile counters (install_compile_counters): every
+    entry point calls this before it builds a program.
 
     One rule, shared with tests/conftest.py: where JAX_COMPILATION_CACHE_DIR
     is set JAX has already read it and no directory is set in code;
@@ -47,6 +108,7 @@ def enable_persistent_cache() -> str:
     path is part of the cache key, so a directory that moves never hits)."""
     import jax
 
+    install_compile_counters()
     if not os.environ.get(CACHE_DIR_ENV):
         os.makedirs(_DEFAULT, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", _DEFAULT)

@@ -483,3 +483,187 @@ def test_serving_metrics_latency_reservoir_is_bounded():
     prom = m.prometheus(model_version="vX")
     assert "photon_serving_requests_total 100000" in prom
     assert 'photon_serving_latency_s{quantile="0.95"}' in prom
+
+
+# --------------------------------------------------------------------------
+# one clock: program spans in the profiler's trace, programs named for their
+# layer, always-on trace/lower/compile counters (ISSUE 25)
+# --------------------------------------------------------------------------
+
+def _profiled_host_events(trace_dir):
+    """[(name, start_ns, end_ns)] of the host planes of the newest
+    `*.xplane.pb` under `trace_dir`."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    return [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events]
+
+
+def test_phase_timings_and_annotate_reach_the_profiler_trace(tmp_path):
+    """A PhaseTimings span and an `annotate` leaf inside it are
+    `photon/<name>` events of the profiler's host plane, nested, and the
+    dict is charged under the same key, no tracer armed."""
+    import jax
+    from photon_ml_tpu.telemetry.timings import PhaseTimings
+    spans = PhaseTimings()
+    assert not telemetry.armed()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with spans.span("0/x/solve", name="solve", coordinate="x"):
+            with telemetry.annotate("re/dispatch"):
+                jax.block_until_ready(jax.numpy.ones(8) + 1)
+    finally:
+        jax.profiler.stop_trace()
+    assert list(spans) == ["0/x/solve"] and spans["0/x/solve"] > 0
+    events = _profiled_host_events(str(tmp_path))
+    (outer,) = [ev for ev in events if ev[0] == "photon/0/x/solve"]
+    (inner,) = [ev for ev in events if ev[0] == "photon/re/dispatch"]
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+    # what the dict was charged is what the annotation spans (both ends
+    # are taken within the same few Python statements)
+    assert abs((outer[2] - outer[1]) * 1e-9 - spans["0/x/solve"]) < 0.05
+
+
+def test_spans_cost_nothing_more_without_a_profiler_session():
+    """No profiler, no tracer: the dict is charged all the same, the leaf
+    is a bare TraceMe (no record anywhere), and `telemetry.span()` is
+    still the shared no-op."""
+    from photon_ml_tpu.telemetry.timings import PhaseTimings
+    assert not telemetry.armed()
+    spans = PhaseTimings()
+    with spans.span("0/x/solve", host_blocked=True):
+        with telemetry.annotate("re/dispatch") as leaf:
+            pass
+    assert list(spans) == ["0/x/solve"] and spans["0/x/solve"] >= 0
+    assert spans.host_blocked == {"0/x/solve": spans["0/x/solve"]}
+    assert telemetry.span("anything") is telemetry.NOOP_SPAN
+    assert leaf is not telemetry.NOOP_SPAN   # JAX is importable here
+
+
+def test_armed_span_and_profiler_give_one_timeline(tmp_path):
+    """With the tracer armed, a PhaseTimings span is ONE annotation, under
+    the dict's key, and a plain `telemetry.span` is annotated under its
+    name: `--trace-out X --profile-dir Y` agree."""
+    import jax
+    from photon_ml_tpu.telemetry.timings import PhaseTimings
+    spans = PhaseTimings()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with telemetry.enabled(watch_compiles=False) as tracer:
+            with spans.span("1/y/solve", name="solve", coordinate="y"):
+                with telemetry.span("checkpoint_write", iteration=1):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    assert [s.name for s in tracer.spans] == ["checkpoint_write", "solve"]
+    names = [ev[0] for ev in _profiled_host_events(str(tmp_path))
+             if ev[0].startswith("photon/")]
+    assert sorted(names) == ["photon/1/y/solve", "photon/checkpoint_write"]
+
+
+def test_solve_programs_are_named_for_their_layer():
+    """The module name is what a profiler trace (and the persistent cache's
+    key) carries: `jit_fe_solve` and `jit_re_bucket_solve`, from one cached
+    wrapper per static signature."""
+    import jax.numpy as jnp
+    from photon_ml_tpu.ops import TASK_LOSSES, GLMObjective
+    from photon_ml_tpu.optim import OptimizerConfig, RegularizationContext
+    from photon_ml_tpu.parallel.fixed_effect import _cached_solver
+    from photon_ml_tpu.parallel.random_effect import _cached_batched_solver
+    loss = TASK_LOSSES["logistic_regression"]
+    config, reg = OptimizerConfig(max_iterations=3), RegularizationContext()
+    E, S, d = 4, 8, 3
+    batched = _cached_batched_solver(loss, config, reg, False, True)
+    assert batched is _cached_batched_solver(loss, config, reg, False, True)
+    lowered = batched.lower(
+        jnp.ones((E, S, d)), jnp.ones((E, S)), jnp.ones((E, S)), None,
+        jnp.zeros((E, S)), jnp.zeros((E, d)), jnp.asarray(1.0), None)
+    assert lowered.as_text().startswith("module @jit_re_bucket_solve ")
+    solver = _cached_solver(config, reg)
+    assert solver is _cached_solver(config, reg)
+    lowered = solver.lower(GLMObjective(loss, jnp.ones((S, d)), jnp.ones(S)),
+                           jnp.zeros(d), jnp.asarray(1.0), None)
+    assert lowered.as_text().startswith("module @jit_fe_solve ")
+
+
+def _jax_counters():
+    counters = telemetry.snapshot()["metrics"]["counters"]
+    return {k: v for k, v in counters.items() if k.startswith("jax.")}
+
+
+def test_trace_lower_compile_counters_are_always_on():
+    """One process-wide listener, no tracer armed: a fresh jit call is one
+    trace, one lowering, one backend compile; a repeat call is none.
+    CompileTimeTracker still counts backend compiles only."""
+    import jax
+    from jax._src import monitoring
+    from photon_ml_tpu.utils import jax_cache
+    jax_cache.install_compile_counters()
+    listeners = len(monitoring.get_event_time_span_listeners())
+    jax_cache.install_compile_counters()
+    jax_cache.enable_persistent_cache()
+    assert len(monitoring.get_event_time_span_listeners()) == listeners
+    assert monitoring.get_event_time_span_listeners().count(
+        jax_cache._publish) == 1
+
+    x = jax.numpy.ones(1789)            # a shape no other test uses
+    tracker = jax_cache.CompileTimeTracker().install()
+    before = _jax_counters()
+    f = jax.jit(lambda v: jax.lax.neg(v))   # no jitted function inside
+    jax.block_until_ready(f(x))
+    after = _jax_counters()
+    for count in ("jax.traces", "jax.lowerings", "jax.backend_compiles"):
+        assert after[count] == before.get(count, 0) + 1, count
+    for seconds in ("jax.trace_s", "jax.lower_s", "jax.backend_compile_s"):
+        assert after[seconds] > before.get(seconds, 0.0), seconds
+    # the tracker is the backend-compile counters since its install()
+    assert tracker.count == 1
+    assert tracker.seconds == pytest.approx(
+        after["jax.backend_compile_s"] - before["jax.backend_compile_s"])
+    assert jax_cache.CompileTimeTracker().install().count == 0
+    jax.block_until_ready(f(x))
+    assert _jax_counters() == after and tracker.count == 1
+
+
+def test_nested_traces_charge_each_second_once():
+    """Tracing a function traces the jitted functions it calls: many trace
+    events, nested. `jax.trace_s` takes the inner ones out of the outer, so
+    trace + lower + compile seconds stay within the call's wall time."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    def nested(v):
+        return jnp.where(v > 0, jnp.sum(v * v), jnp.linalg.norm(v)) \
+            + jnp.arange(1787.0).sum()
+
+    x = jnp.ones(1787)
+    before = _jax_counters()
+    t0 = time.time()
+    jax.block_until_ready(jax.jit(nested)(x))
+    wall = time.time() - t0
+    after = _jax_counters()
+    assert after["jax.traces"] - before["jax.traces"] > 1
+    spent = sum(after[k] - before[k] for k in
+                ("jax.trace_s", "jax.lower_s", "jax.backend_compile_s"))
+    assert 0 < spent <= wall
+
+
+def test_a_second_identical_fit_traces_nothing(rng):
+    """What an operator reads `jax.traces` for: a nightly refit of the same
+    shapes moves it by nothing, no tracer armed, no log scraped."""
+    from photon_ml_tpu.game import GameEstimator
+    ds = _tiny_game(rng)
+    GameEstimator(_tiny_config()).fit(ds)
+    after_first = _jax_counters()
+    GameEstimator(_tiny_config()).fit(ds)
+    assert _jax_counters() == after_first
