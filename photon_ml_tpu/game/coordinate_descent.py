@@ -129,6 +129,16 @@ class TrackerSummary:
     # examples_per_staged_byte — the stochastic lane's win is this ratio
     # going up by ~the local epoch count.  None on resident coordinates.
     stream: Optional[Dict[str, object]] = None
+    # LBFGS/OWLQN solves only (SolveResult.fg_count / .ls_trials).  The
+    # lanes of a vmapped solve run in lock step, so what the device ran is
+    # the MAX over lanes of fg_count: full value+gradient passes, two reads
+    # of the features each.  `ls_trials` is the max over lanes of the line
+    # search's trial points (the lock-step search ran at least as many);
+    # 1 - data_passes / (1 + ls_trials) is the share of evaluations served
+    # from cached margins without reading the features (0 where every
+    # trial is a full pass: L1, box).
+    data_passes: Optional[int] = None
+    ls_trials: Optional[int] = None
 
 
 def _reason_counts(reason) -> Dict[str, int]:
@@ -162,8 +172,16 @@ def _summarize_tracker(tracker: object, wall_s: float,
         for name, c in _reason_counts(getattr(t, "reason", None)).items():
             reasons[name] = reasons.get(name, 0) + c
     cap, tol = (None, None) if budget is None else budget
-    return TrackerSummary(iterations=count, wall_s=wall_s, reasons=reasons,
-                          iteration_cap=cap, tolerance=tol)
+    summary = TrackerSummary(iterations=count, wall_s=wall_s, reasons=reasons,
+                             iteration_cap=cap, tolerance=tol)
+    counted = [t for t in parts if getattr(t, "ls_trials", None) is not None]
+    if counted:
+        # the halves of a factored alternation run one after the other
+        summary.data_passes = sum(
+            int(np.max(np.asarray(t.fg_count), initial=0)) for t in counted)
+        summary.ls_trials = sum(
+            int(np.max(np.asarray(t.ls_trials), initial=0)) for t in counted)
+    return summary
 
 
 @dataclasses.dataclass
@@ -197,7 +215,9 @@ class CoordinateDescentResult:
         """Per-coordinate solver totals for the fit summary: solve count,
         inner iterations actually used, ConvergenceReason outcome counts,
         the budget trajectory (iteration caps per visit, None entries =
-        strict full solves), host-blocked seconds attributed to the
+        strict full solves), for LBFGS/OWLQN the data passes the device ran
+        and the line-search trials per visit (TrackerSummary.data_passes /
+        .ls_trials), host-blocked seconds attributed to the
         coordinate's spans, and — when the telemetry compile watch was
         armed — fresh traces per coordinate.  reference: the per-update
         OptimizationStatesTracker logs the GAME driver prints."""
@@ -213,6 +233,9 @@ class CoordinateDescentResult:
             d["solves"] += 1
             d["iterations"] += t.iterations
             d["iteration_caps"].append(t.iteration_cap)
+            if t.ls_trials is not None:
+                d.setdefault("data_passes", []).append(t.data_passes)
+                d.setdefault("ls_trials", []).append(t.ls_trials)
             if t.containment is not None:
                 d["containment"][t.containment] = \
                     d["containment"].get(t.containment, 0) + 1

@@ -59,6 +59,52 @@ def _apply_weights(v: jax.Array, weights: Optional[jax.Array]) -> jax.Array:
     return v if weights is None else v * weights
 
 
+def value_from_margins(
+    loss: PointwiseLoss,
+    z: jax.Array,
+    labels: jax.Array,
+    *,
+    weights: Optional[jax.Array] = None,
+    mask: Optional[jax.Array] = None,
+) -> jax.Array:
+    """sum_i w_i l(z_i, y_i) from margins already in hand: no read of the
+    features (reference: ValueAndGradientAggregator valueSum).  `mask` (0/1
+    per row) supports padded batches — the TPU replacement for ragged
+    per-entity data (rows with mask 0 contribute nothing; the reference has
+    no equivalent because Spark handles raggedness)."""
+    wl = _apply_weights(loss.loss(z, labels), weights)
+    if mask is not None:
+        # where() not multiply: a non-finite loss on a padded row must not
+        # poison the aggregate (inf * 0 == nan)
+        wl = jnp.where(mask != 0, wl, 0.0)
+    return jnp.sum(wl)
+
+
+def gradient_from_margins(
+    loss: PointwiseLoss,
+    x: fops.FeatureMatrix,
+    z: jax.Array,
+    labels: jax.Array,
+    *,
+    weights: Optional[jax.Array] = None,
+    norm: Optional[NormalizationContext] = None,
+    mask: Optional[jax.Array] = None,
+) -> jax.Array:
+    """d/dc of sum_i w_i l(z_i, y_i) at margins z: one read of the features
+    (reference: ValueAndGradientAggregator.scala:132-221, the gradient
+    assembly)."""
+    wdl = _apply_weights(loss.dz(z, labels), weights)
+    if mask is not None:
+        wdl = jnp.where(mask != 0, wdl, 0.0)
+    grad = fops.rmatvec(x, wdl)
+    if norm is not None and not norm.is_identity:
+        if norm.shifts is not None:
+            grad = grad - norm.shifts * jnp.sum(wdl)
+        if norm.factors is not None:
+            grad = grad * norm.factors
+    return grad
+
+
 def value_and_gradient(
     loss: PointwiseLoss,
     x: fops.FeatureMatrix,
@@ -70,30 +116,12 @@ def value_and_gradient(
     norm: Optional[NormalizationContext] = None,
     mask: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """(sum_i w_i l(z_i, y_i),  d/dc of it) in one fused pass.
-
-    reference: ValueAndGradientAggregator.scala:132-221 (add + gradient
-    assembly).  `mask` (0/1 per row) supports padded batches — the TPU
-    replacement for ragged per-entity data (rows with mask 0 contribute
-    nothing; the reference has no equivalent because Spark handles raggedness).
-    """
+    """(sum_i w_i l(z_i, y_i),  d/dc of it) in one fused pass: the margins
+    of the point, then the value and the gradient from them."""
     z = compute_margins(x, coefficients, offsets, norm)
-    l, dl = loss.loss_and_dz(z, labels)
-    wdl = _apply_weights(dl, weights)
-    wl = _apply_weights(l, weights)
-    if mask is not None:
-        # where() not multiply: a non-finite loss on a padded row must not
-        # poison the aggregate (inf * 0 == nan)
-        wdl = jnp.where(mask != 0, wdl, 0.0)
-        wl = jnp.where(mask != 0, wl, 0.0)
-    value = jnp.sum(wl)
-    grad = fops.rmatvec(x, wdl)
-    if norm is not None and not norm.is_identity:
-        if norm.shifts is not None:
-            grad = grad - norm.shifts * jnp.sum(wdl)
-        if norm.factors is not None:
-            grad = grad * norm.factors
-    return value, grad
+    return (value_from_margins(loss, z, labels, weights=weights, mask=mask),
+            gradient_from_margins(loss, x, z, labels, weights=weights,
+                                  norm=norm, mask=mask))
 
 
 def value_only(
@@ -109,10 +137,7 @@ def value_only(
 ) -> jax.Array:
     """sum_i w_i l(z_i, y_i) (reference: ValueAndGradientAggregator valueSum)."""
     z = compute_margins(x, coefficients, offsets, norm)
-    wl = _apply_weights(loss.loss(z, labels), weights)
-    if mask is not None:
-        wl = jnp.where(mask != 0, wl, 0.0)
-    return jnp.sum(wl)
+    return value_from_margins(loss, z, labels, weights=weights, mask=mask)
 
 
 def hessian_vector(

@@ -72,16 +72,33 @@ class GLMObjective:
         return fops.num_features(self.x)
 
     def value(self, c: jax.Array) -> jax.Array:
-        v = agg.value_only(self.loss, self.x, self.labels, c,
-                           weights=self.weights, offsets=self.offsets,
-                           norm=self.norm, mask=self.mask)
-        return v + 0.5 * self.l2_weight * jnp.dot(c, c)
+        return self.value_from_margins(self.margins(c), c)
 
     def value_and_gradient(self, c: jax.Array) -> Tuple[jax.Array, jax.Array]:
-        v, g = agg.value_and_gradient(self.loss, self.x, self.labels, c,
-                                      weights=self.weights, offsets=self.offsets,
-                                      norm=self.norm, mask=self.mask)
-        return v + 0.5 * self.l2_weight * jnp.dot(c, c), g + self.l2_weight * c
+        z = self.margins(c)
+        return self.value_from_margins(z, c), self.gradient_from_margins(z, c)
+
+    # -- margin surface -------------------------------------------------------
+    # A GLM's margin is affine in the coefficients, z(c + t p) = z(c) + t u
+    # with u = direction_margins(p), so a line search along p can evaluate
+    # its trial points from z and u without reading the features
+    # (optim/lbfgs.py).
+    def margins(self, c: jax.Array) -> jax.Array:
+        return agg.compute_margins(self.x, c, self.offsets, self.norm)
+
+    def direction_margins(self, p: jax.Array) -> jax.Array:
+        return agg.compute_margins(self.x, p, None, self.norm)
+
+    def value_from_margins(self, z: jax.Array, c: jax.Array) -> jax.Array:
+        v = agg.value_from_margins(self.loss, z, self.labels,
+                                   weights=self.weights, mask=self.mask)
+        return v + 0.5 * self.l2_weight * jnp.dot(c, c)
+
+    def gradient_from_margins(self, z: jax.Array, c: jax.Array) -> jax.Array:
+        g = agg.gradient_from_margins(self.loss, self.x, z, self.labels,
+                                      weights=self.weights, norm=self.norm,
+                                      mask=self.mask)
+        return g + self.l2_weight * c
 
     # -- TwiceDiffFunction surface --------------------------------------------
     def hessian_vector(self, c: jax.Array, v: jax.Array) -> jax.Array:

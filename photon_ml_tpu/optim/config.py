@@ -211,10 +211,14 @@ def solve(
 
     lower = None if cfg.box_lower is None else jnp.asarray(cfg.box_lower, x0.dtype)
     upper = None if cfg.box_upper is None else jnp.asarray(cfg.box_upper, x0.dtype)
-    return lbfgs(obj.value_and_gradient, x0,
+    # the line search runs on cached margins wherever its trial points are
+    # x + t p themselves; OWLQN's orthant projection and the box bend them
+    affine_trials = not reg.has_l1 and lower is None and upper is None
+    return lbfgs(None if affine_trials else obj.value_and_gradient, x0,
                  max_iterations=cfg.max_iterations, tolerance=tolerance,
                  history=cfg.history,
                  l1_weight=l1_w if reg.has_l1 else None,
                  lower=lower, upper=upper,
                  track_coefficients=cfg.track_coefficients,
-                 iteration_cap=iteration_cap)
+                 iteration_cap=iteration_cap,
+                 margin_surface=obj if affine_trials else None)

@@ -57,11 +57,12 @@ class _State(NamedTuple):
     rho: jax.Array          # [m] 1/(s.y)
     num_pairs: jax.Array    # pairs stored so far
     f_small: jax.Array      # consecutive sub-tolerance f-changes
-    fg_count: jax.Array     # fused value+grad evaluations (= data passes)
+    ls_trials: jax.Array    # trial points evaluated: first trials + backtracks
     reason: jax.Array
     loss_hist: jax.Array
     gnorm_hist: jax.Array
     coef_hist: "jax.Array | None"   # [max_iter+1, d] when tracking, else None
+    z: "jax.Array | None" = None    # margins at x on the margin path, else None
 
 
 # In float32 a single step's progress can round to an exact zero f-change
@@ -117,7 +118,7 @@ def _two_loop(q, s_buf, y_buf, rho, num_pairs, m):
 
 
 def lbfgs(
-    value_and_grad: ValueAndGrad,
+    value_and_grad: Optional[ValueAndGrad],
     x0: jax.Array,
     *,
     max_iterations: int = 100,
@@ -128,6 +129,7 @@ def lbfgs(
     upper: Optional[jax.Array] = None,
     track_coefficients: bool = False,
     iteration_cap: Optional[jax.Array] = None,
+    margin_surface=None,
 ) -> SolveResult:
     """Minimize f (+ optional l1*|x|_1, making this OWLQN) from x0.
 
@@ -143,18 +145,39 @@ def lbfgs(
     inexactness schedule that varies the budget per coordinate-descent
     outer iteration reuses one compiled program (optim/schedule.py).
 
-    Every line-search trial evaluates the FUSED value+gradient: the first
-    trial is accepted in the common case, so this costs 2 X-reads per
-    iteration (margin + gradient assembly) instead of 3 with a value-only
-    trial followed by a separate gradient pass; at one backtrack the two
-    schemes break even, beyond that fused loses slightly — rare for LBFGS
-    with a unit first step.
+    How a trial point is evaluated.  Given `value_and_grad` every trial,
+    the first and each backtrack, evaluates the FUSED value+gradient at
+    the trial point: 2 X-reads a trial.  That is the only way when the
+    trial point is not `x + t p` (OWLQN's orthant projection, the box) or
+    when all the caller has is `value_and_grad`.
+
+    `margin_surface` REPLACES `value_and_grad` (pass None for it; it is
+    never called): optim.config.solve passes the GLMObjective when there is
+    neither L1 nor a box.  It is an object with `margins(x)`,
+    `direction_margins(p)`, `value_from_margins(z, x)` and
+    `gradient_from_margins(z, x)` whose margins are affine in x:
+    z(x + t p) = z(x) + t u.  The loop then carries z, computes u = X p once
+    an iteration (one X-read), evaluates EVERY trial as
+    value_from_margins(z + t u, x + t p) with no X-read, and assembles the
+    gradient once, at the accepted point (one X-read): 2 X-reads an
+    iteration however often the search backtracks.  Under vmap the search
+    runs until the LAST lane accepts, so without this one lane at its
+    float32 optimum makes every lane re-read its block 10 to 19 times an
+    iteration (PERF.md, PR 26).  Direction, trial sequence, Armijo test and
+    convergence tests are the same on both paths; iterates differ by the
+    rounding of z + t u against X (x + t p).  The value and gradient norm
+    RETURNED are recomputed from fresh margins X x at the final x, so a
+    drift of the carried z never reaches a caller.
     """
     use_l1 = l1_weight is not None
     use_box = lower is not None or upper is not None
     if use_l1 and use_box:
         raise ValueError("L1 (OWLQN) and box constraints cannot be combined "
                          "(the reference has no such solver either)")
+    use_margins = margin_surface is not None
+    if use_margins and (use_l1 or use_box):
+        raise ValueError("the margin surface evaluates trial points x + t p; "
+                         "the orthant and box projections are not affine in t")
     m = history
     d = x0.shape[-1]
     dtype = x0.dtype
@@ -197,11 +220,19 @@ def lbfgs(
             v = v + jnp.sum(l1 * jnp.abs(x))
         return v, g
 
+    def at_point(x):
+        """(f, g, margins or None) at x from scratch: 2 X-reads."""
+        if not use_margins:
+            return (*full_value(x), None)
+        z = margin_surface.margins(x)
+        return (margin_surface.value_from_margins(z, x),
+                margin_surface.gradient_from_margins(z, x), z)
+
     cap = (max_iterations if iteration_cap is None
            else jnp.minimum(jnp.asarray(iteration_cap, jnp.int32),
                             max_iterations))
     x0 = project_box(x0)
-    f0, g0 = full_value(x0)
+    f0, g0, z0 = at_point(x0)
     gnorm0 = jnp.linalg.norm(steer_grad(x0, g0))
     # relative gradient convergence, like breeze's default convergence check
     gtol = tolerance * jnp.maximum(gnorm0, 1.0)
@@ -213,12 +244,13 @@ def lbfgs(
         s_buf=jnp.zeros((m, d), dtype), y_buf=jnp.zeros((m, d), dtype),
         rho=jnp.zeros((m,), dtype), num_pairs=jnp.asarray(0, jnp.int32),
         f_small=jnp.asarray(0, jnp.int32),
-        fg_count=jnp.asarray(1, jnp.int32),  # the f0/g0 evaluation
+        ls_trials=jnp.asarray(0, jnp.int32),
         reason=jnp.asarray(ConvergenceReason.NOT_CONVERGED, jnp.int32),
         loss_hist=jnp.full((max_iterations + 1,), nan).at[0].set(f0),
         gnorm_hist=jnp.full((max_iterations + 1,), nan).at[0].set(gnorm0),
         coef_hist=(jnp.full((max_iterations + 1, d), nan).at[0].set(x0)
                    if track_coefficients else None),
+        z=z0,
     )
 
     def cond(st: _State):
@@ -256,23 +288,44 @@ def lbfgs(
             # Armijo on actual displacement (correct under projection)
             return (ft <= st.f + _C1 * jnp.dot(steer, xt - st.x)) & jnp.isfinite(ft)
 
+        if use_margins:
+            u = margin_surface.direction_margins(p)
+
+            def evaluate(t):
+                """Value at the trial point, from margins; nothing to keep:
+                x, z and g at the accepted step are made once, after the
+                search, so the search carries per-lane scalars only."""
+                xt = trial(t)
+                return xt, margin_surface.value_from_margins(st.z + t * u,
+                                                             xt), ()
+        else:
+            def evaluate(t):
+                """Fused value+gradient at the trial point, both kept."""
+                xt = trial(t)
+                ft, gt = full_value(xt)
+                return xt, ft, (xt, gt)
+
         def ls_cond(c):
             t, ls_iter, done, *_ = c
             return (~done) & (ls_iter < _MAX_LS)
 
         def ls_body(c):
-            t, ls_iter, _, _, _, _ = c
+            t, ls_iter, *_ = c
             t = t * 0.5
-            xt = trial(t)
-            ft, gt = full_value(xt)
-            return t, ls_iter + 1, armijo_ok(xt, ft), xt, ft, gt
+            xt, ft, kept = evaluate(t)
+            return t, ls_iter + 1, armijo_ok(xt, ft), ft, kept
 
-        xt0 = trial(t0)
-        ft0, gt0 = full_value(xt0)
-        t, ls_n, ls_ok, x_new, f_new, g_new = lax.while_loop(
+        t0 = jnp.asarray(t0, dtype)
+        xt0, ft0, kept0 = evaluate(t0)
+        t, ls_n, ls_ok, f_new, kept = lax.while_loop(
             ls_cond, ls_body,
-            (jnp.asarray(t0, dtype), jnp.asarray(0, jnp.int32),
-             armijo_ok(xt0, ft0), xt0, ft0, gt0))
+            (t0, jnp.asarray(0, jnp.int32), armijo_ok(xt0, ft0), ft0, kept0))
+        if use_margins:
+            x_new = trial(t)
+            z_new = st.z + t * u
+            g_new = margin_surface.gradient_from_margins(z_new, x_new)
+        else:
+            (x_new, g_new), z_new = kept, None
 
         # curvature pair from raw gradients (standard OWLQN choice)
         s = x_new - st.x
@@ -309,6 +362,8 @@ def lbfgs(
         x_new = jnp.where(ls_ok, x_new, st.x)
         f_new = jnp.where(ls_ok, f_new, st.f)
         g_new = jnp.where(ls_ok, g_new, st.g)
+        if use_margins:
+            z_new = jnp.where(ls_ok, z_new, st.z)
         gnorm_new = jnp.where(ls_ok, gnorm_new, st.gnorm_hist[st.k])
 
         k = st.k + 1
@@ -316,24 +371,32 @@ def lbfgs(
             k=k, x=x_new, f=f_new, g=g_new,
             s_buf=s_buf, y_buf=y_buf, rho=rho, num_pairs=num_pairs,
             f_small=f_small,
-            fg_count=st.fg_count + 1 + ls_n,  # first trial + backtracks
+            ls_trials=st.ls_trials + 1 + ls_n,  # first trial + backtracks
             reason=reason,
             loss_hist=st.loss_hist.at[k].set(f_new),
             gnorm_hist=st.gnorm_hist.at[k].set(gnorm_new),
             coef_hist=(None if st.coef_hist is None
                        else st.coef_hist.at[k].set(x_new)),
+            z=z_new,
         )
 
     st = lax.while_loop(cond, body, init)
     reason = jnp.where(st.reason == ConvergenceReason.NOT_CONVERGED,
                        jnp.asarray(ConvergenceReason.MAX_ITERATIONS, jnp.int32),
                        st.reason)
-    gnorm_final = st.gnorm_hist[st.k]
-    return SolveResult(x=st.x, value=st.f, gradient_norm=gnorm_final,
+    if use_margins:
+        # what is reported comes from fresh margins X x, not the carried z
+        value, g, _ = at_point(st.x)
+        gnorm_final = jnp.linalg.norm(g)
+        fg_count = st.k + 2     # f0/g0, (u, g) an iteration, this refresh
+    else:
+        value, gnorm_final = st.f, st.gnorm_hist[st.k]
+        fg_count = st.ls_trials + 1     # f0/g0 and every trial
+    return SolveResult(x=st.x, value=value, gradient_norm=gnorm_final,
                        iterations=st.k, reason=reason,
                        loss_history=st.loss_hist, gnorm_history=st.gnorm_hist,
                        coefficient_history=st.coef_hist,
-                       fg_count=st.fg_count)
+                       fg_count=fg_count, ls_trials=st.ls_trials)
 
 
 def owlqn(value_and_grad: ValueAndGrad, x0: jax.Array, *, l1_weight,

@@ -55,11 +55,21 @@ class SolveResult(NamedTuple):
     # (each is a full data pass — the honest work count for throughput
     # accounting; the reference pays one treeAggregate per Hv, TRON.scala:301)
     hv_count: "jax.Array | None" = None
-    # LBFGS/OWLQN only: total fused value+gradient evaluations, INCLUDING
-    # the initial evaluation and every line-search backtrack trial — each is
-    # a full data pass, so throughput accounting must count them all (the
-    # round-3 bench treated line-search extras as free)
+    # LBFGS/OWLQN only: full value+gradient data passes, two reads of the
+    # features each — the honest work count for throughput accounting.
+    # Where every trial point of the line search is a fused value+gradient
+    # (L1, box, a bare value_and_grad): the initial evaluation and every
+    # trial, backtracks included, 1 + ls_trials.  Where the search runs on
+    # cached margins (optim/lbfgs.py `margin_surface`): iterations + 2 — the
+    # initial evaluation, one direction-margins + gradient pair an
+    # iteration, and the refresh of the returned value at the final x —
+    # however often the search backtracked
     fg_count: "jax.Array | None" = None
+    # LBFGS/OWLQN only: trial points the line search evaluated, first trials
+    # and backtracks.  On cached margins a trial reads no features, so
+    # 1 - fg_count / (1 + ls_trials) is the share of evaluations that the
+    # margins served
+    ls_trials: "jax.Array | None" = None
 
     @property
     def converged(self) -> jax.Array:

@@ -276,6 +276,36 @@ def test_strict_vs_scheduled_final_parity_f64(rng):
     assert diag["fixed"]["reasons"]  # ConvergenceReason counts surfaced
 
 
+@pytest.mark.parametrize("per_user_reg", ["l2", "l1"])
+def test_solver_diagnostics_count_data_passes_and_trials(rng, per_user_reg):
+    """What the device ran, per visit: `data_passes` full value+gradient
+    passes (max over the lock-step lanes) and the line search's trials.
+    On cached margins (L2) a pass count follows the iterations however the
+    search backtracks; under L1 every trial is a pass."""
+    train, val = _glmix(rng)
+    cfg = _convex_config(2)
+    if per_user_reg == "l1":
+        per_user = cfg.coordinates["perUser"]
+        cfg = dataclasses.replace(cfg, coordinates=dict(
+            cfg.coordinates, perUser=dataclasses.replace(
+                per_user, optimization=dataclasses.replace(
+                    per_user.optimization, regularization=L1))))
+    descent = GameEstimator(cfg).fit(train, val).descent
+    diag = descent.solver_diagnostics()
+    for name in ("fixed", "perUser"):
+        assert (len(diag[name]["data_passes"]) == len(diag[name]["ls_trials"])
+                == diag[name]["solves"] == 2)
+    for key, t in descent.trackers.items():
+        if key.endswith("/fixed"):      # one lane, on margins
+            assert t.data_passes == t.iterations + 2
+            assert t.ls_trials >= t.iterations
+        elif per_user_reg == "l2":      # 25 lanes in lock step, on margins
+            assert 2 <= t.data_passes <= min(t.iterations, 100) + 2
+            assert t.ls_trials >= t.data_passes - 2
+        else:                           # every trial a fused value+gradient
+            assert t.ls_trials == t.data_passes - 1
+
+
 def test_scheduled_resume_reproduces_trajectory(rng, tmp_path):
     """A scheduled fit interrupted mid-schedule (after outer iteration 0's
     checkpoint) and resumed reproduces the uninterrupted trajectory —

@@ -12,7 +12,9 @@ import numpy as np
 import pytest
 from scipy.optimize import minimize
 
-from photon_ml_tpu.ops import LOGISTIC, POISSON, SQUARED, GLMObjective
+from photon_ml_tpu.ops import (
+    LOGISTIC, POISSON, SMOOTHED_HINGE, SQUARED, GLMObjective,
+)
 from photon_ml_tpu.optim import (
     ConvergenceReason, OptimizerConfig, OptimizerType, RegularizationContext,
     RegularizationType, lbfgs, solve, tron,
@@ -235,3 +237,233 @@ def test_lbfgs_fg_count_counts_every_evaluation():
     # reference: iterations first trials + initial + backtracks
     assert int(res.fg_count) >= int(res.iterations) + 1
     assert int(res.fg_count) <= int(res.iterations) * (1 + 30) + 1
+
+
+# -- line search on cached margins (PR 26) ------------------------------------
+# solve() hands lbfgs the objective's margin surface when there is neither L1
+# nor a box; lbfgs(obj.value_and_gradient, ...) is the generic path, every
+# trial a fused value+gradient.  Same algorithm, two ways to evaluate a trial.
+
+_L2 = RegularizationContext(RegularizationType.L2)
+_L1 = RegularizationContext(RegularizationType.L1)
+_MARGIN_LOSSES = {"logistic": (LOGISTIC, "logistic"), "squared": (SQUARED, "linear"),
+                  "poisson": (POISSON, "poisson"),
+                  "smoothed_hinge": (SMOOTHED_HINGE, "hinge")}
+
+
+def _margin_objective(loss_name, features, variant, dtype, n=240, d=7):
+    from photon_ml_tpu.ops.features import PaddedSparse
+    from photon_ml_tpu.ops.normalization import NormalizationContext
+    loss, task = _MARGIN_LOSSES[loss_name]
+    rng = np.random.default_rng(11)
+    x, y, _, _ = make_glm_data(rng, n=n, d=d, task=task)
+    x[rng.uniform(size=x.shape) < 0.4] = 0.0       # something to be sparse about
+    x[:, -1] = 1.0
+    kw = {}
+    if variant == "weights_offsets_mask":
+        kw = dict(weights=jnp.asarray(rng.uniform(0.5, 2.0, n), dtype),
+                  offsets=jnp.asarray(0.3 * rng.normal(size=n), dtype),
+                  mask=jnp.asarray(rng.uniform(size=n) < 0.8, dtype))
+    elif variant == "normalised":
+        factors = rng.uniform(0.5, 2.0, d); factors[-1] = 1.0
+        shifts = 0.2 * rng.normal(size=d); shifts[-1] = 0.0
+        kw = dict(norm=NormalizationContext(jnp.asarray(factors, dtype),
+                                            jnp.asarray(shifts, dtype), d - 1))
+    xd = jnp.asarray(x, dtype)
+    xf = PaddedSparse.from_dense(xd) if features == "padded_sparse" else xd
+    return GLMObjective(loss, xf, jnp.asarray(y, dtype), **kw)
+
+
+@pytest.mark.parametrize("dtype", ["float64", "float32"])
+@pytest.mark.parametrize("variant", ["plain", "weights_offsets_mask", "normalised"])
+@pytest.mark.parametrize("features", ["dense", "padded_sparse"])
+@pytest.mark.parametrize("loss_name", list(_MARGIN_LOSSES))
+def test_margin_line_search_matches_generic_path(loss_name, features, variant, dtype):
+    dtype = jnp.dtype(dtype)
+    obj = _margin_objective(loss_name, features, variant, dtype)
+    x0 = jnp.zeros(obj.dim, dtype)
+    lam = 1.0
+    l2 = obj.with_l2(jnp.asarray(lam, dtype))
+
+    @jax.jit
+    def both(o, cap, tol):
+        from photon_ml_tpu.optim.schedule import SolveBudget
+        return (solve(o, x0, OptimizerConfig(), _L2, lam,
+                      budget=SolveBudget(cap, tol)),
+                lbfgs(o.with_l2(jnp.asarray(lam, dtype)).value_and_gradient,
+                      x0, tolerance=tol, iteration_cap=cap))
+
+    def counted(on_margins, generic):
+        assert int(on_margins.fg_count) == int(on_margins.iterations) + 2
+        assert int(generic.fg_count) == int(generic.ls_trials) + 1
+
+    if dtype == jnp.float64:
+        on_margins, generic = both(obj, 100, jnp.asarray(1e-7, dtype))
+        counted(on_margins, generic)
+        k_m, k_g = int(on_margins.iterations), int(generic.iterations)
+        assert int(on_margins.reason) == int(generic.reason)
+        assert abs(k_m - k_g) <= 1
+        np.testing.assert_allclose(on_margins.x, generic.x, rtol=1e-9, atol=1e-12)
+        k = min(k_m, k_g) + 1
+        np.testing.assert_allclose(on_margins.loss_history[:k],
+                                   generic.loss_history[:k], rtol=1e-9)
+        assert int(on_margins.ls_trials) == int(generic.ls_trials) + k_m - k_g
+        return
+    # float32, while f still resolves the steps (the easiest of these
+    # problems sits at its floor after five iterations): the iterates agree
+    zero = jnp.asarray(0.0, dtype)
+    on_margins, generic = both(obj, 4, zero)
+    counted(on_margins, generic)
+    assert int(on_margins.iterations) == int(generic.iterations) == 4
+    assert float(jnp.linalg.norm(on_margins.x - generic.x)) <= (
+        1e-4 * float(jnp.linalg.norm(generic.x)))
+    # float32, cap 100, run down to its floor (8 to 21 iterations, then f
+    # stops changing).  The returned value is held to fresh margins X x (the
+    # exit refresh) and to the generic path's; x to the ball that float32's
+    # f cannot see into: one ulp of f decides which trial is accepted, on
+    # either path, so each stops within sqrt(2 ulp(f) / lambda_min) of the
+    # optimum.  ISSUE 26's 1e-4 of |x| is inside that ball for 23 of these
+    # 24 problems (readings up to 6.5e-5); logistic x padded_sparse x
+    # normalised reads 1.1e-3 where the ball is 1.4e-3: there the margin
+    # path's f stops changing at iteration 13, the generic path's at 15.
+    on_margins, generic = both(obj, 100, zero)
+    counted(on_margins, generic)
+    fresh = float(l2.value(on_margins.x))
+    assert abs(float(on_margins.value) - fresh) <= 1e-5 * abs(fresh)
+    assert abs(float(on_margins.value) - float(generic.value)) <= 1e-6 * abs(fresh)
+    l2_f64 = _margin_objective(loss_name, features, variant,
+                               jnp.dtype("float64")).with_l2(jnp.asarray(lam))
+    curvature = np.linalg.eigvalsh(np.asarray(jax.hessian(l2_f64.value)(
+        jnp.asarray(generic.x, jnp.float64))))[0]
+    ball = np.sqrt(2 * np.spacing(np.float32(fresh)) / curvature)
+    assert float(jnp.linalg.norm(on_margins.x - generic.x)) <= 2 * ball
+
+
+@pytest.mark.parametrize("n,d,weakest_column,lam", [
+    (400, 20, 1e-2, 1e-3), (600, 30, 1e-2, 1e-2), (2000, 40, 10 ** -1.5, 1e-2)])
+def test_carried_margins_do_not_drift_over_a_long_float32_solve(
+        n, d, weakest_column, lam):
+    """A solve that uses all of its 100 iterations in float32 (columns
+    scaled over decades, so L-BFGS is still descending at the cap): at
+    EVERY iterate the value and gradient norm the loop carries, made from
+    z + t u a hundred times over, are those of fresh margins X x.  Readings:
+    value within 2.5e-7 of f (4 ulp), gradient norm within 2.4e-7 of the
+    first one.  x itself is no yardstick here: unconverged, the two paths
+    are 4e-3 to 1e-2 of |x| apart along the weak columns while their
+    values agree to 3e-4."""
+    rng = np.random.default_rng(5)
+    x, y, _, _ = make_glm_data(rng, n=n, d=d, task="logistic")
+    x = x * np.logspace(0, np.log10(weakest_column), d)
+    dtype = jnp.float32
+    obj = GLMObjective(LOGISTIC, jnp.asarray(x, dtype), jnp.asarray(y, dtype))
+    l2 = obj.with_l2(jnp.asarray(lam, dtype))
+    cfg = OptimizerConfig(max_iterations=100, tolerance=0.0,
+                          track_coefficients=True)
+    on_margins = jax.jit(
+        lambda o: solve(o, jnp.zeros(d, dtype), cfg, _L2, lam))(obj)
+    generic = jax.jit(lambda o: lbfgs(o.value_and_gradient, jnp.zeros(d, dtype),
+                                      tolerance=0.0))(l2)
+    k = int(on_margins.iterations)
+    assert k >= 50      # reads 100, the cap
+    fresh_f, fresh_g = jax.vmap(l2.value_and_gradient)(
+        on_margins.coefficient_history)
+    fresh_gnorm = np.linalg.norm(np.asarray(fresh_g), axis=1)
+    np.testing.assert_allclose(on_margins.loss_history, fresh_f, rtol=1e-5)
+    np.testing.assert_allclose(on_margins.gnorm_history, fresh_gnorm, rtol=0,
+                               atol=1e-5 * fresh_gnorm[0])
+    # the returned value is the refreshed one (batched and single
+    # evaluations sum in different orders: an ulp or two apart)
+    np.testing.assert_allclose(on_margins.value, fresh_f[k], rtol=1e-6)
+    # and it descends as far: no worse than the generic path by 1e-3 of f
+    assert float(on_margins.value) <= float(generic.value) * (1 + 1e-3)
+
+
+def _while_eqns(jaxpr, depth=0):
+    """(nesting depth among `while`s, eqn) for every while in a jaxpr."""
+    for eqn in jaxpr.eqns:
+        is_while = eqn.primitive.name == "while"
+        if is_while:
+            yield depth, eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _while_eqns(sub, depth + is_while)
+
+
+@pytest.mark.parametrize("path,reads_x", [("margins", False), ("l1", True),
+                                          ("box", True)])
+def test_line_search_body_reads_features_only_off_the_margin_path(path, reads_x):
+    """The inner `while` is the line search.  On the margin path no operand
+    of the feature block's shape goes into it; under L1 and under a box the
+    fused value+gradient of every trial does."""
+    S, d = 37, 5      # unlike every other shape of the solve ([m, d], [101])
+    obj = _margin_objective("logistic", "dense", "plain", jnp.float64, n=S, d=d)
+    cfg, reg = OptimizerConfig(), _L2
+    if path == "l1":
+        reg = _L1
+    elif path == "box":
+        cfg = OptimizerConfig(box_lower=(-0.5,) * d, box_upper=(0.5,) * d)
+    jaxpr = jax.make_jaxpr(
+        lambda o, x0: solve(o, x0, cfg, reg, 0.5))(obj, jnp.zeros(d)).jaxpr
+    whiles = list(_while_eqns(jaxpr))
+    assert [depth for depth, _ in whiles] == [0, 1]   # the solve, its search
+    search = whiles[1][1]
+    shapes = {v.aval.shape for v in search.invars}
+    body = search.params["body_jaxpr"].jaxpr
+    shapes |= {v.aval.shape for e in body.eqns for v in e.invars
+               if hasattr(v, "aval")}
+    assert ((S, d) in shapes) == reads_x
+    assert (S,) in shapes     # margins, labels: the search does run in there
+
+
+def test_vmapped_margin_search_one_hard_lane_costs_no_feature_reads(rng):
+    """64 easy lanes and one whose first step overshoots a thousandfold:
+    the batch backtracks as long as that lane does, on margins alone."""
+    S, d, lanes = 40, 4, 65
+    xs, ys = [], []
+    for _ in range(lanes):
+        x, y, _, _ = make_glm_data(rng, n=S, d=d, task="logistic")
+        xs.append(x); ys.append(y)
+    xs[17] = xs[17] * np.array([1e3, 1.0, 1e-2, 1.0])
+    xb, yb = jnp.asarray(np.stack(xs)), jnp.asarray(np.stack(ys))
+
+    def solve_one(x, y):
+        return solve(GLMObjective(LOGISTIC, x, y), jnp.zeros(d),
+                     OptimizerConfig(), _L2, 0.1)
+
+    batched = jax.jit(jax.vmap(solve_one))(xb, yb)
+    fg, ls, its = (np.asarray(batched.fg_count), np.asarray(batched.ls_trials),
+                   np.asarray(batched.iterations))
+    assert fg.max() == its.max() + 2
+    assert ls.max() > fg.max()
+    assert ls.argmax() == 17
+    np.testing.assert_array_equal(fg, its + 2)
+    single = jax.jit(solve_one)
+    for i in (0, 16, 18, 64):
+        one = single(xb[i], yb[i])
+        assert int(one.iterations) == its[i] and int(one.ls_trials) == ls[i]
+        assert int(one.reason) == int(batched.reason[i])
+        np.testing.assert_allclose(batched.x[i], one.x, rtol=1e-9, atol=1e-12)
+
+
+def test_l1_and_box_solves_run_the_generic_path_bit_for_bit(rng):
+    """solve() under L1 and under a box is lbfgs on the bare fused
+    value+gradient, the code those paths ran before the margin surface."""
+    x, y, _, _ = make_glm_data(rng, n=200, d=6, task="logistic")
+    obj = GLMObjective(LOGISTIC, jnp.asarray(x), jnp.asarray(y))
+    lo, hi = (-0.2,) * 6, (0.3,) * 6
+    pairs = [
+        (solve(obj, jnp.zeros(6), OptimizerConfig(), _L1, 2.0),
+         lbfgs(obj.with_l2(jnp.zeros(())).value_and_gradient, jnp.zeros(6),
+               l1_weight=jnp.asarray(2.0))),
+        (solve(obj, jnp.zeros(6), OptimizerConfig(box_lower=lo, box_upper=hi),
+               _L2, 1.0),
+         lbfgs(obj.with_l2(jnp.asarray(1.0)).value_and_gradient, jnp.zeros(6),
+               lower=jnp.asarray(lo), upper=jnp.asarray(hi))),
+    ]
+    for via_solve, bare in pairs:
+        for a, b in zip(jax.tree_util.tree_leaves(via_solve),
+                        jax.tree_util.tree_leaves(bare)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert int(via_solve.fg_count) == int(via_solve.ls_trials) + 1
+    with pytest.raises(ValueError, match="not affine"):
+        lbfgs(obj.value_and_gradient, jnp.zeros(6), l1_weight=1.0,
+              margin_surface=obj)

@@ -226,8 +226,12 @@ def test_solve_streamed_matches_resident(rng, opt, reg, weight, count_slack):
     np.testing.assert_allclose(float(ss.value), float(rs.value), rtol=1e-9)
     np.testing.assert_allclose(np.asarray(ss.x), np.asarray(rs.x),
                                rtol=1e-6, atol=1e-9)
-    if rs.fg_count is not None:
-        assert abs(int(ss.fg_count) - int(rs.fg_count)) <= count_slack
+    if rs.ls_trials is not None:
+        # the same trial points on both sides; the streamed solver pays a
+        # fused pass for each, the resident one evaluates them on cached
+        # margins where there is neither L1 nor a box (its fg_count is
+        # then iterations + 2)
+        assert abs(int(ss.fg_count) - (1 + int(rs.ls_trials))) <= count_slack
     if rs.hv_count is not None:
         assert abs(int(ss.hv_count) - int(rs.hv_count)) <= count_slack
 
