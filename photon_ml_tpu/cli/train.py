@@ -793,6 +793,9 @@ def _run(args, log) -> int:
             "validation": best.validation,
             "solver_iterations_total": best.descent.total_iterations(),
             "solver_diagnostics": solver_diag,
+            # per random-effect coordinate: active / passive / discarded
+            # rows, capped entities, padded cells, bucket shapes
+            "coordinate_build": best.coordinate_build,
             # fault containment accounting: quarantine events (rollbacks /
             # tightened retries / freezes), coordinates left frozen, how
             # the checkpoint was recovered at resume, and — on chaos runs —

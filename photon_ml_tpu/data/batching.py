@@ -244,6 +244,13 @@ class RandomEffectDataset:
     # whose passive count exceeds the bound) — flat_entity_lanes maps them to
     # lane -1 so they contribute score 0, the missing-score default.
     discarded_rows: Optional[np.ndarray] = None  # [k] canonical row ids
+    # what the build did with the rows, counted once at the build: rows
+    # that train (`active_rows`), are only scored (`passive_rows`) or are
+    # dropped (`discarded_rows`), entities the cap cut (`capped_entities`,
+    # their weights are rescaled), `cells` of the padded blocks and those
+    # that hold no row (`padded_cells`), and `buckets` as [[entities,
+    # samples, real rows]] in lane order
+    build_counts: Dict[str, object] = dataclasses.field(default_factory=dict)
     _global_blocks: Optional[EntityBlocks] = dataclasses.field(
         default=None, repr=False, compare=False)
     _global_row_ids: Optional[np.ndarray] = dataclasses.field(
@@ -264,16 +271,6 @@ class RandomEffectDataset:
     @property
     def max_samples(self) -> int:
         return max(b.samples_per_entity for b in self.buckets)
-
-    def padding_stats(self) -> Dict[str, float]:
-        """Fraction of block cells holding real rows, bucketed vs the
-        single-S layout it replaces (VERDICT r2 item #2's efficiency stat)."""
-        cells = sum(b.num_entities * b.samples_per_entity
-                    for b in self.buckets)
-        single = self.num_entities * self.max_samples
-        return {"num_buckets": len(self.buckets),
-                "bucketed_efficiency": self.num_active / max(cells, 1),
-                "single_block_efficiency": self.num_active / max(single, 1)}
 
     @property
     def active_row_ids(self) -> np.ndarray:
@@ -440,7 +437,7 @@ def _build_random_effect_dataset(
     # --- reservoir cap: segmented random-key rank cut --------------------
     cap = config.active_data_upper_bound
     weight_scale = np.ones(E)
-    num_passive = 0
+    num_passive = num_capped = 0
     discarded_rows = np.zeros((0,), dtype=np.int64)
     if cap is not None and (counts > cap).any():
         keys = rng.random(len(rows_sorted))
@@ -451,6 +448,7 @@ def _build_random_effect_dataset(
         # rand_order space: row rand_order[i] has within-entity random rank
         # rank_in_entity[i] because groups stay contiguous under lexsort
         over = counts > cap
+        num_capped = int(over.sum())
         # weight rescale so the capped sample represents the full count
         # (reference: MinHeapWithFixedCapacity cumCount/size rescale,
         # RandomEffectDataSet.scala:325-388)
@@ -480,7 +478,7 @@ def _build_random_effect_dataset(
 
     pow2_lane = _ceil_pow2(counts_lane)
     # group adjacent power-of-two classes when there are more classes than
-    # max_buckets (compile-count cap; padding cost shows in padding_stats)
+    # max_buckets (compile-count cap; padding cost shows in build_counts)
     uniq_keys, key_of_lane = np.unique(pow2_lane, return_inverse=True)
     n_classes = len(uniq_keys)
     mb = config.max_buckets
@@ -605,12 +603,21 @@ def _build_random_effect_dataset(
                     offsets=None if offsets is None
                     else jnp.asarray(offsets))))
 
+    shapes = [[b.num_entities, b.samples_per_entity,
+               int((b.row_ids >= 0).sum())] for b in buckets]
+    cells = sum(e * s for e, s, _ in shapes)
     return RandomEffectDataset(
         config=config, buckets=buckets, entity_ids=entity_ids,
         entity_position=entity_position,
         projection=projection, global_dim=d_global,
         num_active=num_active, num_passive=num_passive,
-        discarded_rows=discarded_rows, projection_matrix=proj_matrix)
+        discarded_rows=discarded_rows, projection_matrix=proj_matrix,
+        build_counts={
+            "entities": E, "active_rows": num_active,
+            "passive_rows": num_passive,
+            "discarded_rows": len(discarded_rows),
+            "capped_entities": num_capped, "cells": cells,
+            "padded_cells": cells - num_active, "buckets": shapes})
 
 
 def _pearson_select_segmented(

@@ -56,7 +56,7 @@ from photon_ml_tpu.parallel.fixed_effect import (
 from photon_ml_tpu.parallel.random_effect import (
     fit_random_effects, score_by_entity,
 )
-from photon_ml_tpu.telemetry import annotate
+from photon_ml_tpu.telemetry import annotate, gauge
 
 logger = logging.getLogger(__name__)
 
@@ -552,6 +552,16 @@ class _EntityCoordinateBase:
         self.red: RandomEffectDataset = build_random_effect_dataset(
             dataset, config.data_config(
                 seed, keep_host_blocks=hbm_budget_bytes is not None))
+        # what the build did with the rows, under the coordinate's name:
+        # gauges for telemetry.snapshot(), the dict for the fit's summary
+        self.build_stats = self.red.build_counts
+        for key, value in self.build_stats.items():
+            if key != "buckets":
+                gauge(f"train.re_build.{name}.{key}").set(value)
+        for k, shape in enumerate(self.build_stats["buckets"]):
+            for key, value in zip(("entities", "samples", "real_rows"),
+                                  shape):
+                gauge(f"train.re_build.{name}.bucket{k}.{key}").set(value)
         self._flat_x = None
         self._proj_dev = None
         if hbm_budget_bytes is None:

@@ -66,6 +66,12 @@ class GameResult:
     # observable no-retransfer property bench --mesh gates.  None when the
     # fit ran without a multi-device mesh.
     mesh_transfer: Optional[dict] = None
+    # per entity-keyed coordinate, what its build did with the rows:
+    # entities, active / passive / discarded rows, capped entities, padded
+    # cells and bucket shapes (RandomEffectDataset.build_counts; the
+    # same numbers are the `train.re_build.<coordinate>.*` gauges)
+    coordinate_build: Dict[str, dict] = dataclasses.field(
+        default_factory=dict)
 
 
 class GameEstimator:
@@ -269,7 +275,11 @@ class GameEstimator:
                           checkpoint_recovery=(resume.recovery
                                                if resume is not None
                                                else None),
-                          mesh_transfer=mesh_transfer)
+                          mesh_transfer=mesh_transfer,
+                          coordinate_build={
+                              name: c.build_stats
+                              for name, c in coords.items()
+                              if hasattr(c, "build_stats")})
 
     def fit_grid(
         self,

@@ -179,10 +179,11 @@ def test_new_per_layer_entry_resolves_to_its_reader(name):
     (entry,) = [m for m in spec["per_layer"] if m["name"] == name]
     meta = load_module("layer_metrics", name).META
     assert meta == {k: entry[k] for k in ("name", "unit", "layer", "moves")}
-    assert entry["workloads"] == ["glmix-ml20m.fit"]
+    # later cells are appended to the list, as benchmark/README.md prescribes
+    assert entry["workloads"][0] == "glmix-ml20m.fit"
     assert entry["better"] == "lower"
-    # appended, not put among the accepted four
-    assert [m["name"] for m in spec["per_layer"][-6:]] == NEW_METRICS
+    # appended, not put among the accepted four; later PRs' follow
+    assert [m["name"] for m in spec["per_layer"][4:10]] == NEW_METRICS
 
 
 @pytest.mark.parametrize("name", _metric_files())

@@ -211,11 +211,11 @@ class TestBucketedBuild:
         ds = self._skewed_dataset(rng)
         red = build_random_effect_dataset(
             ds, RandomEffectDataConfig("per_user", "g", projector="identity"))
-        stats = red.padding_stats()
-        assert stats["num_buckets"] >= 2
+        stats = red.build_counts
+        assert len(stats["buckets"]) >= 2
         # single-S layout wastes >90% of cells on this skew; buckets fix it
-        assert stats["single_block_efficiency"] < 0.1
-        assert stats["bucketed_efficiency"] > 0.9
+        assert stats["active_rows"] < 0.1 * red.num_entities * red.max_samples
+        assert stats["active_rows"] > 0.9 * stats["cells"]
         # lanes are count-descending and cover all rows exactly once
         per_lane = (np.asarray(red.active_row_ids) >= 0).sum(axis=1)
         assert (np.diff(per_lane) <= 0).all()
@@ -275,7 +275,8 @@ class TestBucketedBuild:
             dtype=np.float32)
         dt = time.perf_counter() - t0
         assert red.num_entities <= E
-        assert red.padding_stats()["bucketed_efficiency"] > 0.5
+        assert red.build_counts["active_rows"] > \
+            0.5 * red.build_counts["cells"]
         assert dt < 60.0, f"1e6-entity build took {dt:.1f}s"
 
 
