@@ -15,8 +15,18 @@ aggregates per iteration — the whole optimization is one XLA program:
 
 Design notes
 ------------
-- Two-loop recursion over rolling [m, d] history buffers with a pair counter;
-  pairs with non-positive curvature s.y are skipped (standard safeguard).
+- Two-loop recursion over a history kept in AGE ORDER, one array a slot
+  (`_History`): slot 0 is the newest pair, a stored pair moves every slot
+  one older (`_push`, a `where` per slot), and step i of either loop reads
+  slot i by a static index.  Why not a ring in an [m, d] buffer: under vmap
+  lanes store different numbers of pairs, so a ring's slot differs by lane,
+  every read is a gather from and every write a scatter into an [E, m, d]
+  buffer, and the TPU tiles that over its two small dimensions (ten times
+  its bytes, streamed whole for each row taken: PERF.md, PR 28).  Here every
+  leaf is [E, d] like x, g and p.  The push costs a lane that is not
+  vmapped a copy of 2 m d numbers an iteration, beside two passes over the
+  data.  A pair counter says how many slots are valid; pairs with
+  non-positive curvature s.y are skipped (standard safeguard).
 - Backtracking Armijo line search on the *actual displacement* so box
   projection (clamp-to-hypercube each trial point, reference:
   OptimizationUtils.scala:40-70 projection used by LBFGS.scala:72) is
@@ -47,15 +57,22 @@ _MAX_LS = 30        # max backtracking halvings
 _CURV_EPS = 1e-12   # curvature-pair acceptance threshold
 
 
+class _History(NamedTuple):
+    """The last m curvature pairs, newest first: slot i of each tuple is the
+    pair stored i pushes ago.  One array a slot, so a slot is a static index
+    in every lane of a vmapped solve."""
+    s: Tuple[jax.Array, ...]     # m displacements, each [d]
+    y: Tuple[jax.Array, ...]     # m gradient differences, each [d]
+    rho: Tuple[jax.Array, ...]   # m scalars 1/(s.y)
+
+
 class _State(NamedTuple):
     k: jax.Array            # iteration counter
     x: jax.Array            # [d]
     f: jax.Array            # objective at x (incl. L1 term for OWLQN)
     g: jax.Array            # raw gradient at x (no L1)
-    s_buf: jax.Array        # [m, d] displacement history
-    y_buf: jax.Array        # [m, d] gradient-difference history
-    rho: jax.Array          # [m] 1/(s.y)
-    num_pairs: jax.Array    # pairs stored so far
+    hist: _History          # the pairs, slot 0 the newest
+    num_pairs: jax.Array    # pairs stored so far; slot i is valid below it
     f_small: jax.Array      # consecutive sub-tolerance f-changes
     ls_trials: jax.Array    # trial points evaluated: first trials + backtracks
     reason: jax.Array
@@ -82,39 +99,43 @@ def _pseudo_gradient(x, g, l1):
     return jnp.where(x != 0, gp, at_zero)
 
 
-def _two_loop(q, s_buf, y_buf, rho, num_pairs, m):
-    """Standard two-loop recursion with rolling buffers; slot i holds pair
-    (num_pairs-1-i) newest-first via modular indexing."""
+def _empty_history(m, d, dtype) -> _History:
+    zero, zeros = jnp.zeros((), dtype), jnp.zeros((d,), dtype)
+    return _History(s=(zeros,) * m, y=(zeros,) * m, rho=(zero,) * m)
 
-    def newest_first(i):
-        return (num_pairs - 1 - i) % m
 
-    def loop1(i, carry):
-        q, alphas = carry
-        j = newest_first(i)
-        valid = i < jnp.minimum(num_pairs, m)
-        a = jnp.where(valid, rho[j] * jnp.dot(s_buf[j], q), 0.0)
-        q = q - a * y_buf[j]
-        return q, alphas.at[i].set(a)
+def _push(hist: _History, store, s, y, sy) -> _History:
+    """Where `store`, (s, y, 1/sy) enters at slot 0, every slot moves one
+    older and the oldest leaves; elsewhere every slot keeps its value."""
+    def shift(slots, new):
+        return tuple(jnp.where(store, younger, slot)
+                     for younger, slot in zip((new,) + slots[:-1], slots))
 
-    q, alphas = lax.fori_loop(0, m, loop1, (q, jnp.zeros((m,), q.dtype)))
+    return _History(s=shift(hist.s, s), y=shift(hist.y, y),
+                    rho=shift(hist.rho, 1.0 / jnp.where(store, sy, 1.0)))
 
-    # H0 scaling from newest valid pair
-    jn = newest_first(0)
-    have = num_pairs > 0
-    sy = jnp.dot(s_buf[jn], y_buf[jn])
-    yy = jnp.dot(y_buf[jn], y_buf[jn])
-    gamma = jnp.where(have & (yy > 0), sy / jnp.where(yy > 0, yy, 1.0), 1.0)
+
+def _two_loop(q, hist: _History, num_pairs):
+    """Standard two-loop recursion; step i reads slot i, the pair stored i
+    pushes ago, which exists once more than i pairs were stored."""
+    m = len(hist.rho)
+    valid = [num_pairs > i for i in range(m)]
+    alphas = []
+    for i in range(m):              # newest first
+        a = jnp.where(valid[i], hist.rho[i] * jnp.dot(hist.s[i], q), 0.0)
+        q = q - a * hist.y[i]
+        alphas.append(a)
+
+    # H0 scaling from the newest pair
+    sy = jnp.dot(hist.s[0], hist.y[0])
+    yy = jnp.dot(hist.y[0], hist.y[0])
+    gamma = jnp.where(valid[0] & (yy > 0), sy / jnp.where(yy > 0, yy, 1.0), 1.0)
     r = gamma * q
 
-    def loop2(i, r):
-        ii = m - 1 - i  # oldest stored first
-        j = newest_first(ii)
-        valid = ii < jnp.minimum(num_pairs, m)
-        b = jnp.where(valid, rho[j] * jnp.dot(y_buf[j], r), 0.0)
-        return r + jnp.where(valid, alphas[ii] - b, 0.0) * s_buf[j]
-
-    return lax.fori_loop(0, m, loop2, r)
+    for i in reversed(range(m)):    # oldest stored first
+        b = jnp.where(valid[i], hist.rho[i] * jnp.dot(hist.y[i], r), 0.0)
+        r = r + jnp.where(valid[i], alphas[i] - b, 0.0) * hist.s[i]
+    return r
 
 
 def lbfgs(
@@ -139,11 +160,12 @@ def lbfgs(
     (reference: LBFGS.scala:72 + OptimizationUtils.scala:40-70); box and L1
     are mutually exclusive, as in the reference.
 
-    `max_iterations` is the STATIC ceiling: it sizes the history buffers
-    and bounds the compiled loop.  `iteration_cap` (and `tolerance`) may be
-    TRACED scalars — the loop condition tests the dynamic cap, so an
-    inexactness schedule that varies the budget per coordinate-descent
-    outer iteration reuses one compiled program (optim/schedule.py).
+    `max_iterations` is the STATIC ceiling: it sizes the loss and
+    gradient-norm histories and bounds the compiled loop.  `iteration_cap`
+    (and `tolerance`) may be TRACED scalars — the loop condition tests the
+    dynamic cap, so an inexactness schedule that varies the budget per
+    coordinate-descent outer iteration reuses one compiled program
+    (optim/schedule.py).
 
     How a trial point is evaluated.  Given `value_and_grad` every trial,
     the first and each backtrack, evaluates the FUSED value+gradient at
@@ -178,7 +200,6 @@ def lbfgs(
     if use_margins and (use_l1 or use_box):
         raise ValueError("the margin surface evaluates trial points x + t p; "
                          "the orthant and box projections are not affine in t")
-    m = history
     d = x0.shape[-1]
     dtype = x0.dtype
     l1 = jnp.asarray(l1_weight, dtype) if use_l1 else None
@@ -241,8 +262,8 @@ def lbfgs(
     init = _State(
         k=jnp.asarray(0, jnp.int32),
         x=x0, f=f0, g=g0,
-        s_buf=jnp.zeros((m, d), dtype), y_buf=jnp.zeros((m, d), dtype),
-        rho=jnp.zeros((m,), dtype), num_pairs=jnp.asarray(0, jnp.int32),
+        hist=_empty_history(history, d, dtype),
+        num_pairs=jnp.asarray(0, jnp.int32),
         f_small=jnp.asarray(0, jnp.int32),
         ls_trials=jnp.asarray(0, jnp.int32),
         reason=jnp.asarray(ConvergenceReason.NOT_CONVERGED, jnp.int32),
@@ -258,7 +279,7 @@ def lbfgs(
 
     def body(st: _State) -> _State:
         steer = steer_grad(st.x, st.g)
-        p = -_two_loop(steer, st.s_buf, st.y_buf, st.rho, st.num_pairs, m)
+        p = -_two_loop(steer, st.hist, st.num_pairs)
         if use_l1:
             # direction must agree with -pseudo-gradient sign-wise
             p = jnp.where(p * (-steer) > 0, p, 0.0)
@@ -339,10 +360,7 @@ def lbfgs(
             yv = jnp.where(bl, 0.0, yv)
         sy = jnp.dot(s, yv)
         store = ls_ok & (sy > _CURV_EPS)
-        slot = st.num_pairs % m
-        s_buf = jnp.where(store, st.s_buf.at[slot].set(s), st.s_buf)
-        y_buf = jnp.where(store, st.y_buf.at[slot].set(yv), st.y_buf)
-        rho = jnp.where(store, st.rho.at[slot].set(1.0 / jnp.where(store, sy, 1.0)), st.rho)
+        hist = _push(st.hist, store, s, yv, sy)
         num_pairs = st.num_pairs + jnp.where(store, 1, 0)
 
         gnorm_new = jnp.linalg.norm(steer_grad(x_new, g_new))
@@ -369,7 +387,7 @@ def lbfgs(
         k = st.k + 1
         return _State(
             k=k, x=x_new, f=f_new, g=g_new,
-            s_buf=s_buf, y_buf=y_buf, rho=rho, num_pairs=num_pairs,
+            hist=hist, num_pairs=num_pairs,
             f_small=f_small,
             ls_trials=st.ls_trials + 1 + ls_n,  # first trial + backtracks
             reason=reason,

@@ -11,18 +11,18 @@ the host schedules, the accelerator computes):
   * every oracle call (value+gradient, Hessian-vector) is one double-
     buffered pass over the chunk stream — chunk i+1 transfers while chunk i
     computes;
-  * optimizer STATE (iterate, gradient, [m, d] curvature buffers, CG
-    vectors) stays on device; the host only reads back the scalars it
-    branches on (line-search acceptance, convergence checks);
+  * optimizer STATE (iterate, gradient, the curvature pairs, CG vectors)
+    stays on device; the host only reads back the scalars it branches on
+    (line-search acceptance, convergence checks);
   * the update rules, constants, and convergence conditions mirror the
     resident solvers line for line — on a single-chunk plan the streamed
     solve follows the identical arithmetic, and fit-level parity vs the
     resident path is gated at ~1e-6 relative objective (the residual being
     chunk-order float summation).
 
-All jitted helpers here are keyed on [d]/[m, d] shapes only — never on the
-row count — so the compile-count regression (zero fresh traces across chunk
-counts) holds through the whole solve.
+All jitted helpers here are keyed on [d] shapes and the history length m
+only — never on the row count — so the compile-count regression (zero fresh
+traces across chunk counts) holds through the whole solve.
 """
 from __future__ import annotations
 
@@ -30,7 +30,6 @@ from __future__ import annotations
 # the documented exception to the batched-flush rule; the host reads back
 # exactly the scalars it branches on (see module docstring)
 
-import functools
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -41,7 +40,8 @@ from photon_ml_tpu.optim.config import (
     OptimizerConfig, OptimizerType, RegularizationContext,
 )
 from photon_ml_tpu.optim.lbfgs import (
-    _C1, _CURV_EPS, _F_CONV_PERSISTENCE, _MAX_LS, _pseudo_gradient, _two_loop,
+    _C1, _CURV_EPS, _F_CONV_PERSISTENCE, _MAX_LS, _empty_history,
+    _pseudo_gradient, _push, _two_loop,
 )
 from photon_ml_tpu.optim.tron import (
     _CG_RTOL, _ETA0, _ETA1, _ETA2, _MAX_FAILURES, _SIG1, _SIG2, _SIG3,
@@ -52,20 +52,18 @@ ValueAndGrad = Callable[[jax.Array], Tuple[jax.Array, jax.Array]]
 HessVec = Callable[[jax.Array, jax.Array], jax.Array]
 
 
-# -- [d]-shaped jitted steps (one trace per (d, m, dtype), never per n) ------
+# -- [d]-shaped jitted steps (one trace per (d, m, dtype), never per n): the
+# resident solver's history (optim/lbfgs.py: age order, one array a slot),
+# its two-loop and its push, each as one program ---------------------------
 
-@functools.partial(jax.jit, static_argnames=("m",))
-def _direction(steer, s_buf, y_buf, rho, num_pairs, *, m):
-    return -_two_loop(steer, s_buf, y_buf, rho, num_pairs, m)
+@jax.jit
+def _direction(steer, hist, num_pairs):
+    return -_two_loop(steer, hist, num_pairs)
 
 
 @jax.jit
-def _store_pair(s_buf, y_buf, rho, slot, s, yv, sy):
-    """Rolling-buffer insert with a TRACED slot (a python-int index would
-    compile one program per slot value)."""
-    return (jax.lax.dynamic_update_index_in_dim(s_buf, s, slot, 0),
-            jax.lax.dynamic_update_index_in_dim(y_buf, yv, slot, 0),
-            jax.lax.dynamic_update_index_in_dim(rho, 1.0 / sy, slot, 0))
+def _store_pair(hist, s, yv, sy):
+    return _push(hist, True, s, yv, sy)
 
 
 def _hist(values, length, dtype):
@@ -94,7 +92,7 @@ def host_lbfgs(
 
     `iteration_cap`/`tolerance` mirror the resident solver's dynamic
     budget: the loop is host-stepped so varying them never recompiles
-    anything (the jitted helpers are keyed on [d]/[m, d] shapes only);
+    anything (the jitted helpers are keyed on [d] shapes and m only);
     histories stay sized by the static `max_iterations` ceiling so result
     shapes are budget-independent."""
     use_l1 = l1_weight is not None
@@ -102,10 +100,8 @@ def host_lbfgs(
     if use_l1 and use_box:
         raise ValueError("L1 (OWLQN) and box constraints cannot be combined "
                          "(the reference has no such solver either)")
-    m = history
     x0 = jnp.asarray(x0)
     dtype = x0.dtype
-    d = x0.shape[-1]
     l1 = jnp.asarray(l1_weight, dtype) if use_l1 else None
 
     def project_box(x):
@@ -146,9 +142,7 @@ def host_lbfgs(
     gnorm = float(jnp.linalg.norm(steer_grad(x, g)))
     gtol = tolerance * max(gnorm, 1.0)
 
-    s_buf = jnp.zeros((m, d), dtype)
-    y_buf = jnp.zeros((m, d), dtype)
-    rho = jnp.zeros((m,), dtype)
+    hist = _empty_history(history, x0.shape[-1], dtype)
     num_pairs = 0
     f_small = 0
     fg_count = 1
@@ -159,8 +153,7 @@ def host_lbfgs(
 
     while k < cap and reason == ConvergenceReason.NOT_CONVERGED:
         steer = steer_grad(x, g)
-        p = _direction(steer, s_buf, y_buf, rho,
-                       jnp.asarray(num_pairs, jnp.int32), m=m)
+        p = _direction(steer, hist, jnp.asarray(num_pairs, jnp.int32))
         if use_l1:
             p = jnp.where(p * (-steer) > 0, p, 0.0)
             orthant = jnp.where(x != 0, jnp.sign(x), jnp.sign(-steer))
@@ -203,9 +196,7 @@ def host_lbfgs(
             yv = jnp.where(bl, 0.0, yv)
         sy = jnp.dot(s, yv)
         if ls_ok and float(sy) > _CURV_EPS:
-            s_buf, y_buf, rho = _store_pair(
-                s_buf, y_buf, rho, jnp.asarray(num_pairs % m, jnp.int32),
-                s, yv, sy)
+            hist = _store_pair(hist, s, yv, sy)
             num_pairs += 1
 
         if ls_ok:

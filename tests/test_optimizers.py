@@ -394,7 +394,7 @@ def test_line_search_body_reads_features_only_off_the_margin_path(path, reads_x)
     """The inner `while` is the line search.  On the margin path no operand
     of the feature block's shape goes into it; under L1 and under a box the
     fused value+gradient of every trial does."""
-    S, d = 37, 5      # unlike every other shape of the solve ([m, d], [101])
+    S, d = 37, 5      # unlike every other shape of the solve ([d], [101])
     obj = _margin_objective("logistic", "dense", "plain", jnp.float64, n=S, d=d)
     cfg, reg = OptimizerConfig(), _L2
     if path == "l1":
@@ -467,3 +467,159 @@ def test_l1_and_box_solves_run_the_generic_path_bit_for_bit(rng):
     with pytest.raises(ValueError, match="not affine"):
         lbfgs(obj.value_and_gradient, jnp.zeros(6), l1_weight=1.0,
               margin_surface=obj)
+
+
+# -- the history in age order, one array a slot (PR 28) ------------------------
+# optim/lbfgs.py keeps the last m pairs newest first in m [d] arrays and shifts
+# them by `where` on a push.  The oracle is the ring it replaced: [m, d]
+# buffers, slot num_pairs % m, the two-loop reading (num_pairs - 1 - i) % m.
+
+def _ring_two_loop(q, s_buf, y_buf, rho, num_pairs, m):
+    q = q.copy()
+    alphas = np.zeros(m)
+    stored = min(num_pairs, m)
+    for i in range(stored):
+        j = (num_pairs - 1 - i) % m
+        alphas[i] = rho[j] * (s_buf[j] @ q)
+        q = q - alphas[i] * y_buf[j]
+    gamma = 1.0
+    if num_pairs > 0:
+        jn = (num_pairs - 1) % m
+        yy = y_buf[jn] @ y_buf[jn]
+        if yy > 0:
+            gamma = (s_buf[jn] @ y_buf[jn]) / yy
+    r = gamma * q
+    for i in reversed(range(stored)):
+        j = (num_pairs - 1 - i) % m
+        b = rho[j] * (y_buf[j] @ r)
+        r = r + (alphas[i] - b) * s_buf[j]
+    return r
+
+
+def _push_schedule(pushes, m):
+    """`pushes` stored pairs, with a skipped pair before the first, in the
+    middle, and (where the ring wraps) just as slot 0 is about to be reused."""
+    flags = [True] * pushes
+    skips = {0, pushes // 2} | ({m} if pushes > m else set())
+    for at in sorted(skips, reverse=True):
+        flags.insert(at, False)
+    return flags
+
+
+@pytest.mark.parametrize("pushes", ["none", "fewer", "exactly_m", "wrapped"])
+@pytest.mark.parametrize("d", [1, 21])
+@pytest.mark.parametrize("m", [1, 3, 10])
+def test_age_ordered_history_matches_a_ring_buffer_two_loop(m, d, pushes):
+    from photon_ml_tpu.optim.lbfgs import _empty_history, _push, _two_loop
+    n = {"none": 0, "fewer": m - 1, "exactly_m": m, "wrapped": 2 * m + 3}[pushes]
+    rng = np.random.default_rng(1000 * m + 10 * d + n)
+    hist = _empty_history(m, d, jnp.float64)
+    push = jax.jit(_push)
+    direction = jax.jit(_two_loop)
+    s_buf, y_buf, rho, num_pairs = np.zeros((m, d)), np.zeros((m, d)), np.zeros(m), 0
+
+    def same_direction():
+        q = rng.normal(size=d)
+        want = _ring_two_loop(q, s_buf, y_buf, rho, num_pairs, m)
+        got = direction(jnp.asarray(q), hist, jnp.asarray(num_pairs, jnp.int32))
+        np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-12)
+
+    same_direction()
+    for store in _push_schedule(n, m):
+        s = rng.normal(size=d)
+        y = rng.uniform(0.5, 2.0, size=d) * s + 0.05 * rng.normal(size=d)
+        sy = s @ y      # a skipped pair is a real pair: only `store` keeps it out
+        hist = push(hist, jnp.asarray(store), jnp.asarray(s), jnp.asarray(y),
+                    jnp.asarray(sy))
+        if store:
+            slot = num_pairs % m
+            s_buf[slot], y_buf[slot], rho[slot] = s, y, 1.0 / sy
+            num_pairs += 1
+        same_direction()
+    assert num_pairs == n
+    assert all(leaf.shape == (d,) for leaf in hist.s + hist.y)
+    assert all(leaf.shape == () for leaf in hist.rho)
+
+
+def _huber_lane(center, scales):
+    """sum_j scales_j huber(x_j - center_j): beyond 1 of the centre the
+    gradient is constant, so a step that stays out there has y = 0 exactly
+    and its pair is skipped."""
+    def vg(x):
+        r = x - center
+        quad = jnp.abs(r) <= 1.0
+        return (jnp.sum(scales * jnp.where(quad, 0.5 * r * r, jnp.abs(r) - 0.5)),
+                scales * jnp.where(quad, r, jnp.sign(r)))
+    return vg
+
+
+def test_vmapped_lanes_that_store_different_numbers_of_pairs_match_their_own_solves():
+    d, lanes, far = 6, 5, 3
+    rng = np.random.default_rng(3)
+    centers = rng.uniform(-0.8, 0.8, size=(lanes, d))
+    centers[far] = rng.uniform(4.0, 6.0, size=d)   # flat for its first steps
+    scales = rng.uniform(0.5, 3.0, size=(lanes, d))
+    centers, scales = jnp.asarray(centers, jnp.float32), jnp.asarray(scales, jnp.float32)
+
+    def solve_one(c, a):
+        return lbfgs(_huber_lane(c, a), jnp.zeros(d, jnp.float32),
+                     max_iterations=60, tolerance=1e-5, track_coefficients=True)
+
+    batched = jax.jit(jax.vmap(solve_one))(centers, scales)
+    single = jax.jit(solve_one)
+    stored = []
+    for i in range(lanes):
+        one = single(centers[i], scales[i])
+        k = int(one.iterations)
+        assert k == int(batched.iterations[i])
+        assert int(one.ls_trials) == int(batched.ls_trials[i])
+        assert int(one.reason) == int(batched.reason[i])
+        np.testing.assert_allclose(batched.x[i], one.x, rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(one.x, centers[i], atol=1e-4)
+        xs = np.asarray(one.coefficient_history[:k + 1], np.float64)
+        gs = np.asarray(jax.vmap(lambda x: _huber_lane(centers[i], scales[i])(x)[1])(
+            one.coefficient_history[:k + 1]), np.float64)
+        sy = np.sum(np.diff(xs, axis=0) * np.diff(gs, axis=0), axis=1)
+        stored.append((int(np.sum(sy > 1e-12)), k))
+    pairs, its = zip(*stored)
+    assert pairs[far] < its[far]                 # the far lane did skip
+    assert all(p == k for j, (p, k) in enumerate(stored) if j != far)
+    assert len(set(pairs)) > 1                   # and the lanes differ
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def test_vmapped_solve_body_indexes_no_history_and_holds_no_slot_axis():
+    """Under vmap every lane has its own pair count, so anything indexed by
+    it is a per-lane gather or scatter.  The loop body of the vmapped solve
+    has those on the [E, max_iterations + 1] loss and gradient-norm
+    histories alone, and no array with a slot axis beside lanes and width."""
+    E, S, d, m, cap = 6, 11, 5, 3, 17      # no two shapes of the solve alike
+    objs = [_margin_objective("logistic", "dense", "plain", jnp.float32, n=S, d=d)
+            for _ in range(E)]
+    xb = jnp.stack([o.x for o in objs])
+    yb = jnp.stack([o.labels for o in objs])
+    cfg = OptimizerConfig(max_iterations=cap, history=m)
+
+    def solve_one(x, y):
+        return solve(GLMObjective(LOGISTIC, x, y), jnp.zeros(d, jnp.float32),
+                     cfg, _L2, 0.5)
+
+    jaxpr = jax.make_jaxpr(jax.vmap(solve_one))(xb, yb).jaxpr
+    (depth, loop), *_ = _while_eqns(jaxpr)
+    assert depth == 0
+    body = loop.params["body_jaxpr"].jaxpr
+    shapes = {v.aval.shape for e in _eqns(body) for v in (*e.invars, *e.outvars)
+              if hasattr(v, "aval")}
+    assert (E, d) in shapes and (E, S, d) in shapes     # it is the solve's body
+    assert not [s for s in shapes if len(s) >= 3 and m in s[1:]]
+    assert not [s for s in shapes if sorted(s) == sorted((E, m))]
+    indexed = [(e.primitive.name, e.invars[0].aval.shape) for e in _eqns(body)
+               if e.primitive.name.startswith(("gather", "scatter", "dynamic_"))]
+    assert indexed, "loss_hist and gnorm_hist are written at a per-lane k"
+    assert {shape for _, shape in indexed} == {(E, cap + 1)}, indexed

@@ -19,7 +19,9 @@ conftest.py, not autouse — everything compiles in the test's own process,
 and these tests stay in this one file: a process keeps the TPU library, and
 its lock, until it exits.
 """
+import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -39,6 +41,8 @@ from photon_ml_tpu.parallel.mesh import DATA_AXIS, FEATURE_AXIS
 F32 = jnp.float32
 L2 = RegularizationContext(RegularizationType.L2)
 GLMIX_ROWS, D_GLOBAL, D_USER, USERS = 950_051, 31, 19, 6040
+# the benchmark's largest per-user bucket (glmix-ml20m, both cells): E x S x d
+ML20M_BIG_BUCKET = (30_518, 512, 21)
 CONFIG1_SHAPE = (1_643_520, 124)
 V5E_HBM_BYTES = 16 * 1024 ** 3
 
@@ -121,22 +125,65 @@ def test_fixed_effect_solve_compiles(one_chip, shape, optimizer):
     assert args >= n * d * 4          # the design matrix is an argument
 
 
+def _bucket_solve(one_chip, E, S, d, config):
+    """`jit_re_bucket_solve` (parallel/random_effect._cached_batched_solver)
+    compiled for one E x S x d bucket with weights and offsets, as the
+    trainer calls it; float32 and within the chip's memory."""
+    from photon_ml_tpu.parallel.random_effect import _cached_batched_solver
+    cells = _sds((E, S), F32, one_chip)
+    solver = _cached_batched_solver(LOGISTIC, config, L2, True, True)
+    compiled = solver.lower(
+        _sds((E, S, d), F32, one_chip), cells, cells, cells, cells,
+        _sds((E, d), F32, one_chip), _sds((), F32, one_chip), None).compile()
+    _assert_float32(compiled)
+    _fits(compiled)
+    return compiled
+
+
 @pytest.mark.parametrize("entities,samples",
                          [(3663, 512), (2232, 64), (141, 8), (2, 1)])
 def test_random_effect_bucket_solve_compiles(one_chip, entities, samples):
     """The vmapped per-entity solver (parallel/random_effect.
     _cached_batched_solver) at every S-bucket of the GLMix fit: rows capped
     at active_data_upper_bound=512, per-user width 19."""
-    from photon_ml_tpu.parallel.random_effect import _cached_batched_solver
-    E, S, d = entities, samples, D_USER
-    cells = _sds((E, S), F32, one_chip)
-    solver = _cached_batched_solver(
-        LOGISTIC, OptimizerConfig(max_iterations=100), L2, True, True)
-    compiled = solver.lower(
-        _sds((E, S, d), F32, one_chip), cells, cells, cells, cells,
-        _sds((E, d), F32, one_chip), _sds((), F32, one_chip), None).compile()
-    _assert_float32(compiled)
-    _fits(compiled)
+    _bucket_solve(one_chip, entities, samples, D_USER,
+                  OptimizerConfig(max_iterations=100))
+
+
+def _hbm_bytes(dims, minor_to_major, tile):
+    """Bytes of a float32 array as the TPU lays it out: the two minor-most
+    dimensions padded to the tile."""
+    by_minor = [dims[i] for i in minor_to_major]
+    for k, t in enumerate(reversed(tile)):
+        by_minor[k] = -(-by_minor[k] // t) * t
+    return 4 * math.prod(by_minor)
+
+
+def test_random_effect_big_bucket_solve_holds_no_slot_axis(one_chip):
+    """`jit_re_bucket_solve` at the benchmark's big bucket, history m = 10.
+    A history indexed by a per-lane pair count was an [E, m, d] buffer that
+    this compiler tiles T(8,128) over [m, d]: ten times its bytes, streamed
+    whole for every row taken from it (PERF.md, PR 28).  The program holds no
+    array with the slot axis beside lanes and width, in any order, and every
+    [E, d] vector of the solve (x, g, p, the 2 m history leaves) costs less
+    than twice its real bytes."""
+    E, S, d = ML20M_BIG_BUCKET
+    config = OptimizerConfig(max_iterations=100)
+    m = config.history
+    text = _bucket_solve(one_chip, E, S, d, config).as_text()
+    shapes = {tuple(map(int, dims.split(",")))
+              for dims in re.findall(r"\b[a-z]+[0-9]*\[([0-9,]+)\]", text)}
+    assert (E, S, d) in shapes and (E, d) in shapes     # the parser does read
+    assert not [s for s in shapes if sorted(s) == sorted((E, m, d))]
+    assert not [s for s in shapes if len(s) >= 3 and E in s and m in s]
+    vectors = re.findall(
+        rf"f32\[({E},{d}|{d},{E})\]\{{([0-9,]+):T\(([0-9]+),([0-9]+)\)", text)
+    assert len(vectors) >= 2 * m
+    for dims, order, *tile in vectors:
+        dims = tuple(map(int, dims.split(",")))
+        padded = _hbm_bytes(dims, tuple(map(int, order.split(","))),
+                            tuple(map(int, tile)))
+        assert padded <= 2 * 4 * E * d, (dims, order, tile)
 
 
 @pytest.mark.parametrize("bucket", [8, 1024], ids=["smallest", "largest"])
