@@ -5,9 +5,9 @@
     python chip_smoke.py --multichip     # four chips: mesh fit vs one-device fit
 
 Default run, one chip — the GLMix deployment the repo calls its flagship
-(BASELINE.json config 4; bench.py `_game_setup`, mode "glmix"):
+(BASELINE.json config 4, as the benchmark's `glmix-ml20m` trains it):
 
-  data   MovieLens-1M shape from photon_ml_tpu/data/synthetic_bench.py
+  data   MovieLens-1M shape from photon_ml_tpu.data.synthetic_bench
          (1,000,209 rows, 6,040 users, global width 31, per-user width 19),
          made from --seed and written as the Avro part files cli.train
          reads, plus a 5% validation file from the same seed.
@@ -68,7 +68,7 @@ REDUCED_AUC_BAR = 0.55
 # the TPU's multi-pass float32 matmul
 SCORE_ATOL, SCORE_RTOL = 2e-4, 2e-4
 # float32 objective histories, mesh vs one device: the psum changes the
-# summation order only (bench.py's GAME parity gate is the same 1e-4)
+# summation order only (the benchmark's objective check is the same 1e-4)
 OBJECTIVE_RTOL = 1e-4
 SCORE_BATCH_ROWS = 1324        # 1024 + 300: buckets 1024 and 512
 # a mesh peer's live bytes as a share of the one-device fit's: a quarter of
@@ -151,7 +151,7 @@ def _write_part(seed: int, rows: int, work: str, part: int) -> None:
     maps = {k: IndexMap.from_keys(
         [feature_key(f"{k}{j:04d}") for j in range(shards[k].shape[1] - 1)])
         for k in ("global", "per_user")}
-    # the deterministic 95/5 split bench.py's _game_setup makes
+    # a deterministic 95/5 split, drawn from seed + 99 as the benchmark's is
     val_mask = np.random.default_rng(seed + 99).uniform(size=rows) < 0.05
     if part < TRAIN_PARTS:
         take = np.array_split(np.flatnonzero(~val_mask), TRAIN_PARTS)[part]
@@ -194,8 +194,10 @@ def phase_data(seed: int, rows: int, work: str) -> dict:
 # -- phase: train -------------------------------------------------------------
 
 def _glmix_config(seed: int) -> dict:
-    """bench.py `_game_setup(mode="glmix")` as GameTrainingConfig JSON: FE +
-    per-user RE, LBFGS (100 iterations), L2 weight 1, 2 outer iterations."""
+    """The training configuration of the benchmark's `glmix-ml20m` as
+    GameTrainingConfig JSON (tests/test_chip_smoke.py holds the two equal):
+    FE + per-user RE, LBFGS (100 iterations), cap 512, L2 weight 1, 2 outer
+    iterations."""
     opt = {"optimizer": {"optimizer": "lbfgs", "max_iterations": 100},
            "regularization": {"type": "l2"}, "regularization_weight": 1.0}
     return {
@@ -601,7 +603,7 @@ def run(args) -> dict:
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     p.add_argument("--seed", type=int, default=11,
-                   help="data seed (bench.py config 4 uses 11)")
+                   help="data seed")
     p.add_argument("--rows", type=int, default=FULL_ROWS,
                    help="corpus rows before the 95/5 split (cutting it is "
                         "said on a printed line; widths and users stay)")

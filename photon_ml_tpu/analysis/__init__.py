@@ -4,8 +4,8 @@ Every performance and robustness property this repo ships — zero fresh XLA
 traces when warm, exactly one batched `jax.device_get` flush per outer
 iteration, copy-before-donate aliasing guards, fsync+atomic-replace
 checkpoint writes, string-literal fault sites — is an invariant the code
-states in prose and the benches gate after the fact.  This package checks
-them at diff time, over every file, including paths no bench exercises.
+states in prose and the tests hold after the fact.  This package checks
+them at diff time, over every file, including paths no test exercises.
 
     python -m photon_ml_tpu.analysis.lint photon_ml_tpu/
 

@@ -16,7 +16,7 @@ Precision over recall: every check is anchored to the module semantics the
 engine resolved (import aliases, wrapper forms, device-value tracking), so
 a finding is worth reading.  What a rule cannot see statically (values
 flowing through unannotated call results, factory-returned solvers) it
-stays silent on — the compile-count and parity benches remain the backstop
+stays silent on — the compile-count and parity tests remain the backstop
 for those.
 """
 from __future__ import annotations
@@ -581,7 +581,7 @@ class RawTimerRule(Rule):
                     "phases through telemetry (PhaseTimings.span / "
                     ".blocked, or telemetry.timings.clock) so the span "
                     "lands in the unified trace instead of a bespoke "
-                    "counter the bench can't correlate"))
+                    "counter nothing else can correlate"))
         return findings
 
 

@@ -1,8 +1,8 @@
 """Out-of-core chunk streaming: host shards -> double-buffered device chunks.
 
 The resident training path requires every coordinate's data on the
-accelerator for the whole fit; bench config 5 documents that 5M MovieLens
-rows exhaust HBM with all four coordinates resident.  Snap ML
+accelerator for the whole fit, which a corpus larger than one chip's HBM cannot
+give.  Snap ML
 (arXiv:1803.06333) and "Large-Scale Stochastic Learning using GPUs"
 (arXiv:1702.07005) both recover near-resident throughput on datasets larger
 than device memory with hierarchical memory management + pipelined
@@ -210,7 +210,8 @@ class StreamStats:
         # chunk when the stochastic lane pins the chunk for K local
         # epochs) and examples processed (real rows x epochs).  The ratio
         # examples_processed / total_bytes is THE out-of-core efficiency
-        # number — bench --stoch gates its improvement.
+        # number (tests/test_stochastic.py holds the stochastic lane to
+        # 1.5x the strict lane's).
         self.local_epochs = 0
         self.examples_processed = 0
 

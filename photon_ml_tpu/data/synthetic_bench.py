@@ -1,22 +1,23 @@
-"""Statistically-matched synthetic replicas of the benchmark corpora.
+"""Statistically-matched synthetic replicas of public corpora.
 
 The BASELINE configs name two public datasets (a1a, MovieLens-1M/20M) that
 cannot be fetched in this environment (zero network egress).  These
 generators produce seeded replicas matched to the corpora's published shape
-statistics, and every bench result produced from them is labelled
-`data: "synthetic-replica"` in the JSON so the numbers are never mistaken
-for real-corpus runs.
+statistics; whatever reports a result on them says so (`chip_smoke.py`
+calls `make_movielens_like`; `benchmark/configs/a1a-dense.json` describes
+its data by `make_a1a_like`; the benchmark's accepted cells build their own
+data under `benchmark/builders/`).
 
 a1a (LIBSVM adult): n=1605 train rows, d=123 binary one-hot features,
 density ~0.115 (a1a stores ~14 active features per row of 123), ~24%
 positive labels.  Replicated `replicas`x row-wise for throughput-scale
-benchmarks (the reference bench path feeds a1a through
+runs (the reference feeds a1a through
 dev-scripts/libsvm_text_to_trainingexample_avro.py + run_photon_ml_driver.sh).
 
 MovieLens-1M: 1,000,209 ratings, 6040 users, 3706 movies, 18 genres;
 user activity is heavy-tailed (min 20, median ~96, max 2314 ratings/user).
 MovieLens-20M: 20,000,263 ratings, 138,493 users, 26,744 movies, 20 genre
-tags (19 + "(no genres listed)").  The GLMix bench task is the KDD'16 paper
+tags (19 + "(no genres listed)").  The GLMix task is the KDD'16 paper
 setup: binarized response (rating >= 4), fixed effect on global features,
 per-user (and per-item) random effects — so the generator plants a true
 mixed-effect structure: a global weight vector plus per-user/per-item
@@ -30,11 +31,6 @@ import dataclasses
 from typing import Dict, Optional
 
 import numpy as np
-
-# Bump whenever any generator in this module changes its output for a given
-# seed.  bench.py folds this into its reference-optimum cache keys so a
-# generator change can never silently reuse stale float64 reference NLLs.
-GENERATOR_VERSION = "g2"
 
 
 def make_a1a_features(replicas: int = 1, seed: int = 42,
@@ -163,80 +159,3 @@ def make_movielens_like(
 def movielens_shards(ml: MovieLensLike) -> Dict[str, np.ndarray]:
     return {"global": ml.x_global, "per_user": ml.x_user,
             "per_item": ml.x_item}
-
-
-def make_wide_sparse_logistic(n: int, d: int = 250_000, nnz: int = 64,
-                              seed: int = 77):
-    """Wide sparse logistic fixture: [n, d] binary CSR with `nnz` active
-    features per row (hashed-feature shape; reference: the >200k-feature
-    depth-switch regime, GameEstimator.scala:667-669) + labels from a
-    planted sparse GLM.  Column d-1 is the intercept."""
-    import scipy.sparse as sp
-    rng = np.random.default_rng(seed)
-    rows = np.repeat(np.arange(n), nnz)
-    cols = rng.integers(0, d - 1, size=n * nnz)
-    x = sp.coo_matrix((np.ones(n * nnz, np.float32), (rows, cols)),
-                      shape=(n, d)).tocsr()
-    x.sum_duplicates()
-    x.data[:] = 1.0                      # binary features, exact in bf16
-    icpt = sp.csr_matrix(np.ones((n, 1), np.float32))
-    x = sp.hstack([x[:, :d - 1], icpt]).tocsr()
-    w = (rng.normal(size=d) * (0.35 / np.sqrt(nnz))).astype(np.float64)
-    z = x.astype(np.float64) @ w
-    y = (rng.uniform(size=n) < 1.0 / (1.0 + np.exp(-z))).astype(np.float32)
-    return x, y
-
-
-@dataclasses.dataclass
-class YahooLike:
-    """Yahoo!-Music-fixture-shaped GAME data: a WIDE sparse global shard
-    (the DriverTest e2e asserts 14,983 fixed-effect coefficients,
-    photon-client/src/integTest/.../DriverTest.scala:96-98) + narrow dense
-    per-user / per-item shards."""
-
-    user_ids: np.ndarray
-    item_ids: np.ndarray
-    response: np.ndarray
-    x_global: object          # [n, d_global] scipy CSR
-    x_user: np.ndarray        # [n, d_user] float32
-    x_item: np.ndarray        # [n, d_item] float32
-    num_users: int
-    num_items: int
-
-
-def make_yahoo_like(n_rows: int, d_global: int = 14_983, nnz_global: int = 24,
-                    num_users: int = 2_000, num_items: int = 10_000,
-                    d_user: int = 21, d_item: int = 21,
-                    seed: int = 23) -> YahooLike:
-    """FE (wide sparse) + per-user RE + per-item RE logistic fixture at the
-    Yahoo integration-test shape."""
-    import scipy.sparse as sp
-    rng = np.random.default_rng(seed)
-    n = int(n_rows)
-    user_ids = rng.integers(0, num_users, size=n).astype(np.int32)
-    item_ids = rng.integers(0, num_items, size=n).astype(np.int32)
-
-    rows = np.repeat(np.arange(n), nnz_global)
-    cols = rng.integers(0, d_global - 1, size=n * nnz_global)
-    xg = sp.coo_matrix((np.ones(n * nnz_global, np.float32), (rows, cols)),
-                       shape=(n, d_global)).tocsr()
-    xg.sum_duplicates()
-    xg.data[:] = 1.0
-    icpt = sp.csr_matrix(np.ones((n, 1), np.float32))
-    xg = sp.hstack([xg[:, :d_global - 1], icpt]).tocsr()
-
-    xu = rng.normal(size=(n, d_user)).astype(np.float32)
-    xu[:, -1] = 1.0
-    xi = rng.normal(size=(n, d_item)).astype(np.float32)
-    xi[:, -1] = 1.0
-
-    w_g = (rng.normal(size=d_global) * (0.4 / np.sqrt(nnz_global)))
-    w_u = rng.normal(size=(num_users, d_user)) * 0.5
-    w_i = rng.normal(size=(num_items, d_item)) * 0.3
-    z = xg.astype(np.float64) @ w_g
-    z = z + np.einsum("nd,nd->n", xu.astype(np.float64), w_u[user_ids])
-    z = z + np.einsum("nd,nd->n", xi.astype(np.float64), w_i[item_ids])
-    response = (rng.uniform(size=n) < 1.0 / (1.0 + np.exp(-z))).astype(np.float32)
-    return YahooLike(user_ids=user_ids, item_ids=item_ids, response=response,
-                     x_global=xg, x_user=xu, x_item=xi,
-                     num_users=num_users, num_items=num_items)

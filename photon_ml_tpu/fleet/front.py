@@ -160,7 +160,7 @@ class Front:
         """`publisher_url` names the replica that accepts model-state
         changes (/feedback, /swap, /rollback); defaults to the first URL.
         `start_probes=False` keeps probing manual (`probe_once()`) for
-        tests and the bench."""
+        tests."""
         if not replica_urls:
             raise ValueError("a front needs at least one replica URL")
         if config.degraded_policy not in ("partial", "error"):

@@ -219,7 +219,7 @@ class Replica:
     `join()` is the catch-up path (returns only when the replica is
     bit-identical with the log head and warmed); `start()` runs the
     background poll loop; `poll_once()` is one tail-apply cycle (tests
-    and the bench drive it directly for determinism)."""
+    drive it directly for determinism)."""
 
     def __init__(self, service, log: ReplicationLog, state_dir: str,
                  config: ReplicaConfig = ReplicaConfig()):
@@ -619,9 +619,12 @@ class Replica:
             except Exception as e:  # the loop must never die silently
                 logger.exception("replica poll cycle failed: %s", e)
 
-    def close(self, timeout: float = 5.0) -> None:
+    def close(self) -> None:
+        """Stop the poll loop and wait for its thread: it ends after the
+        apply in flight, and a process must not exit while it is inside
+        XLA (see `OnlineUpdater.close`)."""
         self._closed.set()
         with self._lock:
             thread, self._thread = self._thread, None
         if thread is not None:
-            thread.join(timeout=timeout)
+            thread.join()

@@ -26,8 +26,8 @@ row (own coordinate included), `o_s + x_s . c0` is just `margin + base
 offset` — no per-coordinate margin decomposition is needed.
 
 Also here: per-entity sub-dataset extraction (carve the rows of a set of
-entities out of a GameDataset) and the OFFLINE refit reference that the
-bench's parity gate compares the online path against — it goes through the
+entities out of a GameDataset) and the OFFLINE refit reference that
+tests/test_online.py compares the online path against — it goes through the
 training-side dataset build (`build_random_effect_dataset`), i.e. a
 genuinely different block-construction path arriving at the same optimum.
 """

@@ -63,12 +63,6 @@ class ValidationSpec:
         return self.evaluator(s, dataset.response, dataset.weights)
 
 
-# PhaseTimings lives in telemetry/timings.py now (photonlint PH007: hot
-# modules route span timing through telemetry); re-imported above so
-# `from photon_ml_tpu.game.coordinate_descent import PhaseTimings` keeps
-# working for bench.py and the tests.
-
-
 @functools.partial(jax.jit, static_argnames=("loss",))
 def _data_term(total_scores, base_offsets, labels, weights, *, loss):
     """Weighted data-loss sum as ONE compiled program (a single device
@@ -115,8 +109,8 @@ class TrackerSummary:
     # parallel/mesh_residency.py TransferStats delta): cold = static
     # coordinate data (first visit / post-eviction re-stream), warm =
     # per-visit operands (offsets, x0).  A warm steady-state mesh visit
-    # must stage ZERO cold bytes — bench --mesh and the transfer
-    # regression test gate on this.  None on non-mesh fits.
+    # must stage ZERO cold bytes (tests/test_mesh_residency.py::
+    # test_warm_iterations_stage_zero_cold_bytes).  None on non-mesh fits.
     staged_bytes: Optional[Dict[str, int]] = None
     # fresh XLA traces observed during THIS visit (telemetry's compile
     # watch, the runtime counterpart of photonlint PH002): a warm fit must

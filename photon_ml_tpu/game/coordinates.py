@@ -233,7 +233,7 @@ class FixedEffectCoordinate:
                               np.asarray(a, dtype=self._canonical))
             # ONE persistent chunked objective: per-update residual offsets
             # swap in via replace() (prefetcher stats accumulate across the
-            # fit for the bench's transfer accounting).  Under a mesh each
+            # fit for the transfer accounting in `solver_diagnostics()`).  Under a mesh each
             # staged chunk shards rows over the "data" axis and GSPMD
             # inserts the accumulation psums.
             self._stream = ChunkedGLMObjective(

@@ -54,7 +54,7 @@ class GameResult:
     validation_specs: List[ValidationSpec] = dataclasses.field(default_factory=list)
     # HBM residency accounting (ResidencyManager.accounting()): budget,
     # per-coordinate block bytes, eviction count, tracked peak — the
-    # memory_stats() stand-in bench --stream and the peak-memory test read
+    # memory_stats() stand-in the peak-memory tests read
     residency: Optional[dict] = None
     # how the fit's checkpoint was recovered at resume time (CheckpointState
     # .recovery: fallback flag, pruned partial writes, resumed iteration);
@@ -63,7 +63,7 @@ class GameResult:
     # mesh transfer accounting over this fit (TransferStats delta from
     # parallel/mesh_residency.py): bytes staged cold (static coordinate
     # data, once per residency) vs warm (per-visit offsets/x0) — the
-    # observable no-retransfer property bench --mesh gates.  None when the
+    # observable no-retransfer property (tests/test_mesh_residency.py).  None when the
     # fit ran without a multi-device mesh.
     mesh_transfer: Optional[dict] = None
     # per entity-keyed coordinate, what its build did with the rows:
@@ -125,7 +125,7 @@ class GameEstimator:
 
     def _residency_manager(self, coords, dataset: GameDataset):
         """HBM residency bookkeeping (game/residency.py): always built so
-        bench/tests get byte accounting; it only EVICTS when
+        summaries and tests get byte accounting; it only EVICTS when
         hbm_budget_bytes is set and the coordinates' resident blocks bust
         it."""
         import jax as _jax

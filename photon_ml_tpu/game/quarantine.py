@@ -34,7 +34,7 @@ Three pieces, by design all batched or rare:
     `TrackerSummary.containment`, `solver_diagnostics()`, and the fit
     summary.
   * `poison_model` — the fault-injection hook's corruption (site
-    "solve.poison"): multiplies the solve result by NaN so the chaos bench
+    "solve.poison"): multiplies the solve result by NaN so that tests/test_faults.py
     can prove the quarantine recovers the fault-free trajectory.
 
 Objective-only divergence (finite coefficients, non-finite data term) is

@@ -1,9 +1,8 @@
 """HBM residency budget: decide what lives on device, evict what doesn't fit.
 
 The resident trainer pins EVERY coordinate's device blocks (FE feature
-shards, RE EntityBlocks) for the whole fit; bench config 5 documents the
-consequence — 5M MovieLens rows exhaust a single chip's HBM with four
-coordinates resident.  With a budget (GameTrainingConfig.hbm_budget_bytes /
+shards, RE EntityBlocks) for the whole fit, so a corpus whose coordinates exceed one chip's HBM
+cannot train (ROADMAP R0: no chip record of the budgeted path yet).  With a budget (GameTrainingConfig.hbm_budget_bytes /
 --hbm-budget) this manager applies the hierarchy Snap ML's memory manager
 describes (arXiv:1803.06333):
 
@@ -43,8 +42,8 @@ data on a D-chip mesh.
 
 The manager also keeps the transfer-size accounting (`peak_tracked_bytes`,
 per-device when a mesh is present) that stands in for
-device.memory_stats() on backends without it — bench --stream / --mesh and
-the peak-memory tests consume it.
+device.memory_stats() on backends without it — the peak-memory tests
+(tests/test_streaming.py, tests/test_mesh_residency.py) consume it.
 """
 from __future__ import annotations
 
@@ -94,7 +93,7 @@ class ResidencyManager:
         self.footprints: Dict[str, CoordinateFootprint] = {}
         self.store = BlockStore()
         # streamed coordinates' chunk-stream accounting, surfaced through
-        # accounting() so bench --stream/--stoch and the cli summary see
+        # accounting() so the cli summary sees
         # work-per-staged-byte next to the byte peaks
         self._stream_snapshots = {}
         for name, coord in coordinates.items():
@@ -169,7 +168,7 @@ class ResidencyManager:
 
     # -- reporting ------------------------------------------------------------
     def accounting(self) -> dict:
-        """Byte accounting for bench --stream / training summaries: the
+        """Byte accounting for training summaries: the
         stand-in for device.memory_stats() where that API is missing."""
         return {
             "budget_bytes": self.budget_bytes,

@@ -4,7 +4,8 @@ Every threshold is Optional — None disables that gate — so an operator can
 run pure drift monitoring (no labels needed), pure calibration monitoring,
 or the full set.  Windows are COUNT-based (labeled rows / scored rows),
 never wall-clock, so detection latency is deterministic under replay and
-the bench can gate "tripped within <= 3 evaluation windows" exactly.
+a test can hold "tripped within <= 3 evaluation windows" exactly
+(tests/test_health.py).
 
 `cli.serve --health-config` takes this as inline JSON or `@file`
 (`from_dict` rejects unknown keys loudly — a typo'd threshold must not

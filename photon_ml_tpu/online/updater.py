@@ -101,7 +101,7 @@ class OnlineUpdater:
 
     `submit()` is the intake (thread-safe, called from request threads);
     `run_once()` is one drain-solve-publish cycle (the background loop
-    calls it; tests and the bench call it directly for determinism)."""
+    calls it; tests call it directly for determinism)."""
 
     def __init__(self, registry, metrics=None,
                  config: OnlineUpdateConfig = OnlineUpdateConfig(),
@@ -232,7 +232,8 @@ class OnlineUpdater:
         gather/mask chain, and the delta scatter at each pow-2 row count —
         so no feedback stream ever traces (the online twin of
         CompiledScorer.warmup; the background loop runs this before its
-        first drain)."""
+        first drain).  A `close()` ends it after the program in flight: a
+        closing updater compiles nothing more, and `warmed` stays False."""
         from photon_ml_tpu.serving.scorer import _pad_pow2_rows, _scatter_rows
         cfg = self.config
         scorer = self.registry.scorer
@@ -262,6 +263,8 @@ class OnlineUpdater:
                         blocks, prior, self._loss(), self._solver,
                         cfg.anchor_weight)
                     jax.block_until_ready(lane_all_finite(new_rows))
+                    if self._closed.is_set():
+                        return clock() - t0
                     if S >= s_max:
                         break
                     S <<= 1
@@ -276,6 +279,8 @@ class OnlineUpdater:
                     jax.block_until_ready(_scatter_rows(
                         table, jnp.asarray(rows_p),
                         jnp.asarray(vals_p, table.dtype)))
+                    if self._closed.is_set():
+                        return clock() - t0
                     k <<= 1
         self.warmup_s = clock() - t0
         self.warmed = True
@@ -326,7 +331,7 @@ class OnlineUpdater:
         return totals
 
     def flush(self, max_cycles: int = 1000) -> Dict[str, int]:
-        """Drain the buffer to empty (tests / bench determinism)."""
+        """Drain the buffer to empty (the serve CLI's graceful drain; tests)."""
         totals = {"entities": 0, "rows": 0, "deltas": 0}
         for _ in range(max_cycles):
             if not self.buffer.lanes() or self.paused:
@@ -390,7 +395,7 @@ class OnlineUpdater:
 
     def alive(self) -> bool:
         """Is the background loop thread running?  (False under manual
-        `run_once()` driving — tests/bench — and after close().)"""
+        `run_once()` driving — tests — and after close().)"""
         with self._state_lock:
             thread = self._thread
         return thread is not None and thread.is_alive()
@@ -749,7 +754,13 @@ class OnlineUpdater:
                 if self.metrics is not None:
                     self.metrics.observe_solve_failure()
 
-    def close(self, timeout: float = 5.0) -> None:
+    def close(self) -> None:
+        """Stop the loop and wait for its thread.  The wait has no limit:
+        the thread ends after the program it is in (a warm-up compile, one
+        solve), and a process that went on to exit while the thread was
+        still inside XLA died by SIGABRT or SIGSEGV during interpreter
+        teardown — a graceful drain of `cli.serve` under load, where the
+        warm-up outlasted the 5 s this join used to wait."""
         self._closed.set()
         self._wake.set()
         # detach under the lock, join OUTSIDE it: the loop thread takes
@@ -758,4 +769,4 @@ class OnlineUpdater:
         with self._state_lock:
             thread, self._thread = self._thread, None
         if thread is not None:
-            thread.join(timeout=timeout)
+            thread.join()

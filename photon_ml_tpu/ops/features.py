@@ -197,8 +197,8 @@ FeatureMatrix = Union[jax.Array, jsparse.BCOO, KroneckerDesign, PaddedSparse]
 
 # below this width the scatter-add accumulator is small enough that the
 # scatter path wins outright, and the csc stream would only add host->device
-# transfer (measured: yahoo-shape d=14,983 FE pays ~5s extra transfer for no
-# solve-time gain, while d=250k gains 3.7x; see BENCH config 6 vs 7)
+# transfer.  Not measured on this chip (ROADMAP S5: keep a cell on each side
+# of this width)
 CSC_MIN_COLS = 100_000
 
 
@@ -262,8 +262,8 @@ def _csc_segment_sum(vals: jax.Array, rows: jax.Array, end: jax.Array,
 
     Chunking is a precision device, not a speed one: a single global
     cumsum accumulates ~eps*sqrt(nnz) rounding noise into every boundary
-    difference, which measurably slowed LBFGS convergence (61 iterations
-    vs 34 on the exact path, BENCH round 5).  With the scan restarted per
+    difference, which slowed LBFGS convergence (61 iterations
+    against 34 on the exact path in a pre-round record).  With the scan restarted per
     64k-element chunk, a column contained in one chunk — the overwhelming
     case at realistic column counts — differences two LOCAL prefixes and
     the cross-chunk terms cancel EXACTLY (identical floats), so its error

@@ -22,8 +22,9 @@ GSPMD from the sharding of the einsum operands:
 
   * ONE [n]-vector psum over the FEATURE axis — the margin sum
     ``einsum('nfa,fa->n', X, W)`` that forms mbar (the only place shards
-    exchange vector-sized data; the bench's collective-accounting leg
-    gates this at exactly one per iteration);
+    exchange vector-sized data; tests/test_admm.py::
+    test_one_feature_axis_reduction_per_iteration holds it to exactly one
+    per iteration);
   * ONE [F, d_F] psum over the DATA axis — the residual product
     ``einsum('nfa,n->fa', X, r)`` (transpose-reduction: together with the
     cached per-shard Gram it reconstructs X_j^T b_j without ever
@@ -184,8 +185,8 @@ def _make_kernels(loss: PointwiseLoss, has_l1: bool, newton_steps: int,
                   adapt_rho: bool, rho_tau: float, rho_mu: float):
     """The iteration body + init as pure closures over the STATIC choices
     (loss family, L1 split presence, Newton depth, balancing constants).
-    Shared by the compiled while_loop program and the bench's standalone
-    single-iteration probe, so the collective accounting measures the
+    Shared by the compiled while_loop program and the standalone
+    single-iteration probe (`cached_step_probe`), so the collective accounting measures the
     exact body the solver runs."""
 
     def loss_value(ops: ADMMOperands, mbar, w, v):
@@ -342,7 +343,7 @@ def admm_solve(loss: PointwiseLoss, has_l1: bool, ops: ADMMOperands,
     Callers normally go through parallel.fixed_effect.fit_fixed_effect_admm
     (which stages the column grid and Gram eigendecomposition through the
     mesh residency layer); this entry point is the pure-compute surface the
-    tests and the bench drive directly.  `loss` and `has_l1` are the STATIC
+    tests drive directly.  `loss` and `has_l1` are the STATIC
     structural choices (trace-cache keys, like solve()'s reg.has_l1); a
     traced l1 weight of 0 under has_l1=True converges to the same smooth
     optimum.  `w0` is the [F, d_F] warm start; `budget` follows the
@@ -371,7 +372,7 @@ def cached_step_probe(loss: PointwiseLoss, has_l1: bool, adapt_rho: bool,
     """A jitted SINGLE ADMM iteration (the exact `body` the while_loop
     runs) as a standalone (ops, carry) -> carry program.
 
-    This is the bench's collective-accounting surface: lowering it with
+    This is the collective-accounting surface: lowering it with
     the real shardings and inspecting the compiled HLO counts the
     all-reduces one iteration costs — the gate is exactly ONE vector
     ([n]-shaped) all-reduce over the FEATURE axis plus one [F, d_F]
@@ -385,7 +386,7 @@ def cached_step_probe(loss: PointwiseLoss, has_l1: bool, adapt_rho: bool,
 def make_init(loss: PointwiseLoss, has_l1: bool, ops: ADMMOperands,
               w0: jax.Array, rho0, ceil: int,
               newton_steps: int = 8) -> ADMMCarry:
-    """Build the iteration-0 carry for `cached_step_probe` (test/bench
+    """Build the iteration-0 carry for `cached_step_probe` (test
     helper; the production program builds its carry inside the jit)."""
     _, init, _ = _make_kernels(loss, has_l1, newton_steps, True, 2.0, 10.0)
     return jax.jit(init, static_argnums=(3,))(ops, w0, rho0, ceil)

@@ -41,7 +41,8 @@ This module is that lane:
 The lane is the COARSE mode: it buys cheap early progress per staged
 byte, and `SolverSchedule.stochastic_plan` always hands the final outer
 iteration(s) to the strict host-stepped solver, whose full-tolerance
-polish pins the fixed point (the f64 parity gate in bench --stoch).
+polish pins the fixed point (held to 1e-6 in f64 by
+tests/test_stochastic.py::test_fixed_point_parity_stochastic_plus_polish).
 
 Determinism: the per-(chunk, epoch) permutation key is PRNGKey(seed)
 folded with (pass, chunk, epoch) in turn — a given (plan, seed,

@@ -240,9 +240,10 @@ def _cached_gram_eig(mesh: Mesh):
     ADMM w-update closed form for ANY traced shift.  The Gram itself is
     never stored: only (Q, lam), [F, d_F, d_F] + [F, d_F] sharded over
     "feature" (out_shardings pin this so per-device aggregator memory is
-    d_F^2, shrinking quadratically as the feature axis widens — the
-    bench's memory gate).  Unweighted by construction, so downsampling /
-    per-visit weights never invalidate it (they only reweight the z-prox)."""
+    d_F^2, shrinking quadratically as the feature axis widens:
+    tests/test_admm.py::test_per_device_aggregator_shrinks_with_feature_axis).
+    Unweighted by construction, so downsampling / per-visit weights never
+    invalidate it (they only reweight the z-prox)."""
     out_sh = (NamedSharding(mesh, P(FEATURE_AXIS, None, None)),
               NamedSharding(mesh, P(FEATURE_AXIS, None)))
 

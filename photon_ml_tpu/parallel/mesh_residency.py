@@ -18,8 +18,8 @@ and residuals; this module is that discipline for the GSPMD mesh path:
     operands — residual offsets, x0 — between host and devices.
   * `TransferStats` counts every staged byte, split COLD (static data,
     staged once per residency) vs WARM (per-visit operands), so the
-    no-retransfer property is observable: bench --mesh and the regression
-    tests gate "zero cold bytes across warm outer iterations" on it.
+    no-retransfer property is observable: tests/test_mesh_residency.py
+    gates "zero cold bytes across warm outer iterations" on it.
   * staging runs under the same transient/fatal fault classification as
     the streaming Prefetcher: the `mesh.stage` injection site
     (utils/faults.py) fires before each transfer, transient failures retry
@@ -387,7 +387,7 @@ class MeshResidency:
 # -- process-global default registry ------------------------------------------
 # One registry serves every estimator in the process (entries are keyed by
 # coordinate identity + mesh, so fits never collide); module-level so the
-# descent loop, benches, and the CLI summary all read one TransferStats.
+# descent loop and the CLI summary read one TransferStats.
 
 _DEFAULT: Optional[MeshResidency] = None
 _DEFAULT_LOCK = threading.Lock()
@@ -396,7 +396,7 @@ _DEFAULT_LOCK = threading.Lock()
 def default_residency() -> MeshResidency:
     # double-checked: scoring worker threads and the training loop race
     # the first stage; a bare check-then-act would build TWO registries
-    # and split the TransferStats the mesh bench gates on [PH013]
+    # and split the TransferStats the transfer tests read [PH013]
     global _DEFAULT
     if _DEFAULT is None:
         with _DEFAULT_LOCK:

@@ -106,7 +106,7 @@ def process_index() -> int:
 
 def is_primary() -> bool:
     """True on the one process that owns durable writes (checkpoints,
-    models, summaries, benches)."""
+    models, summaries)."""
     return process_index() == 0
 
 

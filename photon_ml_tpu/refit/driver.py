@@ -37,7 +37,7 @@ half-installed candidate.  (`refit.compact` fires inside the compactor.)
 Determinism: the fit consumes rows in log order, splits train/holdout by
 position, and runs fixed-seed solvers — the objective history of a refit
 from the log is bit-identical to one from the same rows in memory (the
-parity gate in tests/test_refit.py and bench --refit).
+parity gate in tests/test_refit.py).
 """
 from __future__ import annotations
 

@@ -22,8 +22,8 @@ resume, with each arrow owned by the component that already owned it.
 A losing candidate leaves the gates tripped and the updater paused; the
 trigger retries after `cooloff_s`.
 
-`poll()` is one state-machine step with an injectable clock — tests and
-the bench drive it synchronously; `start()` runs it on a daemon thread
+`poll()` is one state-machine step with an injectable clock — tests
+drive it synchronously; `start()` runs it on a daemon thread
 every `poll_s` seconds for real deployments (cycle errors are recorded
 and the loop keeps running: a failed refit must not kill the trigger).
 """

@@ -12,8 +12,7 @@ Two render paths off the same instruments:
 
   * `snapshot()` — the JSON surface (p50/p90/p95/p99 latency included):
     the serve CLI dumps it on SIGUSR1 / a periodic timer and at
-    `GET /metrics.json`, and `bench.py --serve` records it in
-    BENCH_serve.json.
+    `GET /metrics.json`.
   * `prometheus()` — text exposition 0.0.4 for `GET /metrics` (counters
     as `photon_serving_*_total`, the latency histogram as a summary with
     quantile series), scrapeable by a stock Prometheus.

@@ -73,7 +73,7 @@ class ScoringService:
         background OnlineUpdater re-solves ONLY the touched entities'
         random-effect subproblems, publishing row-level delta swaps into
         the live scorer.  `start_updater=False` keeps the updater manual
-        (tests/bench drive `service.updater.run_once()` themselves).
+        (tests drive `service.updater.run_once()` themselves).
 
         `health` (a health.HealthConfig) arms the model-health monitor:
         streaming calibration over feedback-joined labels, score-

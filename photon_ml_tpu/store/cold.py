@@ -197,7 +197,7 @@ class ColdStore:
         return out
 
     def seal_report(self) -> Dict[str, Dict]:
-        """Per-segment seal metadata (bench/debug accounting)."""
+        """Per-segment seal metadata (debug accounting)."""
         out: Dict[str, Dict] = {}
         for si in range(self.num_segments):
             with open(os.path.join(self.directory, _seal_name(si))) as f:

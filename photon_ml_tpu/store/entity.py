@@ -510,7 +510,7 @@ class TieredEntityStore:
     def preload_all(self) -> None:
         """Pin the ENTIRE table hot (requires hot_rows == rows): one bulk
         device transfer + identity slot maps.  The all-resident
-        configuration — what a budgeted store is benchmarked against."""
+        configuration — what a budgeted store is compared with."""
         if self.hot_rows != self.rows:
             raise StoreError(
                 f"store {self.name!r}: preload_all needs hot_rows == "

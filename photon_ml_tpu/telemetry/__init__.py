@@ -21,7 +21,7 @@ Three layers, one import:
     `telemetry.snapshot()` returns everything.  Always live (an increment
     costs what the bespoke accumulators already cost).
   * EXPORTERS (`export`) — Chrome-trace/Perfetto JSON (`--trace-out` on
-    cli.train and bench.py), a JSONL run log correlated with EventEmitter
+    cli.train), a JSONL run log correlated with EventEmitter
     events and fault/quarantine/recovery records by span id, and
     Prometheus text exposition (mounted at `/metrics` on the serving HTTP
     service).
@@ -75,8 +75,7 @@ def unregister_collector(name: str) -> None:
 def snapshot() -> dict:
     """Everything: the default registry's instruments, every registered
     collector, and (when a tracer is or was armed) its record counts.
-    All values JSON-safe — this dict lands verbatim in BENCH_*.json and
-    training-summary.json."""
+    All values JSON-safe — this dict lands verbatim in training-summary.json."""
     out = {"metrics": default_registry().snapshot()}
     for name, fn in sorted(_COLLECTORS.items()):
         try:

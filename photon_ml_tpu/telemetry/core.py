@@ -1,7 +1,7 @@
 """Span tracer: hierarchical, thread-aware run timelines with fault-style
 disarm semantics.
 
-Every perf PR so far justified itself through a bespoke bench-only counter
+Every perf PR of the early rounds justified itself through a bespoke counter
 (PhaseTimings, StreamStats, TransferStats, ServingMetrics, ...); none of
 them compose into one picture of where a fit or a serving process spends
 its time.  This module is the composing layer:
@@ -29,10 +29,10 @@ its time.  This module is the composing layer:
 DISARM SEMANTICS (the contract the hot paths rely on, same discipline as
 `utils.faults.fire`): with no tracer installed, `span()` is a module-global
 None check returning a shared no-op singleton — no span objects, no list
-appends, no fresh XLA traces, nothing on the device hot path.  The
-compile-count and disarmed-overhead bench legs (bench.py --trace) gate
-this.  Armed tracing touches HOST values only (names, ints, floats); it
-never reads a device array, so it adds zero sync points (photonlint PH001
+appends, no fresh XLA traces, nothing on the device hot path
+(tests/test_telemetry.py: `test_disarmed_span_is_the_shared_noop_singleton`,
+`test_armed_telemetry_adds_zero_fresh_traces_to_a_warm_fit`).  Armed
+tracing touches HOST values only (names, ints, floats); it never reads a device array, so it adds zero sync points (photonlint PH001
 stays clean over every instrumented module).
 """
 from __future__ import annotations
@@ -479,8 +479,7 @@ def shutdown() -> Optional[Tracer]:
 
 
 class enabled:
-    """`with telemetry.enabled() as tracer:` — scoped arming for tests and
-    bench legs."""
+    """`with telemetry.enabled() as tracer:` — scoped arming for tests."""
 
     def __init__(self, run_log: Optional[str] = None,
                  watch_compiles: bool = True,

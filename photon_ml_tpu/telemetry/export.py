@@ -1,7 +1,6 @@
 """Telemetry exporters: Chrome-trace/Perfetto JSON and Prometheus text.
 
-Chrome trace (the `--trace-out trace.json` format on cli.train and
-bench.py): the Trace Event Format's JSON-object form — `{"traceEvents":
+Chrome trace (the `--trace-out trace.json` format on cli.train): the Trace Event Format's JSON-object form — `{"traceEvents":
 [...]}` with complete ("X") events for spans and instant ("i") events for
 point records.  Every event carries the format's required keys (`name`,
 `ph`, `ts`, `pid`, `tid`; `dur` on "X") plus `args.span`/`args.parent` so
@@ -83,8 +82,8 @@ def write_chrome_trace(tracer: Tracer, path: str) -> dict:
 
 def validate_chrome_trace(payload: dict) -> List[str]:
     """Problems with a trace dict against the format's required keys
-    (empty list = valid).  Used by the --trace bench gate and the smoke
-    test rather than trusting the writer to have stayed honest."""
+    (empty list = valid).  Used by tests/test_telemetry.py (on `cli.train --trace-out`'s
+    output) rather than trusting the writer to have stayed honest."""
     problems: List[str] = []
     events = payload.get("traceEvents")
     if not isinstance(events, list) or not events:

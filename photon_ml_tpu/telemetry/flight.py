@@ -12,9 +12,8 @@ a durable, correlated bundle when a registered trigger fires.
 
 DISARM SEMANTICS (the `faults.fire()` contract): with no recorder
 installed, `trigger()`/`record_event()` are a module-global None check and
-return.  Armed, a record is one deque append (O(1), bounded memory) — the
-armed-overhead bench gate (`bench.py --fleetobs`, <= 1.1x disarmed scoring
-p99, zero fresh XLA traces) holds the recorder to the same hot-path
+return.  Armed, a record is one deque append (O(1), bounded memory) — tests/test_fleetobs.py::
+test_request_tracing_adds_no_fresh_traces_armed_or_disarmed holds the recorder to the same hot-path
 discipline as the tracer.
 
 TRIGGERS is the registry of dump reasons, the flight twin of
@@ -212,8 +211,7 @@ def shutdown() -> Optional[FlightRecorder]:
 
 
 class enabled:
-    """`with flight.enabled(dump_dir) as rec:` — scoped arming for tests
-    and bench legs."""
+    """`with flight.enabled(dump_dir) as rec:` — scoped arming for tests."""
 
     def __init__(self, dump_dir: Optional[str] = None, proc: str = "proc",
                  ring_records: int = RING_RECORDS):

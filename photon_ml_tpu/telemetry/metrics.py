@@ -1,10 +1,10 @@
 """Metrics registry: counters, gauges, bounded-reservoir histograms.
 
 One uniform surface for every quantity this repo used to track through
-bespoke bench-only accumulators (PhaseTimings.host_blocked, StreamStats,
+bespoke accumulators (PhaseTimings.host_blocked, StreamStats,
 TransferStats, ServingMetrics, checkpoint/retry counters): an instrument
 is created once by name, incremented from any thread, and read back via
-`snapshot()` — which is what `telemetry.snapshot()`, the bench entries,
+`snapshot()` — which is what `telemetry.snapshot()`,
 the cli.train summary, and the serving Prometheus endpoint all render.
 
 Design constraints, in order:
@@ -18,7 +18,7 @@ Design constraints, in order:
     `min` stay exact.  Replaces the unbounded percentile lists the naive
     approach grows per request;
   * JSON-safe snapshots — every snapshot value is an int or float, so a
-    snapshot can land verbatim in BENCH_*.json / training-summary.json.
+    snapshot can land verbatim in training-summary.json.
 
 Instruments are process-global when created through the module-level
 `counter()/gauge()/histogram()` helpers (one registry serves training,

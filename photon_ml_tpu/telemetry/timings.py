@@ -4,12 +4,13 @@ span tracer.
 Moved here from game/coordinate_descent.py: photonlint PH007 forbids raw
 `time.perf_counter()` span timing inside the hot-path modules, and this is
 the ONE sanctioned implementation — every timed phase of a fit lands both
-in the per-fit dict (the cli summary / bench tables, armed or not), in
+in the per-fit dict (the cli summary and the benchmark's `build_s.fit` /
+`descent_s.fit`, armed or not), in
 any JAX profiler trace as the annotation `photon/<label>` and, when the
 tracer is armed, in the hierarchical trace as a named span.
 
 `clock()` is the sanctioned raw timestamp for hot modules that need a
-bare duration (the disarmed-overhead bench times itself with it too).
+bare duration.
 """
 from __future__ import annotations
 
@@ -29,14 +30,13 @@ class PhaseTimings(dict):
     """Accumulating span timer (reference: Timer/Timed spans at every driver
     stage, photon-lib/.../util/Timer.scala:32-234 used ~30x).  Spans are
     CONTIGUOUS over the descent loop so their sum accounts for the whole
-    fit wall-clock — an unattributed gap means an untimed stage, which is
-    exactly what round 3's bench suffered from.
+    fit wall-clock — an unattributed gap means an untimed stage
+    (tests/test_game.py holds the span sum to the fit's wall-clock).
 
     `host_blocked` tracks, per span label, the seconds the host spent
     BLOCKED on device readbacks (scalar syncs, `float()` objective fetches,
     [n]-array transfers into numpy evaluators, the pipelined boundary
-    flush).  host_blocked_total()/wall is the host-blocked fraction bench
-    reports per config — the quantity pipelining exists to shrink; it also
+    flush).  host_blocked_total()/wall is the host-blocked fraction — the quantity pipelining exists to shrink; it also
     lands in the `train.host_blocked_s`/`train.host_blocked_frac` gauges
     at fit end (game/coordinate_descent.py).
 

@@ -11,8 +11,8 @@ work.  This module makes faults FIRST-CLASS and REPRODUCIBLE:
     filtered on call context like the coordinate name or chunk index).
     Activated per-process via `install_plan` / the `injected` context
     manager, or across process boundaries via the `PHOTON_FAULT_PLAN`
-    environment variable (inline JSON or `@file`) — which is how the
-    bench's kill-resume chaos leg arms its subprocess children.
+    environment variable (inline JSON or `@file`) — which is how
+    tests/test_faults.py's kill-and-resume leg arms its child processes.
   * `fire(site, **ctx)` — the hook threaded through chunk staging, device
     transfer, checkpoint write/fsync, and model save/load.  With no plan
     installed it is a module-global None check and return: a zero-overhead
@@ -342,8 +342,7 @@ class FaultPlan:
                 "faults": [s.to_dict() for s in specs]}
 
     def report(self) -> dict:
-        """Per-site calls/fired accounting (the bench records this per
-        chaos leg)."""
+        """Per-site calls/fired accounting."""
         sites: Dict[str, Dict[str, int]] = {}
         with self._lock:
             for s in self.specs:
@@ -416,7 +415,7 @@ def install_plan(plan: Optional[FaultPlan]) -> Optional[FaultPlan]:
 
 class injected:
     """Context manager: `with faults.injected(plan): ...` — scoped
-    activation for tests and in-process bench legs."""
+    activation for tests."""
 
     def __init__(self, plan: FaultPlan):
         self.plan = plan
@@ -432,7 +431,7 @@ class injected:
 def install_from_env(env_var: str = "PHOTON_FAULT_PLAN"
                      ) -> Optional[FaultPlan]:
     """Arm the plan named by the environment (inline JSON, or `@path`):
-    how subprocess children of the chaos bench — and preempted re-launches
+    how child processes of the fault tests — and preempted re-launches
     of cli.train — pick up their injection plan."""
     raw = os.environ.get(env_var)
     if not raw:

@@ -379,6 +379,62 @@ def test_grid_staging_pads_and_splits(rng):
 
 
 # ---------------------------------------------------------------------------
+# per-device aggregator memory falls with the feature axis
+# ---------------------------------------------------------------------------
+
+_MEM_D = 256
+
+
+@pytest.fixture(scope="module")
+def admm_memory_legs():
+    """One squared-loss problem at d=256 trained by pure consensus ADMM on
+    8x1, 4x2, 2x4 and 1x8 meshes: per feature-axis width, the largest
+    per-device shard of the eigenbasis aggregator [F, d_F, d_F] and whether
+    the objective fell.  Built once; the cases below only read it."""
+    rng = np.random.default_rng(7)
+    x = rng.normal(size=(1024, _MEM_D))
+    x[:, -1] = 1.0
+    y = x @ (rng.normal(size=_MEM_D) * 0.5) + 0.1 * rng.normal(size=1024)
+    obj = GLMObjective(SQUARED, x, y)
+    v0 = float(obj.value(jnp.zeros(_MEM_D)))
+    legs = {}
+    for width in (1, 2, 4, 8):
+        mesh = _mesh(8 // width, width)
+        key = ("admm-mem", width)
+        res = fit_fixed_effect_admm(
+            obj, np.zeros(_MEM_D), mesh,
+            ADMMConfig(max_iterations=25, tolerance=1e-9, polish=False),
+            reg=L2, reg_weight=0.3, residency_key=key)
+        staged, _, _, _ = _stage_admm_operands(obj, mesh, key)
+        legs[width] = {
+            "aggregator_bytes": max(
+                s.data.nbytes for s in staged["q_eig"].addressable_shards),
+            "trained": float(res.value) < v0}
+    return legs
+
+
+@pytest.mark.parametrize("width", [2, 4, 8])
+def test_per_device_aggregator_shrinks_with_feature_axis(admm_memory_legs,
+                                                         width):
+    """The eigenbasis [F, d_F, d_F] is sharded over "feature", so a device
+    holds one d_F x d_F block: its share falls with the SQUARE of the axis
+    width (a replicated eigenbasis would fall only linearly)."""
+    base = admm_memory_legs[1]["aggregator_bytes"]
+    assert admm_memory_legs[width]["aggregator_bytes"] <= (
+        base / width ** 2 * 1.15)
+
+
+def test_wide_model_trains_inside_a_budget_the_monolithic_layout_busts(
+        admm_memory_legs):
+    """A d whose monolithic d^2 aggregator exceeds a per-device budget
+    trains on the data x feature mesh with every device inside it."""
+    budget = _MEM_D * _MEM_D * 8 // 4
+    assert admm_memory_legs[1]["aggregator_bytes"] > budget
+    assert admm_memory_legs[8]["aggregator_bytes"] <= budget
+    assert admm_memory_legs[8]["trained"]
+
+
+# ---------------------------------------------------------------------------
 # eligibility: fail loud / warn once instead of silently not sharding
 # ---------------------------------------------------------------------------
 

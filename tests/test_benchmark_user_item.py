@@ -131,15 +131,27 @@ def reference_inputs(ds, cfg):
     return entities
 
 
+@pytest.fixture(scope="module")
+def float64_fit():
+    """caps -> (corpus, configuration, result) of the float64 fit stopped at
+    1e-12, each made once for the tests that read it."""
+    made = {}
+
+    def of(caps):
+        if caps not in made:
+            ds, cfg = ratings(np.float64), config(caps, tolerance=1e-12)
+            made[caps] = (ds, cfg, fit(ds, cfg))
+        return made[caps]
+    return of
+
+
 @pytest.mark.parametrize("caps", ["free", "capped"])
-def test_float64_fit_equals_the_plain_reference(caps):
+def test_float64_fit_equals_the_plain_reference(float64_fit, caps):
     """(a), (b): coefficients of all three coordinates and the objective
     history equal the reference's block coordinate descent to 1e-6, with no
     cap and with one that binds; under the binding cap passive rows are
     scored and discarded ones are not."""
-    ds = ratings(np.float64)
-    cfg = config(caps, tolerance=1e-12)
-    result = fit(ds, cfg)
+    ds, cfg, result = float64_fit(caps)
     entities = reference_inputs(ds, cfg)
     want = reference_game.fit_game(
         ds.feature_shards["global"], ds.response, WEIGHTS["fixed"], entities,
@@ -283,13 +295,12 @@ def test_cell_rehearsal_is_correct_and_seeds_share_bucket_shapes():
     assert shapes[0] == shapes[1]
 
 
-def test_cli_train_gives_the_estimators_model(tmp_path):
+def test_cli_train_gives_the_estimators_model(float64_fit, tmp_path):
     """(e): the three-coordinate configuration as a --config JSON through
     cli.train gives the model of the estimator call, and its summary carries
     the build counters."""
-    ds = ratings(np.float64)
-    cfg = config("capped", tolerance=1e-12)
-    want = fit(ds, cfg).descent.model.coordinates
+    ds, cfg, result = float64_fit("capped")
+    want = result.descent.model.coordinates
     train_p, cfg_p = str(tmp_path / "train.npz"), str(tmp_path / "game.json")
     save_game_dataset(ds, train_p)
     with open(cfg_p, "w") as f:

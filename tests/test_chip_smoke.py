@@ -15,7 +15,6 @@ import subprocess
 import sys
 import time
 
-import numpy as np
 import pytest
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -29,24 +28,51 @@ def _load(name, path):
     return mod
 
 
-def _run(argv, script=_SMOKE, **env_overrides):
+def _start(argv, script=_SMOKE, **env_overrides):
     env = dict(os.environ, JAX_PLATFORMS="cpu", **env_overrides)
     env.pop("XLA_FLAGS", None)
     env.pop("JAX_ENABLE_X64", None)
-    return subprocess.run([sys.executable, script] + argv, env=env,
-                          capture_output=True, text=True, timeout=600,
-                          cwd=os.path.dirname(script))
+    return subprocess.Popen([sys.executable, script] + argv, env=env,
+                            stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                            text=True, cwd=os.path.dirname(script))
+
+
+def _finish(child):
+    try:
+        out, err = child.communicate(timeout=600)
+    except subprocess.TimeoutExpired:
+        child.kill()
+        child.communicate()
+        raise
+    return subprocess.CompletedProcess(child.args, child.returncode, out, err)
+
+
+def _run(argv, script=_SMOKE, **env_overrides):
+    return _finish(_start(argv, script, **env_overrides))
+
+
+@pytest.fixture(scope="module")
+def rehearsals(tmp_path_factory):
+    """The one-device and the four-device rehearsal, each its own chain of
+    child processes in its own work directory, started together: {multichip:
+    (work directory, completed process)}."""
+    started = {}
+    for multichip in (False, True):
+        work = tmp_path_factory.mktemp("rehearsal") / "work"
+        argv = ["--rehearse-cpu", "--rows", "40000", "--work-dir", str(work)]
+        started[multichip] = (
+            work, _start(argv + (["--multichip"] if multichip else [])))
+    return {multichip: (work, _finish(child))
+            for multichip, (work, child) in started.items()}
 
 
 @pytest.mark.parametrize("multichip", [False, True],
                          ids=["one-device", "four-devices"])
-def test_rehearsal_runs_every_phase_on_cpu(tmp_path, multichip):
+def test_rehearsal_runs_every_phase_on_cpu(rehearsals, multichip):
     """--rehearse-cpu drives every phase through the real entry points at
     40,000 rows and ends with the result line — naming the CPU, never the
     chip."""
-    work = tmp_path / "work"
-    argv = ["--rehearse-cpu", "--rows", "40000", "--work-dir", str(work)]
-    r = _run(argv + (["--multichip"] if multichip else []))
+    work, r = rehearsals[multichip]
     assert r.returncode == 0, r.stderr[-3000:]
     assert '"platform": "tpu"' not in r.stdout
     assert "rows cut to 40000 of 1000209" in r.stdout
@@ -86,12 +112,18 @@ def test_script_alone_fails(tmp_path):
     assert "not beside this script" in r.stderr and r.stdout == ""
 
 
-def test_smoke_config_is_the_bench_glmix_config():
-    """The --config JSON the smoke hands cli.train IS bench.py's config-4
-    GLMix config (`_game_setup(mode="glmix")`), field for field."""
+def test_smoke_config_is_the_benchmark_glmix_config():
+    """The --config JSON the smoke hands cli.train IS the
+    GameTrainingConfig the benchmark trains `glmix-ml20m` with, field for
+    field: the builder's own object (built at the configuration's rehearsal
+    size, which changes the data and no training field)."""
+    from benchmark.builders import game_fit
     from photon_ml_tpu.game import GameTrainingConfig
     smoke = _load("chip_smoke_under_test", _SMOKE)
-    bench = _load("bench_for_chip_smoke", os.path.join(_REPO, "bench.py"))
-    _, _, want = bench._game_setup("1m", 2000, 11, np.float32, "glmix")
+    with open(os.path.join(_REPO, "benchmark", "configs",
+                           "glmix-ml20m.json")) as f:
+        config = json.load(f)
+    config.update(config["rehearsal"])
+    want = game_fit.build(config, 11, 1).cfg
     got = GameTrainingConfig.from_json(json.dumps(smoke._glmix_config(11)))
     assert got == want

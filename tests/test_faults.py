@@ -256,10 +256,12 @@ def test_injected_staging_faults_keep_streamed_fit_exact(rng):
         return res, coords["fixed"]._stream.stats.snapshot()
 
     ref, _ = run(None)
-    plan = faults.FaultPlan([{"site": "stage.fetch", "action": "transient",
-                              "hits": [1, 4]}])
+    plan = faults.FaultPlan([
+        {"site": "stage.fetch", "action": "transient", "hits": [1, 4]},
+        {"site": "stage.transfer", "action": "transient", "hits": [2]}])
     faulted, stats = run(plan)
-    assert stats["retries"] == 2 and stats["gave_up"] == 0
+    assert plan.report()["total_fired"] == 3
+    assert stats["retries"] == 3 and stats["gave_up"] == 0
     np.testing.assert_array_equal(ref.objective_history,
                                   faulted.objective_history)
 
@@ -524,20 +526,41 @@ def test_exit_preempted_is_distinct():
 # f64 trajectory)
 # --------------------------------------------------------------------------
 
+# One seeded f64 GLMix fit (this file's own _glmix/_config) in a process of
+# its own, because the leg needs true process death: the fault plan arrives
+# through PHOTON_FAULT_PLAN, one JSON line comes back.
+_CHILD = """
+import json, sys
+sys.path.insert(0, {tests!r})
+import numpy as np
+from photon_ml_tpu.utils.jax_cache import enable_persistent_cache
+enable_persistent_cache()
+from photon_ml_tpu.game import GameEstimator
+from photon_ml_tpu.utils import faults
+from test_faults import _config, _glmix
+faults.install_from_env()
+res = GameEstimator(_config(3)).fit(
+    _glmix(np.random.default_rng(31), n=700, n_users=50),
+    checkpoint_dir={ckpt!r}, timing_mode="strict")
+print(json.dumps({{
+    "objective_history": [float(v) for v in res.objective_history],
+    "checkpoint_recovery": res.checkpoint_recovery}}))
+"""
+
+
 def _run_child(tmp_path, ckpt=None, plan=None, expect_kill=False):
     env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_ENABLE_X64="1",
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jaxcache"))
+    env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("XLA_FLAGS", None)
     env.pop("PHOTON_FAULT_PLAN", None)
     if plan is not None:
         env["PHOTON_FAULT_PLAN"] = json.dumps(plan)
-    cmd = [sys.executable, os.path.join(_REPO, "bench.py"), "--faults-child",
-           "--n", "700", "--outer", "3", "--iters", "6", "--seed", "31",
-           "--timing-mode", "strict"]
-    if ckpt:
-        cmd += ["--ckpt", ckpt]
-    p = subprocess.run(cmd, env=env, capture_output=True, text=True,
-                       timeout=420, cwd=_REPO)
+    code = _CHILD.format(tests=os.path.dirname(os.path.abspath(__file__)),
+                         ckpt=ckpt)
+    p = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=420,
+                       cwd=_REPO)
     if expect_kill:
         assert p.returncode not in (0, 1), (p.returncode, p.stderr[-500:])
         return p.returncode

@@ -294,6 +294,64 @@ def test_replica_crash_resume_is_idempotent(tmp_path, rng):
             s.close()
 
 
+def test_steady_state_replay_traces_nothing(tmp_path, rng):
+    """Tailing the log on a joined replica touches only cached programs:
+    the join-time delta warm-up compiled every pow-2 scatter shape."""
+    from test_pipeline import _compile_counting
+    mdir = _save_model(rng, tmp_path)
+    svc, log, _pub = _publisher(mdir, tmp_path / "log")
+    rep = _follower(mdir, log, tmp_path / "s0")
+    try:
+        svc.updater.warmup()
+        for s in range(2):      # warm publisher programs + replica replay
+            _feedback(svc, 1000 + s, n=24)
+            rep.poll_once()
+        fresh = applied = 0
+        for s in range(4):
+            _feedback(svc, 2000 + s, n=24)
+            with _compile_counting() as counter:
+                applied += rep.poll_once()
+            fresh += counter.count
+        assert fresh == 0
+        assert applied >= 4
+        assert _audits_equal(svc, rep.service)
+    finally:
+        svc.close()
+        rep.service.close()
+
+
+def test_restarted_follower_reports_its_lag_until_it_has_caught_up(tmp_path,
+                                                                  rng):
+    """What a front federates into `fleet.front_max_lag_seq` is each
+    replica's reported `applied_seq`: a follower that was down while the
+    publisher advanced comes back behind the log head and not ready, and
+    joins to the head from its durable state."""
+    mdir = _save_model(rng, tmp_path)
+    svc, log, _pub = _publisher(mdir, tmp_path / "log")
+    rep = _follower(mdir, log, tmp_path / "s0")
+    services = [rep.service]
+    try:
+        _feedback(svc, 400)
+        rep.poll_once()
+        assert rep.status()["applied_seq"] == log.head_seq()
+        rep.service.close()                      # the follower goes down
+        for s in range(1, 3):
+            _feedback(svc, 400 + s)
+        rep2 = _follower(mdir, log, tmp_path / "s0", join=False)
+        services.append(rep2.service)
+        assert not rep2.healthy()
+        assert rep2.status()["applied_seq"] < log.head_seq()
+        rep2.join()
+        assert rep2.healthy()
+        assert rep2.status()["applied_seq"] == log.head_seq()
+        assert rep2.status()["lag_seq"] == 0
+        assert _audits_equal(svc, rep2.service)
+    finally:
+        svc.close()
+        for s in services:
+            s.close()
+
+
 def test_compaction_snapshot_join(tmp_path, rng):
     mdir = _save_model(rng, tmp_path)
     svc, log, _pub = _publisher(mdir, tmp_path / "log")
@@ -468,7 +526,11 @@ class _StubReplica:
 
             def do_POST(self):
                 length = int(self.headers.get("Content-Length") or 0)
-                self.rfile.read(length)
+                body = self.rfile.read(length)
+                if self.path == "/flight/dump":
+                    stub.flight_dumps.append(json.loads(body))
+                    self._reply(200, {})
+                    return
                 if stub.delay_s:
                     time.sleep(stub.delay_s)
                 stub.hits += 1
@@ -491,6 +553,7 @@ class _StubReplica:
         self.delay_s = 0.0
         self.hits = 0
         self.drained = False
+        self.flight_dumps = []
         self.feedback_status = 202
         self.httpd = ThreadingHTTPServer(("127.0.0.1", 0), Handler)
         self.url = f"http://127.0.0.1:{self.httpd.server_address[1]}"
@@ -555,6 +618,31 @@ def test_front_unready_replica_leaves_rotation(stubs):
             "fleet.front_max_lag_seq"] == 5
     finally:
         front.close()
+
+
+def test_front_dumps_and_broadcasts_one_flight_trigger_when_a_replica_leaves(
+        stubs, tmp_path):
+    """A replica leaving rotation is a fleet-level flight trigger: the
+    front dumps its own ring and asks every attached replica to dump
+    under the SAME trigger id, so the bundles can be laid side by side."""
+    from photon_ml_tpu.telemetry import flight
+    dumps = tmp_path / "dumps"
+    with flight.enabled(str(dumps), proc="front"):
+        front = _front(stubs, unhealthy_after=1)
+        try:
+            stubs[1].healthy = False
+            front.probe_once()
+            deadline = time.time() + 10
+            while not stubs[0].flight_dumps and time.time() < deadline:
+                time.sleep(0.01)
+        finally:
+            front.close()
+    (bundle,) = [json.loads(f.read_text()) for f in dumps.iterdir()]
+    assert bundle["reason"] == "replica.unhealthy"
+    assert stubs[0].flight_dumps
+    for sent in stubs[0].flight_dumps:
+        assert sent["reason"] == "replica.unhealthy"
+        assert sent["trigger_id"] == bundle["trigger_id"]
 
 
 def test_front_failover_on_dead_replica(stubs):
@@ -839,14 +927,15 @@ def test_graceful_drain_sigterm_subprocess(tmp_path, fill_buffer):
     save_game_model(_make_model(r), mdir)
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
-    proc = subprocess.Popen(
-        [sys.executable, "-m", "photon_ml_tpu.cli.serve",
-         "--model-dir", mdir, "--port", "0", "--max-batch", "32",
-         "--min-bucket", "4", "--enable-updates",
-         "--feedback-max-pending", "8" if fill_buffer else "1024",
-         "--update-interval-ms", "50"],
-        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, env=env,
-        text=True)
+    stderr_path = tmp_path / "serve.stderr"
+    with open(stderr_path, "w") as stderr_file:
+        proc = subprocess.Popen(
+            [sys.executable, "-m", "photon_ml_tpu.cli.serve",
+             "--model-dir", mdir, "--port", "0", "--max-batch", "32",
+             "--min-bucket", "4", "--enable-updates",
+             "--feedback-max-pending", "8" if fill_buffer else "1024",
+             "--update-interval-ms", "50"],
+            stdout=subprocess.PIPE, stderr=stderr_file, env=env, text=True)
     try:
         info = json.loads(proc.stdout.readline())
         url = info["serving"]
@@ -882,7 +971,9 @@ def test_graceful_drain_sigterm_subprocess(tmp_path, fill_buffer):
         if proc.poll() is None:
             proc.kill()
             proc.communicate(timeout=10)
-    assert proc.returncode == 0
+    assert proc.returncode == 0, (
+        f"drained serve child exited {proc.returncode}; stdout tail "
+        f"{out[-300:]!r}; stderr tail {stderr_path.read_text()[-2000:]!r}")
     last = json.loads(out.strip().splitlines()[-1])
     assert last["drained"] is True and last["aborted"] is False
     if not fill_buffer:

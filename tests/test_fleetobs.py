@@ -417,6 +417,46 @@ def test_replica_failure_dumps_flight_bundle(tmp_path, rng=None):
         rep.service.close()
 
 
+def test_request_tracing_adds_no_fresh_traces_armed_or_disarmed():
+    """Warm scoring traces nothing with fleet observability off, and
+    nothing with the tracer, the flight ring and a per-request
+    `server_span` context armed: no span name or request id reaches a jit
+    boundary."""
+    from photon_ml_tpu.serving import ScoringService, ServingConfig
+    from test_pipeline import _compile_counting
+    r = np.random.default_rng(29)
+    svc = ScoringService(model=_make_model(r),
+                         config=ServingConfig(max_batch=64, min_bucket=4))
+
+    def one_round(armed):
+        for k in range(24):
+            feats = {"global": r.normal(size=(4, D_G)),
+                     "per_user": r.normal(size=(4, D_U))}
+            ids = {"userId": np.asarray(
+                [f"u{r.integers(0, N_ENT)}" for _ in range(4)],
+                dtype=object)}
+            if armed:
+                with distributed.server_span(
+                        "serve_request", {TRACE_HEADER: f"{k:016x}"},
+                        path="/score"):
+                    svc.score(feats, ids)
+            else:
+                svc.score(feats, ids)
+
+    try:
+        one_round(False)                            # warm the bucket
+        with _compile_counting() as disarmed:
+            one_round(False)
+        with telemetry.enabled(watch_compiles=False) as tracer:
+            with flight.enabled(None, proc="serve"):
+                with _compile_counting() as armed:
+                    one_round(True)
+        assert (disarmed.count, armed.count) == (0, 0)
+        assert any(s.name == "serve_request" for s in tracer.spans)
+    finally:
+        svc.close()
+
+
 # --------------------------------------------------------------------------
 # the subprocess merge satellite: 2 replicas + front under load
 # --------------------------------------------------------------------------

@@ -307,6 +307,43 @@ def test_health_gate_pause_resume_rollback_lifecycle(rng):
     assert tracker.acquisitions().get("HealthMonitor._lock", 0) > 0
 
 
+def test_gate_trip_dumps_a_flight_bundle_holding_its_window(rng, tmp_path):
+    """A health-gate trip dumps the flight ring: the bundle on disk holds
+    the `health_gate_tripped` event and the `health_evaluate` spans that
+    led to it, before any operator attaches."""
+    import json
+    import os
+
+    from photon_ml_tpu import telemetry
+    from photon_ml_tpu.telemetry import flight
+    dumps = str(tmp_path / "dumps")
+    with telemetry.enabled(watch_compiles=False):
+        with flight.enabled(dumps, proc="serve"):
+            svc = _service(rng, updates=OnlineUpdateConfig(micro_batch=8),
+                           health=_lifecycle_config())
+            try:
+                for s in range(2):
+                    f, i, y = _calibrated_feedback(
+                        svc, np.random.default_rng(20 + s), 64)
+                    svc.feedback(f, i, y)
+                    svc.updater.flush()
+                for s in range(2):
+                    f, i, y = _calibrated_feedback(
+                        svc, np.random.default_rng(30 + s), 64, flip=True)
+                    svc.feedback(f, i, y)
+                assert svc.metrics_snapshot()["health"]["gate_trips"] == 1
+            finally:
+                svc.close()
+    bundles = [json.load(open(os.path.join(dumps, name)))
+               for name in sorted(os.listdir(dumps))]
+    tripped = [b for b in bundles if b["reason"] == "health.gate_trip"]
+    assert len(tripped) == 1
+    records = tripped[0]["records"]
+    assert any(r.get("name") == "health_gate_tripped" for r in records)
+    assert any(r.get("kind") == "span" and r.get("name") == "health_evaluate"
+               for r in records)
+
+
 def test_drift_gate_trips_without_labels(rng):
     """Covariate shift is detected from scores alone (no feedback, no
     updater): PSI/KS gates run on pure scoring traffic."""

@@ -442,3 +442,39 @@ def test_warm_latent_init_applies_only_to_cold_first_visit(rng, tmp_path):
         coords, cfg.updating_sequence, 1, ds, cfg.task_type,
         initial_models={"perUserMF": provided})
     assert np.isfinite(res.objective_history).all()
+
+# -- the schedule reaches every coordinate, the factored one included ---------
+
+@pytest.fixture(scope="module")
+def mf_strict_and_scheduled():
+    """One FE + RE + factored-MF dataset fitted twice over five outer
+    iterations, strict and under a schedule that starts at four inner
+    iterations: (strict, scheduled) descents.  Fitted once; the cases
+    below only read the diagnostics."""
+    ds = _mf_dataset(np.random.default_rng(7))
+    sched = SolverSchedule(initial_iterations=4, iteration_growth=2.0,
+                           initial_tolerance_factor=1e3, tolerance_decay=0.1)
+    strict = GameEstimator(_mf_config(5)).fit(ds).descent
+    scheduled = GameEstimator(dataclasses.replace(
+        _mf_config(5), solver_schedule=sched)).fit(ds).descent
+    return strict, scheduled
+
+
+@pytest.mark.parametrize("coordinate", ["fixed", "perUser", "perUserMF"])
+def test_schedule_caps_early_visits_of_every_coordinate(
+        mf_strict_and_scheduled, coordinate):
+    """Under a schedule the first visit of each coordinate (the factored
+    one too) runs at the schedule's small cap; a strict fit records no cap
+    on any visit."""
+    strict, scheduled = mf_strict_and_scheduled
+    caps = scheduled.solver_diagnostics()[coordinate]["iteration_caps"]
+    assert caps[0] is not None and caps[0] <= 4
+    assert all(c is None for c in
+               strict.solver_diagnostics()[coordinate]["iteration_caps"])
+
+
+def test_scheduled_fit_with_a_factored_coordinate_runs_fewer_iterations(
+        mf_strict_and_scheduled):
+    strict, scheduled = mf_strict_and_scheduled
+    assert scheduled.total_iterations() < strict.total_iterations()
+    assert np.isfinite(scheduled.objective_history).all()

@@ -481,11 +481,48 @@ def test_updater_start_close_race_spawns_one_thread(rng):
                    if t.ident not in before
                    and t.name == "photon-online-updater"]
         assert len(spawned) == 1
-        svc.updater.close(timeout=5)
+        svc.updater.close()
         assert not spawned[0].is_alive()
         # restartable after close
         svc.updater.start()
-        svc.updater.close(timeout=5)
+        svc.updater.close()
+    finally:
+        svc.close()
+
+
+def test_close_during_warmup_ends_it_and_joins_the_thread(rng, monkeypatch):
+    """A close() that lands while the loop thread is still warming up (a
+    SIGTERM drain of `cli.serve` soon after start, on a loaded machine)
+    stops the warm-up after the program in flight and returns only once
+    the thread is gone.  It used to wait 5 s and let the process exit with
+    the thread inside XLA: the drained child died by SIGABRT or SIGSEGV."""
+    import time
+
+    from photon_ml_tpu.online import updater as updater_mod
+    calls = []
+    entered = threading.Event()
+    real = updater_mod.solve_anchored
+
+    def slow_solve(*args, **kw):
+        calls.append(1)
+        entered.set()
+        time.sleep(0.3)
+        return real(*args, **kw)
+
+    monkeypatch.setattr(updater_mod, "solve_anchored", slow_solve)
+    svc = _service(rng, updates=OnlineUpdateConfig(
+        micro_batch=8, min_rows_bucket=4, max_rows_per_entity=256),
+        start_updater=False)
+    try:
+        svc.updater.start()
+        thread = svc.updater._thread
+        assert entered.wait(timeout=30)
+        svc.updater.close()
+        assert not thread.is_alive()
+        # seven S-buckets (4..256) were due; the warm-up stopped after
+        # the one in flight
+        assert len(calls) == 1
+        assert not svc.updater.warmed
     finally:
         svc.close()
 

@@ -6,6 +6,8 @@ Mirrors the reference's optimizer suite (photon-lib/src/test/.../optimization/
 plus TPU-specific requirements the reference never had: the whole solve must
 run under jit and vmap.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -138,9 +140,11 @@ def test_solve_under_jit_and_vmap(rng):
 
     batched = jax.jit(jax.vmap(solve_one))(xb, yb)
     assert batched.x.shape == (8, d)
-    # each batched solve must match its standalone solve
+    # each batched solve must match its standalone solve (one compiled
+    # program for the eight rows, not one per closure)
+    solve_alone = jax.jit(solve_one)
     for i in range(8):
-        single = solve_one(xb[i], yb[i])
+        single = solve_alone(xb[i], yb[i])
         np.testing.assert_allclose(batched.x[i], single.x, rtol=1e-6, atol=1e-8)
 
     # TRON under vmap too
@@ -252,6 +256,10 @@ _MARGIN_LOSSES = {"logistic": (LOGISTIC, "logistic"), "squared": (SQUARED, "line
 
 
 def _margin_objective(loss_name, features, variant, dtype, n=240, d=7):
+    """Every variant carries weights, offsets, a mask and a normalisation
+    (the ones it does not vary are 1, 0, 1 and the identity, which change
+    no float), so the three are ONE pytree structure and share a compiled
+    program: only loss, feature format and dtype retrace."""
     from photon_ml_tpu.ops.features import PaddedSparse
     from photon_ml_tpu.ops.normalization import NormalizationContext
     loss, task = _MARGIN_LOSSES[loss_name]
@@ -259,19 +267,41 @@ def _margin_objective(loss_name, features, variant, dtype, n=240, d=7):
     x, y, _, _ = make_glm_data(rng, n=n, d=d, task=task)
     x[rng.uniform(size=x.shape) < 0.4] = 0.0       # something to be sparse about
     x[:, -1] = 1.0
-    kw = {}
+    weights, offsets, mask = np.ones(n), np.zeros(n), np.ones(n)
+    factors, shifts = np.ones(d), np.zeros(d)
     if variant == "weights_offsets_mask":
-        kw = dict(weights=jnp.asarray(rng.uniform(0.5, 2.0, n), dtype),
-                  offsets=jnp.asarray(0.3 * rng.normal(size=n), dtype),
-                  mask=jnp.asarray(rng.uniform(size=n) < 0.8, dtype))
+        weights = rng.uniform(0.5, 2.0, n)
+        offsets = 0.3 * rng.normal(size=n)
+        mask = rng.uniform(size=n) < 0.8
     elif variant == "normalised":
         factors = rng.uniform(0.5, 2.0, d); factors[-1] = 1.0
         shifts = 0.2 * rng.normal(size=d); shifts[-1] = 0.0
-        kw = dict(norm=NormalizationContext(jnp.asarray(factors, dtype),
-                                            jnp.asarray(shifts, dtype), d - 1))
     xd = jnp.asarray(x, dtype)
     xf = PaddedSparse.from_dense(xd) if features == "padded_sparse" else xd
-    return GLMObjective(loss, xf, jnp.asarray(y, dtype), **kw)
+    return GLMObjective(
+        loss, xf, jnp.asarray(y, dtype), weights=jnp.asarray(weights, dtype),
+        offsets=jnp.asarray(offsets, dtype), mask=jnp.asarray(mask, dtype),
+        norm=NormalizationContext(jnp.asarray(factors, dtype),
+                                  jnp.asarray(shifts, dtype), d - 1))
+
+
+_MARGIN_LAM = 1.0
+
+
+@jax.jit
+def _margin_and_generic_solves(o, x0, cap, tol):
+    """(solve on cached margins, the generic lbfgs) of one objective: a
+    module-level jit, so the cases that differ only in data share it."""
+    from photon_ml_tpu.optim.schedule import SolveBudget
+    return (solve(o, x0, OptimizerConfig(), _L2, _MARGIN_LAM,
+                  budget=SolveBudget(cap, tol)),
+            lbfgs(o.with_l2(jnp.asarray(_MARGIN_LAM, x0.dtype))
+                  .value_and_gradient, x0, tolerance=tol, iteration_cap=cap))
+
+
+@jax.jit
+def _least_curvature(o, x):
+    return jnp.linalg.eigvalsh(jax.hessian(o.value)(x))[0]
 
 
 @pytest.mark.parametrize("dtype", ["float64", "float32"])
@@ -282,16 +312,10 @@ def test_margin_line_search_matches_generic_path(loss_name, features, variant, d
     dtype = jnp.dtype(dtype)
     obj = _margin_objective(loss_name, features, variant, dtype)
     x0 = jnp.zeros(obj.dim, dtype)
-    lam = 1.0
-    l2 = obj.with_l2(jnp.asarray(lam, dtype))
+    l2 = obj.with_l2(jnp.asarray(_MARGIN_LAM, dtype))
 
-    @jax.jit
     def both(o, cap, tol):
-        from photon_ml_tpu.optim.schedule import SolveBudget
-        return (solve(o, x0, OptimizerConfig(), _L2, lam,
-                      budget=SolveBudget(cap, tol)),
-                lbfgs(o.with_l2(jnp.asarray(lam, dtype)).value_and_gradient,
-                      x0, tolerance=tol, iteration_cap=cap))
+        return _margin_and_generic_solves(o, x0, cap, tol)
 
     def counted(on_margins, generic):
         assert int(on_margins.fg_count) == int(on_margins.iterations) + 2
@@ -331,10 +355,11 @@ def test_margin_line_search_matches_generic_path(loss_name, features, variant, d
     fresh = float(l2.value(on_margins.x))
     assert abs(float(on_margins.value) - fresh) <= 1e-5 * abs(fresh)
     assert abs(float(on_margins.value) - float(generic.value)) <= 1e-6 * abs(fresh)
-    l2_f64 = _margin_objective(loss_name, features, variant,
-                               jnp.dtype("float64")).with_l2(jnp.asarray(lam))
-    curvature = np.linalg.eigvalsh(np.asarray(jax.hessian(l2_f64.value)(
-        jnp.asarray(generic.x, jnp.float64))))[0]
+    l2_f64 = _margin_objective(
+        loss_name, features, variant,
+        jnp.dtype("float64")).with_l2(jnp.asarray(_MARGIN_LAM))
+    curvature = float(_least_curvature(
+        l2_f64, jnp.asarray(generic.x, jnp.float64)))
     ball = np.sqrt(2 * np.spacing(np.float32(fresh)) / curvature)
     assert float(jnp.linalg.norm(on_margins.x - generic.x)) <= 2 * ball
 
@@ -496,6 +521,14 @@ def _ring_two_loop(q, s_buf, y_buf, rho, num_pairs, m):
     return r
 
 
+@functools.lru_cache(maxsize=None)
+def _jitted_history_ops():
+    """(push, two-loop direction) under jit, wrapped ONCE: the cases of one
+    (m, d) differ only in what they push and share the two programs."""
+    from photon_ml_tpu.optim.lbfgs import _push, _two_loop
+    return jax.jit(_push), jax.jit(_two_loop)
+
+
 def _push_schedule(pushes, m):
     """`pushes` stored pairs, with a skipped pair before the first, in the
     middle, and (where the ring wraps) just as slot 0 is about to be reused."""
@@ -510,12 +543,11 @@ def _push_schedule(pushes, m):
 @pytest.mark.parametrize("d", [1, 21])
 @pytest.mark.parametrize("m", [1, 3, 10])
 def test_age_ordered_history_matches_a_ring_buffer_two_loop(m, d, pushes):
-    from photon_ml_tpu.optim.lbfgs import _empty_history, _push, _two_loop
+    from photon_ml_tpu.optim.lbfgs import _empty_history
     n = {"none": 0, "fewer": m - 1, "exactly_m": m, "wrapped": 2 * m + 3}[pushes]
     rng = np.random.default_rng(1000 * m + 10 * d + n)
     hist = _empty_history(m, d, jnp.float64)
-    push = jax.jit(_push)
-    direction = jax.jit(_two_loop)
+    push, direction = _jitted_history_ops()
     s_buf, y_buf, rho, num_pairs = np.zeros((m, d)), np.zeros((m, d)), np.zeros(m), 0
 
     def same_direction():

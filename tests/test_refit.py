@@ -159,6 +159,128 @@ def test_winning_candidate_swaps_and_records_metrics(rng, tmp_path):
         svc.close()
 
 
+def test_serving_traces_nothing_on_either_side_of_a_refit_swap(rng,
+                                                                tmp_path):
+    """Scoring rounds before the cycle and against the freshly installed
+    candidate trace nothing: install warms the candidate's bucket programs
+    off the request path."""
+    from test_pipeline import _compile_counting
+    svc = _service(rng, tmp_path)
+    try:
+        for _ in range(5):
+            f, i, y = _feedback(svc, rng, 32, flip=True)
+            svc.feedback(f, i, y)
+        driver, _ = _driver(svc, tmp_path)
+
+        def score_round(seed):
+            f, i, _ = _feedback(svc, np.random.default_rng(seed), 16)
+            svc.score(f, i)
+
+        for seed in range(2):                   # warm the bucket programs
+            score_round(seed)
+        with _compile_counting() as before:
+            for seed in range(10, 13):
+                score_round(seed)
+        version = svc.registry.version
+        assert driver.run_once().swapped
+        assert svc.registry.version != version
+        with _compile_counting() as after:
+            for seed in range(20, 23):
+                score_round(seed)
+        assert (before.count, after.count) == (0, 0)
+    finally:
+        svc.close()
+
+
+def test_drift_trip_refit_swap_recovery_closed_loop(rng, tmp_path):
+    """The loop end to end on a live service: label-flip feedback trips
+    the calibration gate and pauses the updater; the on-trip trigger runs
+    a cycle whose candidate wins and swaps in; the swap resets every gate
+    and resumes the updater; stationary traffic against the new model
+    trips nothing."""
+    from photon_ml_tpu.health import HealthConfig
+    svc = _service(rng, tmp_path, health=HealthConfig(
+        window_labels=64, window_scores=256, baseline_scores=256,
+        sustain_windows=2, recovery_windows=2, calibration_p_min=1e-4,
+        psi_max=None, ks_max=None))
+    try:
+        for _ in range(2):
+            f, i, y = _feedback(svc, rng, 64)
+            svc.feedback(f, i, y)
+            svc.updater.flush()
+        assert svc.healthz()["status"] == "ok"
+        incumbent = svc.registry.version
+        windows_to_trip = None
+        for w in range(1, 8):
+            f, i, y = _feedback(svc, rng, 64, flip=True)
+            svc.feedback(f, i, y)
+            if svc.healthz()["status"] == "degraded":
+                windows_to_trip = w
+                break
+        assert windows_to_trip is not None and windows_to_trip <= 3
+        assert svc.updater.paused
+
+        driver, _ = _driver(svc, tmp_path)
+        trigger = RefitTrigger(driver, health=svc.health,
+                               config=TriggerConfig(mode="on_trip",
+                                                    trip_polls=2,
+                                                    cooloff_s=0.0))
+        result = None
+        for _ in range(4):
+            result = trigger.poll()
+            if result is not None:
+                break
+        assert result is not None and result.swapped
+        assert result.version != incumbent
+        assert result.candidate["loss"] < result.incumbent["loss"]
+        verdict = svc.health.verdict()
+        assert verdict["status"] == "ok"
+        assert not verdict["updates_paused_by_health"]
+        assert not any(g["tripped"] for g in verdict["gates"].values())
+        assert not svc.updater.paused
+
+        # the refit learnt the flipped labels: they are the stationary
+        # traffic of the new model
+        trips = svc.metrics_snapshot()["health"]["gate_trips"]
+        for _ in range(3):
+            f, i, y = _feedback(svc, rng, 64)
+            svc.feedback(f, i, y)
+            svc.updater.flush()
+        assert svc.metrics_snapshot()["health"]["gate_trips"] == trips
+        assert svc.healthz()["status"] == "ok"
+        assert svc.metrics_snapshot()["refit"]["swaps"] == 1
+    finally:
+        svc.close()
+
+
+def test_cli_refit_runs_one_cycle_from_a_feedback_lane(rng, tmp_path,
+                                                       capsys):
+    """`python -m photon_ml_tpu.cli.refit` as the batch job beside a
+    serving process: it opens the lane the service wrote, compacts, refits
+    and lands the winning candidate under --model-root."""
+    from photon_ml_tpu.cli.refit import main as refit_main
+    from photon_ml_tpu.models.io import save_game_model
+    mdir = str(tmp_path / "incumbent")
+    svc = _service(rng, tmp_path)
+    try:
+        save_game_model(svc.registry.scorer.model, mdir)
+        for _ in range(5):
+            f, i, y = _feedback(svc, rng, 32, flip=True)
+            svc.feedback(f, i, y)
+    finally:
+        svc.close()
+    rc = refit_main([
+        "--model-dir", mdir, "--feedback-log", str(tmp_path / "fb"),
+        "--chunks", str(tmp_path / "chunks"),
+        "--model-root", str(tmp_path / "models"), "--chunk-rows", "64",
+        "--outer-iterations", "1", "--fe-iterations", "15",
+        "--re-iterations", "20"])
+    assert rc == 0
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["swapped"] is True
+    assert os.listdir(str(tmp_path / "models")) == [line["version"]]
+
+
 def test_losing_candidate_keeps_incumbent(rng, tmp_path):
     """An impossible win margin forces the loss: no swap, no version
     directory, the registry keeps serving the incumbent."""

@@ -212,6 +212,116 @@ def test_fanout_merge_bit_parity_and_per_shard_audits(tmp_path, rng):
             s.close()
 
 
+def test_warm_fanout_scoring_traces_nothing(tmp_path, rng):
+    """After one warm round, per-shard margin legs and the merge run on
+    cached programs only."""
+    from test_pipeline import _compile_counting
+    mdir = _save_model(rng, tmp_path)
+    spec = ShardSpec(num_shards=2)
+    svcs = [_shard_service(mdir, k, 2) for k in range(2)]
+    meta = svcs[0].registry.scorer.coordinate_meta()
+
+    def fan_out():
+        feats, ids = _request(rng)
+        legs = {k: svcs[k].score_margins(feats, ids)["margins"]
+                for k in range(2)}
+        return merge_margins(spec, meta, ids, legs, primary=0)["scores"]
+
+    try:
+        fan_out()
+        with _compile_counting() as counter:
+            for _ in range(3):
+                assert len(fan_out()) == 12
+        assert counter.count == 0
+    finally:
+        for s in svcs:
+            s.close()
+
+
+def test_shard_filtered_replay_is_trace_free_and_sha256_exact(tmp_path, rng):
+    """Sharded replicas tail the fleet's one log and apply only their
+    owned slice: steady-state replay compiles nothing, and each replica's
+    table hashes equal the publisher's per-shard filter of its full
+    tables (the /fleet/audit?shard=K contract)."""
+    from photon_ml_tpu.fleet import (FleetPublisher, Replica, ReplicaConfig,
+                                     ReplicationLog)
+    from photon_ml_tpu.online import OnlineUpdateConfig
+    from test_pipeline import _compile_counting
+    mdir = _save_model(rng, tmp_path)
+    spec = ShardSpec(num_shards=2)
+    svc = ScoringService(
+        model_dir=mdir, config=ServingConfig(max_batch=64, min_bucket=4),
+        updates=OnlineUpdateConfig(micro_batch=8), start_updater=False)
+    log = ReplicationLog(str(tmp_path / "log"))
+    pub = FleetPublisher(svc, log, model_dir=mdir, shard_spec=spec)
+    reps = [Replica(_shard_service(mdir, k, 2), log, str(tmp_path / f"s{k}"),
+                    ReplicaConfig()) for k in range(2)]
+
+    def feedback_round():
+        feats, ids = _request(rng, n=24)
+        svc.feedback(feats, ids, (rng.uniform(size=24) < 0.5).astype(float))
+        svc.updater.flush()
+
+    try:
+        for rep in reps:
+            rep.join()
+        svc.updater.warmup()
+        for _ in range(2):      # warm: publisher solve + replica scatter
+            feedback_round()
+            for rep in reps:
+                rep.poll_once()
+        fresh = applied = 0
+        for _ in range(4):
+            feedback_round()
+            with _compile_counting() as counter:
+                for rep in reps:
+                    applied += rep.poll_once()
+            fresh += counter.count
+        assert fresh == 0 and applied >= 4
+        for k, rep in enumerate(reps):
+            assert rep.service.audit()["table_hashes"] == \
+                pub.shard_audit(k)["table_hashes"]
+            assert rep.service.version_vector() == svc.version_vector()
+    finally:
+        svc.close()
+        for rep in reps:
+            rep.service.close()
+
+
+def test_four_budgeted_shards_serve_four_times_one_store_bit_exactly(
+        tmp_path, rng):
+    """The capacity claim: four sharded services, each behind a tiered
+    store whose hot tier holds a quarter of the random-effect rows, serve
+    the whole table with the monolithic scorer's bits."""
+    n_shards = 4
+    budget = -(-N_ENT // n_shards)
+    mdir = _save_model(rng, tmp_path)
+    spec = ShardSpec(num_shards=n_shards)
+    mono = _service(mdir)
+    svcs = [ScoringService(model_dir=mdir, config=ServingConfig(
+        max_batch=64, min_bucket=4, shard_index=k, shard_count=n_shards,
+        store_budget_rows=budget, store_dir=str(tmp_path / f"store{k}")))
+        for k in range(n_shards)]
+    meta = svcs[0].registry.scorer.coordinate_meta()
+    try:
+        for _ in range(4):
+            feats, ids = _request(rng, n=16)
+            legs = {k: svcs[k].score_margins(feats, ids)["margins"]
+                    for k in range(n_shards)}
+            got = np.asarray(merge_margins(spec, meta, ids, legs,
+                                           primary=0)["scores"], np.float64)
+            expected = np.asarray(mono.score(feats, ids), np.float64)
+            assert got.tobytes() == expected.tobytes()
+        owned = [sum(s.registry.scorer.shard_info()["owned_rows"].values())
+                 for s in svcs]
+        assert sum(owned) == N_ENT
+        assert N_ENT / budget >= 3.75      # 30 rows over stores of 8
+    finally:
+        mono.close()
+        for s in svcs:
+            s.close()
+
+
 def test_merge_missing_owner_policies(tmp_path, rng):
     mdir = _save_model(rng, tmp_path)
     spec = ShardSpec(num_shards=2)
@@ -240,11 +350,14 @@ def test_merge_missing_owner_policies(tmp_path, rng):
 # the front over sharded HTTP replicas
 # --------------------------------------------------------------------------
 
-@pytest.fixture
-def sharded_http(tmp_path, rng):
+@pytest.fixture(scope="module")
+def sharded_http(tmp_path_factory):
     """3 sharded services behind real serve-CLI HTTP servers, plus a
-    monolithic reference service."""
-    mdir = _save_model(rng, tmp_path)
+    monolithic reference service: built once, the tests below only send
+    requests (the one that takes a shard's server down puts a new one in
+    its place)."""
+    mdir = _save_model(np.random.default_rng(7),
+                       tmp_path_factory.mktemp("sharded_http"))
     mono = _service(mdir)
     svcs = [_shard_service(mdir, k, 3) for k in range(3)]
     servers = [_serve_http(s) for s in svcs]
@@ -388,6 +501,8 @@ def test_front_lost_shard_degrades_only_that_shard(sharded_http, rng):
     finally:
         front.close()
         front_err.close()
+        sharded_http["servers"][lost] = _serve_http(
+            sharded_http["svcs"][lost])
 
 
 def test_front_rejects_mismatched_shard_spec(sharded_http, tmp_path, rng):

@@ -189,6 +189,43 @@ def test_tiered_scorer_parity_with_resident(rng, tmp_path):
     assert tiered.table_hashes() == resident.table_hashes()
 
 
+def test_skewed_traffic_is_served_from_the_hot_tier(rng, tmp_path):
+    """The residency claim at a small size: a hot tier a fifth of the
+    table serves traffic whose head (97% of lookups) fits it at a hot hit
+    rate of 90% or better once the misses have been promoted."""
+    hot, head = 64, 40
+    tiered = CompiledScorer(
+        _make_model(rng), max_batch=64, min_bucket=8,
+        store=StoreConfig(hot_rows=hot, warm_segments=2, seg_rows=64,
+                          overlay_rows=64, flush_rows=64,
+                          scatter_chunk=64, lfu_sample=64),
+        store_dir=str(tmp_path / "store"))
+    tiered.warmup()
+    st = tiered.entity_store("perUser")
+
+    def traffic(n=48):
+        rows = rng.integers(0, head, size=n)
+        tail = rng.random(n) >= 0.97
+        rows[tail] = rng.integers(0, N_ENT, size=int(tail.sum()))
+        return ({"global": rng.normal(size=(n, D_G)),
+                 "per_user": rng.normal(size=(n, D_U))},
+                {"userId": np.asarray([f"u{r}" for r in rows],
+                                      dtype=object)})
+
+    for _ in range(10):                 # the head gets promoted
+        tiered.score(*traffic())
+    st.promote_pending()
+    before = st.stats.snapshot()
+    for _ in range(20):
+        tiered.score(*traffic())
+    after = st.stats.snapshot()
+    hits, warm, cold = (after[k] - before[k]
+                        for k in ("hot_hits", "warm_hits", "cold_misses"))
+    assert hot < N_ENT
+    assert hits / (hits + warm + cold) >= 0.90
+    assert after["promotions"] > 0
+
+
 def test_delta_to_warm_row_rollback_restores_exact_bytes(rng, tmp_path):
     """ISSUE 14 satellite: a delta landing on rows living in the hot,
     warm AND cold tiers, followed by rollback, restores the exact

@@ -667,3 +667,92 @@ def test_a_second_identical_fit_traces_nothing(rng):
     after_first = _jax_counters()
     GameEstimator(_tiny_config()).fit(ds)
     assert _jax_counters() == after_first
+
+
+# --------------------------------------------------------------------------
+# cli.train --trace-out / --run-log / --fault-plan, end to end
+# --------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def cli_trace_run(tmp_path_factory):
+    """One `cli.train` run of a two-coordinate GAME fit, two outer
+    iterations, with the tracer armed by --trace-out and --run-log and the
+    second solve (perUser, outer iteration 0) poisoned by --fault-plan.
+    Run once; the tests below read what it wrote."""
+    from photon_ml_tpu.cli.train import main as train_main
+    from photon_ml_tpu.data.game_data import save_game_dataset
+    tmp = tmp_path_factory.mktemp("cli_trace")
+    data = str(tmp / "train.npz")
+    save_game_dataset(_tiny_game(np.random.default_rng(17)), data)
+    (tmp / "game.json").write_text(_tiny_config(outer=2).to_json())
+    out = tmp / "out"
+    rc = train_main([
+        "--train-data", data, "--task", "logistic_regression",
+        "--config", str(tmp / "game.json"), "--output-dir", str(out),
+        "--mesh", "none", "--trace-out", str(out / "trace.json"),
+        "--run-log", str(out / "run-log.jsonl"),
+        "--checkpoint-dir", str(tmp / "ckpt"),
+        "--fault-plan", json.dumps({"faults": [
+            {"site": "solve.poison", "action": "poison", "hits": [2]}]})])
+    return {
+        "rc": rc,
+        "trace": json.loads((out / "trace.json").read_text()),
+        "records": [json.loads(line) for line in
+                    (out / "run-log.jsonl").read_text().splitlines()],
+        "summary": json.loads((out / "training-summary.json").read_text()),
+    }
+
+
+def test_cli_trace_out_is_a_valid_chrome_trace(cli_trace_run):
+    assert cli_trace_run["rc"] == 0
+    assert telemetry.validate_chrome_trace(cli_trace_run["trace"]) == []
+    assert cli_trace_run["records"]
+
+
+def test_cli_trace_nests_outer_iterations_visits_and_solves(cli_trace_run):
+    """The exported span TREE, by the args.span / args.parent ids: two
+    outer iterations, a visit per coordinate inside each, every solve
+    inside a visit, and the checkpoint writes present."""
+    spans = {e["args"]["span"]: e
+             for e in cli_trace_run["trace"]["traceEvents"]
+             if e.get("ph") == "X" and "span" in e.get("args", {})}
+    by_name = {}
+    for e in spans.values():
+        by_name.setdefault(e["name"], []).append(e)
+
+    def parent_name(e):
+        parent = spans.get(e["args"].get("parent"))
+        return parent["name"] if parent else None
+
+    assert len(by_name["outer_iteration"]) == 2
+    assert len(by_name["coordinate_visit"]) == 4
+    assert {parent_name(e) for e in by_name["coordinate_visit"]} == {
+        "outer_iteration"}
+    assert by_name["solve"]
+    assert {parent_name(e) for e in by_name["solve"]} == {"coordinate_visit"}
+    assert by_name.get("checkpoint_write") or by_name.get("checkpoint")
+
+
+def test_cli_run_log_attributes_the_fault_to_its_coordinate_visit(
+        cli_trace_run):
+    """The injected solve.poison is an event under the perUser visit's
+    span chain, its quarantine is logged, and the summary says the retry
+    recovered."""
+    records = cli_trace_run["records"]
+    spans = {r["span"]: r for r in records if r["kind"] == "span"}
+
+    def visit_coordinate(record):
+        sid = record["span"]
+        while sid is not None and sid in spans:
+            if spans[sid]["name"] == "coordinate_visit":
+                return spans[sid]["attrs"].get("coordinate")
+            sid = spans[sid]["parent"]
+        return None
+
+    fired = [r for r in records
+             if r["kind"] == "event" and r["name"] == "fault"]
+    assert [visit_coordinate(r) for r in fired] == ["perUser"]
+    assert any(r["kind"] == "event" and r["name"] == "quarantine"
+               for r in records)
+    diagnostics = cli_trace_run["summary"]["solver_diagnostics"]
+    assert "retry_ok" in diagnostics["perUser"]["containment"]
