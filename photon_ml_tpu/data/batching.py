@@ -63,9 +63,10 @@ class RandomEffectDataConfig:
     projector: str = "index_map"
     seed: int = 7
     # cap on the number of S-buckets: each bucket shape is a separate XLA
-    # compile of the vmapped per-entity solver, so unbounded power-of-two
-    # classes trade compile wall-clock for padding efficiency.  None = one
-    # bucket per power-of-two class.
+    # compile (and launch) of the vmapped per-entity solver.  Under the cap
+    # the boundaries are the ones that leave the fewest padded cells
+    # (`_bucket_bounds`).  None = one bucket per power-of-two class of the
+    # padded sample count, however many classes there are.
     max_buckets: Optional[int] = 4
     # keep the host numpy block arrays alongside the device copies so the
     # coordinate residency manager can EVICT the device blocks between
@@ -110,9 +111,12 @@ class EntityBucket:
     dataset's count-descending lane order, padded to this bucket's own S.
 
     SURVEY §7 "Hard parts" — bucketed batches: one hot entity must not pad
-    every block, so entities are grouped by ceil-power-of-two sample count
-    and each class is padded only to its own max (the reference never faces
-    this because its per-entity data is ragged RDD rows).
+    every block, so the count-descending lanes are cut into at most
+    `max_buckets` contiguous runs, at the boundaries that leave the fewest
+    padded cells, and each run is padded only to its own largest count,
+    rounded up to the sample granule the chip's layout pads to anyway (the
+    reference never faces this because its per-entity data is ragged RDD
+    rows).
 
     Device residency: `blocks` is a lazily materialized device copy.  In the
     default (resident) build the device copy is created eagerly at build
@@ -221,9 +225,10 @@ class RandomEffectDataset:
         also realizes addScoresToOffsets as one gather
 
     Entities live in count-descending lane order, partitioned into S-buckets
-    (`buckets`); `blocks` / `active_row_ids` are single-S compatibility views
-    padded to the global max (materialized lazily — the plain random-effect
-    solve path iterates buckets and never builds them).
+    (`buckets`: contiguous runs of lanes, boundaries by `_bucket_bounds`, S a
+    multiple of the sample granule); `blocks` / `active_row_ids` are single-S
+    compatibility views padded to the global max (materialized lazily — the
+    plain random-effect solve path iterates buckets and never builds them).
     """
 
     config: RandomEffectDataConfig
@@ -397,6 +402,75 @@ def _is_np_dense(x) -> bool:
         return True
 
 
+#: rows the chip's layout pads a bucket's sample axis to.  The v5e compile of
+#: `jit_re_bucket_solve` lays S on sublanes (`f32[E,S]{0,1:T(8,128)}`,
+#: `f32[E,S,d]{0,1,2:T(8,128)}`: lanes minor) unless S is a multiple of 128,
+#: when it lies on lanes and pads nothing; either way a multiple of 8 costs
+#: what it says and anything else costs the next one.  (The line search's
+#: trial fusion reads S on lanes at every S; boundaries that counted that
+#: measured no faster on the chip: PERF.md, PR 30.)
+_SAMPLE_GRANULE = 8
+#: bucket boundaries are searched among the distinct padded counts, thinned
+#: to one per factor of (1 + this): at most this share of cells above the
+#: minimum, and a candidate list whose length grows with the logarithm of
+#: the largest count.  Multiples of the granule up to 64 granules (512 rows)
+#: differ by more than the factor, so none of them is thinned away.
+_BOUNDARY_LOSS = 1.0 / 64.0
+
+
+def _padded_samples(counts: np.ndarray) -> np.ndarray:
+    """Per-entity row counts rounded up to the sample granule."""
+    return -(-np.asarray(counts, np.int64) // _SAMPLE_GRANULE) * _SAMPLE_GRANULE
+
+
+def _bucket_bounds(samples_lane: np.ndarray,
+                   max_buckets: Optional[int]) -> np.ndarray:
+    """Lane boundaries `[0, ..., E]` of the S-buckets, given the padded
+    sample counts in lane (descending) order.
+
+    Under a cap it is the partition of the lanes into at most `max_buckets`
+    contiguous runs that minimises the padded cells, sum of entities x S
+    with S the run's first (largest) padded count: what the solves, the
+    offsets gather and the staging stream is proportional to cells, and the
+    cap bounds the compiled bucket shapes, so the boundaries go where the
+    counts' mass is.  A run only ever starts at the first lane of a distinct
+    padded count (starting later pads that count's lanes to the run before),
+    so it is a dynamic programme over those candidates, no loop over
+    entities.  Without a cap (`None`): one bucket per power-of-two class of
+    the padded count."""
+    E = len(samples_lane)
+    if max_buckets is None or max_buckets < 1:
+        starts = np.flatnonzero(np.diff(_ceil_pow2(samples_lane))) + 1
+        return np.concatenate([[0], starts, [E]])
+    first = np.concatenate([[0], np.flatnonzero(np.diff(samples_lane)) + 1])
+    # the largest count of each geometric bin stands for the bin
+    geo = np.floor(np.log(samples_lane[first]) / np.log1p(_BOUNDARY_LOSS))
+    first = first[np.concatenate([[True], np.diff(geo) != 0])]
+    m = len(first)
+    s_of = samples_lane[first]
+    end = np.append(first[1:], E)
+    # best[j]: fewest cells of lanes [0, end[j]) in at most k runs.  Row 0 of
+    # `options` keeps the k - 1 answer (ties go to fewer buckets); row i >= 1
+    # starts the last run at candidate i
+    best = s_of[0] * end
+    last_run = np.where(np.arange(m)[:, None] <= np.arange(m),
+                        s_of[:, None] * (end - first[:, None]),
+                        np.iinfo(np.int64).max // 2)[1:]
+    choices = []
+    for _ in range(min(max_buckets, m) - 1):
+        options = np.concatenate([best[None, :],
+                                  best[:-1, None] + last_run])
+        choices.append(options.argmin(axis=0))
+        best = options.min(axis=0)
+    starts, j = [], m - 1
+    for choice in reversed(choices):
+        i = int(choice[j])
+        if i:
+            starts.append(first[i])
+            j = i - 1
+    return np.concatenate([[0], starts[::-1], [E]]).astype(np.int64)
+
+
 def _build_random_effect_dataset(
     dataset: GameDataset,
     config: RandomEffectDataConfig,
@@ -405,7 +479,7 @@ def _build_random_effect_dataset(
     """Fully vectorized build: one lexsort replaces groupByKey, the per-entity
     reservoir cap is a segmented random-key rank cut, the index-map projector
     is segment reductions over the group-sorted rows, and entities are packed
-    into power-of-two S-buckets in count-descending lane order.  No O(E)
+    into cell-minimal S-buckets in count-descending lane order.  No O(E)
     Python loops anywhere (VERDICT r2 item #2; reference:
     RandomEffectDataSet.scala:240-472 + MinHeapWithFixedCapacity)."""
     re_type = config.random_effect_type
@@ -467,7 +541,7 @@ def _build_random_effect_dataset(
         counts = np.bincount(grp, minlength=E)
         starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
 
-    # --- lane order: count-descending, then pow2 S-buckets ---------------
+    # --- lane order: count-descending, then cell-minimal S-buckets -------
     perm = np.argsort(-counts, kind="stable")        # lane -> uniq rank
     lane_of = np.empty(E, dtype=np.int64)
     lane_of[perm] = np.arange(E)                     # uniq rank -> lane
@@ -476,17 +550,8 @@ def _build_random_effect_dataset(
     entity_position = np.full(dataset.num_entities(re_type), -1, dtype=np.int64)
     entity_position[entity_ids] = np.arange(E)
 
-    pow2_lane = _ceil_pow2(counts_lane)
-    # group adjacent power-of-two classes when there are more classes than
-    # max_buckets (compile-count cap; padding cost shows in build_counts)
-    uniq_keys, key_of_lane = np.unique(pow2_lane, return_inverse=True)
-    n_classes = len(uniq_keys)
-    mb = config.max_buckets
-    if mb is not None and n_classes > mb > 0:
-        width = -(-n_classes // mb)
-        key_of_lane = ((n_classes - 1) - key_of_lane) // width
-    bucket_bounds = np.concatenate(
-        [[0], np.flatnonzero(np.diff(key_of_lane)) + 1, [E]])
+    samples_lane = _padded_samples(counts_lane)
+    bucket_bounds = _bucket_bounds(samples_lane, config.max_buckets)
 
     # kept rows in (lane, canonical-row) order; per-lane slot index
     lane_rows = lane_of[grp]
@@ -564,9 +629,9 @@ def _build_random_effect_dataset(
     for b in range(len(bucket_bounds) - 1):
         lb, ub = int(bucket_bounds[b]), int(bucket_bounds[b + 1])
         Eb = ub - lb
-        Sb = int(counts_lane[lb:ub].max()) if Eb else 1
+        Sb = int(samples_lane[lb])        # descending: the bucket's largest
         sel = in_bucket_of_lane == b
-        r_ids = np.full((Eb, max(Sb, 1)), -1, dtype=np.int64)
+        r_ids = np.full((Eb, Sb), -1, dtype=np.int64)
         r_ids[lane_l[sel] - lb, slot_l[sel]] = row_ids_l[sel]
         mask = (r_ids >= 0).astype(dtype)
         gat = np.where(r_ids >= 0, r_ids, n)  # pad cell -> zero row

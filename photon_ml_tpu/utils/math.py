@@ -20,11 +20,11 @@ DEFAULT_SEED = 7
 
 def ceil_pow2(v):
     """Smallest power of two >= v (v >= 1), elementwise over arrays and
-    exact for scalars.  The ONE shape-bucketing rule shared by training prep
-    (data/batching.py packs entities into power-of-two sample-count buckets)
-    and the serving micro-batcher (serving/ pads request batches to
-    power-of-two sizes) — both trade padding waste for a bounded set of XLA
-    program shapes, and sharing the rule keeps the two from drifting."""
+    exact for scalars.  The ONE power-of-two shape rule: the serving
+    micro-batcher and the streamed chunks pad to power-of-two sizes, and
+    data/batching.py classes entities by it where no `max_buckets` caps the
+    bucket count — each trades padding waste for a bounded set of XLA
+    program shapes, and sharing the rule keeps them from drifting."""
     if np.isscalar(v) or np.ndim(v) == 0:
         return 1 << max(int(v) - 1, 0).bit_length()
     return 1 << np.ceil(np.log2(np.maximum(v, 1))).astype(np.int64)

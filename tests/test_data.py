@@ -18,9 +18,12 @@ from photon_ml_tpu.data import (
     binary_classification_downsample, build_game_dataset, build_index_map,
     build_random_effect_dataset, feature_key, read_libsvm,
 )
+from photon_ml_tpu.data.batching import (_BOUNDARY_LOSS, _SAMPLE_GRANULE,
+                                          _bucket_bounds, _padded_samples)
 from photon_ml_tpu.ops import LOGISTIC
 from photon_ml_tpu.optim import RegularizationContext, RegularizationType
 from photon_ml_tpu.parallel import fit_random_effects, score_by_entity
+from photon_ml_tpu.utils.math import ceil_pow2
 
 
 def test_index_map_roundtrip(tmp_path):
@@ -191,6 +194,155 @@ def test_offsets_from_flat(rng):
                 assert float(blocks.offsets[e, s]) == 0.0
 
 
+def _bucket_cells(samples, bounds):
+    """Padded cells of a partition: entities x the first (largest) S."""
+    return int(sum((hi - lo) * samples[lo]
+                   for lo, hi in zip(bounds[:-1], bounds[1:])))
+
+
+def _parent_rule_bounds(counts_lane, max_buckets):
+    """The rule before PR 30, kept as the reference to beat: ceil-power-of-
+    two classes of the raw count, adjacent classes merged in groups of
+    ceil(n_classes / max_buckets)."""
+    classes, key = np.unique(ceil_pow2(counts_lane), return_inverse=True)
+    if len(classes) > max_buckets:
+        key = ((len(classes) - 1) - key) // -(-len(classes) // max_buckets)
+    return np.concatenate([[0], np.flatnonzero(np.diff(key)) + 1,
+                           [len(counts_lane)]])
+
+
+def _skewed_counts(kind, seed, entities=4000, cap=512):
+    """Per-entity row counts, descending, as a capped coordinate has them."""
+    rng = np.random.default_rng(seed)
+    raw = (rng.lognormal(3.5, 1.3, entities) if kind == "lognormal"
+           else rng.zipf(1.3, entities))
+    return -np.sort(-np.clip(raw.astype(np.int64), 1, cap))
+
+
+def _cell_counts(config_name):
+    """Training rows per user and, where the configuration has items as
+    entities, per item, as its builder's generators draw them from
+    `shape_seed` (counts only)."""
+    import json
+    from benchmark.builders.game_fit import N_USER_FEATS
+    from benchmark.builders.game_fit_user_item import quantile_values
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, "benchmark", "configs",
+                           config_name + ".json")) as f:
+        cfg = json.load(f)
+    p = cfg["params"]
+    n_val = int(round(p["validation_share"] * cfg["rows"]))
+    shape = np.random.default_rng(p["shape_seed"])
+    prop = shape.lognormal(0.0, 1.1, cfg["users"])
+    users = shape.multinomial(cfg["rows"] - n_val, prop / prop.sum())
+    drawn = [users]
+    if "item_count_quantiles" in p:
+        shape.multinomial(n_val, prop / prop.sum())
+        shape.normal(size=cfg["genres"] + N_USER_FEATS + 1)
+        prop = quantile_values(p["item_count_quantiles"], cfg["items"])
+        drawn.append(shape.multinomial(cfg["rows"] - n_val,
+                                       prop / prop.sum()))
+    cap = p["active_data_upper_bound"]
+    return [-np.sort(-np.minimum(c[c > 0], cap)) for c in drawn]
+
+
+class TestBucketBounds:
+    """The rule that places the S-bucket boundaries (`_bucket_bounds`), on
+    count vectors alone: NumPy, no build, no device."""
+
+    @pytest.mark.parametrize("cap", [1, 2, 3, 4, 5])
+    @pytest.mark.parametrize("seed", range(6))
+    def test_partition_equals_brute_force(self, seed, cap):
+        import itertools
+        rng = np.random.default_rng(seed)
+        counts = -np.sort(-rng.integers(1, 200, size=rng.integers(6, 12)))
+        samples = _padded_samples(counts)
+        bounds = _bucket_bounds(samples, cap)
+        E = len(counts)
+        best = min(_bucket_cells(samples, [0, *cuts, E])
+                   for k in range(cap)
+                   for cuts in itertools.combinations(range(1, E), k))
+        assert _bucket_cells(samples, bounds) == best
+        assert len(bounds) - 1 <= cap
+        assert bounds[0] == 0 and bounds[-1] == E
+        assert (np.diff(bounds) > 0).all()
+
+    @pytest.mark.parametrize("max_buckets", [2, 4, 6])
+    @pytest.mark.parametrize("seed", range(3))
+    @pytest.mark.parametrize("kind", ["lognormal", "zipf"])
+    def test_cells_never_exceed_the_parent_rule(self, kind, seed,
+                                                max_buckets):
+        """Against the parent's boundaries on the same padded counts (what
+        the chip lays out for either), under the same cap."""
+        counts = _skewed_counts(kind, seed)
+        samples = _padded_samples(counts)
+        ours = _bucket_bounds(samples, max_buckets)
+        theirs = _parent_rule_bounds(counts, max_buckets)
+        assert len(ours) - 1 <= max_buckets
+        assert _bucket_cells(samples, ours) <= _bucket_cells(samples, theirs)
+
+    @pytest.mark.parametrize("seed", [3, 4])
+    def test_uncapped_counts_are_thinned_within_the_stated_loss(self, seed):
+        """Hundreds of distinct counts above 64 granules: the candidates are
+        thinned to one a factor of 1 + _BOUNDARY_LOSS, and the cells stay
+        within that share of the exact minimum (a plain dynamic programme
+        over every distinct padded count)."""
+        rng = np.random.default_rng(seed)
+        counts = -np.sort(-np.clip(rng.lognormal(6.5, 1.2, 3000), 1, 50_000
+                                   ).astype(np.int64))
+        samples = _padded_samples(counts)
+        assert len(np.unique(samples[samples > 512])) > 200
+        first = np.concatenate([[0], np.flatnonzero(np.diff(samples)) + 1])
+        end = np.append(first[1:], len(samples))
+        best = [int(samples[0] * e) for e in end]
+        for _ in range(3):
+            best = [min([best[j]] + [best[i - 1] + int(samples[first[i]])
+                                     * int(end[j] - first[i])
+                                     for i in range(1, j + 1)])
+                    for j in range(len(first))]
+        got = _bucket_cells(samples, _bucket_bounds(samples, 4))
+        assert best[-1] <= got <= (1 + _BOUNDARY_LOSS) * best[-1]
+
+    def test_no_cap_and_a_cap_of_one(self):
+        counts = _skewed_counts("lognormal", 1)
+        samples = _padded_samples(counts)
+        np.testing.assert_array_equal(_bucket_bounds(samples, 1),
+                                      [0, len(counts)])
+        bounds = _bucket_bounds(samples, None)
+        classes = ceil_pow2(samples[bounds[:-1]]).tolist()  # one a class
+        assert classes == sorted(set(ceil_pow2(samples).tolist()),
+                                 reverse=True)
+        alike = _padded_samples(np.full(100, 37))
+        np.testing.assert_array_equal(_bucket_bounds(alike, 4), [0, 100])
+
+    def test_benchmark_cells_shed_the_stated_share_of_cells(self):
+        """At the two configurations' own counts: the parent's rule gives
+        the bucket shapes the configuration file states (so the counts are
+        the cells'), per-user cells fall to at most 0.63 and per-item cells
+        to at most 0.82 of them, under a third of all cells stay empty, and
+        the search takes no time set-up could see."""
+        import time
+        users, items = _cell_counts("glmix-ml20m-user-item")
+        np.testing.assert_array_equal(users, *_cell_counts("glmix-ml20m"))
+        stated = {"users": [(30_518, 512), (23_221, 64), (1_594, 8), (45, 1)],
+                  "items": [(7_220, 512), (5_556, 64), (6_475, 8), (3_406, 1)]}
+        cells = real = 0
+        for name, counts, share in (("users", users, 0.63),
+                                    ("items", items, 0.82)):
+            parent = _parent_rule_bounds(counts, 4)
+            assert [(hi - lo, counts[lo]) for lo, hi in
+                    zip(parent[:-1], parent[1:])] == stated[name]
+            samples = _padded_samples(counts)
+            t0 = time.perf_counter()
+            bounds = _bucket_bounds(samples, 4)
+            assert time.perf_counter() - t0 < 0.1
+            assert len(bounds) - 1 <= 4
+            ours = _bucket_cells(samples, bounds)
+            assert ours <= share * _bucket_cells(counts, parent)
+            cells, real = cells + ours, real + int(counts.sum())
+        assert (cells - real) / cells < 0.33
+
+
 class TestBucketedBuild:
     """S-bucketed RE build (VERDICT r2 item #2): multiple size classes, no
     hot-entity padding blowup, per-bucket solves equal the single-block
@@ -208,7 +360,7 @@ class TestBucketedBuild:
                                   entity_ids={"per_user": np.asarray(users)})
 
     def test_buckets_bound_padding(self, rng):
-        ds = self._skewed_dataset(rng)
+        ds = self._skewed_dataset(rng, small_n=8)      # one sample granule
         red = build_random_effect_dataset(
             ds, RandomEffectDataConfig("per_user", "g", projector="identity"))
         stats = red.build_counts
@@ -223,6 +375,37 @@ class TestBucketedBuild:
         ids = np.asarray(red.active_row_ids)
         real = np.sort(ids[ids >= 0])
         np.testing.assert_array_equal(real, np.arange(ds.num_rows))
+
+    @pytest.mark.parametrize("max_buckets", [1, 2, 4, None])
+    def test_buckets_are_contiguous_runs_of_granule_multiples(
+            self, rng, max_buckets):
+        sizes = np.clip(rng.lognormal(2.5, 1.2, 60).astype(int), 1, 300)
+        users = np.repeat(np.arange(60), sizes)
+        x = rng.normal(size=(len(users), 3))
+        ds = build_game_dataset((rng.uniform(size=len(users)) < 0.5) * 1.0,
+                                {"g": x}, entity_ids={"per_user": users})
+        red = build_random_effect_dataset(
+            ds, RandomEffectDataConfig("per_user", "g", projector="identity",
+                                       max_buckets=max_buckets))
+        if max_buckets is not None:
+            assert len(red.buckets) <= max_buckets
+        lane, per_lane = 0, []
+        for b in red.buckets:
+            assert b.lane_start == lane
+            lane += b.num_entities
+            real = (b.row_ids >= 0).sum(axis=1)
+            assert b.samples_per_entity % _SAMPLE_GRANULE == 0
+            assert real.max() <= b.samples_per_entity < \
+                real.max() + _SAMPLE_GRANULE
+            per_lane.append(real)
+        assert lane == red.num_entities == 60
+        per_lane = np.concatenate(per_lane)
+        assert (np.diff(per_lane) <= 0).all()           # count-descending
+        np.testing.assert_array_equal(
+            per_lane, np.bincount(users)[red.entity_ids])
+        assert red.build_counts["cells"] == _bucket_cells(
+            _padded_samples(per_lane),
+            [b.lane_start for b in red.buckets] + [60])
 
     def test_bucketed_solve_equals_single_block(self, rng):
         ds = self._skewed_dataset(rng, num_small=10, big_n=64)
@@ -275,8 +458,10 @@ class TestBucketedBuild:
             dtype=np.float32)
         dt = time.perf_counter() - t0
         assert red.num_entities <= E
-        assert red.build_counts["active_rows"] > \
-            0.5 * red.build_counts["cells"]
+        # three rows an entity, a granule of cells each: all but the few
+        # entities with more than a granule share the S = granule bucket
+        assert red.build_counts["cells"] < \
+            1.01 * _SAMPLE_GRANULE * red.num_entities
         assert dt < 60.0, f"1e6-entity build took {dt:.1f}s"
 
 

@@ -520,6 +520,17 @@ def _config(iters=3, budget=None):
         hbm_budget_bytes=budget)
 
 
+def _rotation_budget(acct):
+    """A budget under the all-resident total (rotation on) whose half still
+    holds the FE shard (the fixed effect streams when its shard is over half
+    the budget, game/coordinates.py: no auto-stream here)."""
+    data_bytes = acct["resident_block_total"] + acct["flat_vector_bytes"]
+    budget = max(int(data_bytes * 0.8),
+                 2 * acct["resident_block_bytes"]["fixed"] + 2)
+    assert budget < data_bytes
+    return budget
+
+
 def test_budgeted_fit_through_store_matches_resident_f64(rng):
     """The training tenant: a budgeted fit whose residency rotation runs
     through the store's block handles reproduces the all-resident f64
@@ -527,13 +538,7 @@ def test_budgeted_fit_through_store_matches_resident_f64(rng):
     the same host bytes)."""
     train, val = _glmix(rng)
     resident = GameEstimator(_config()).fit(train, val)
-    acct = resident.residency
-    data_bytes = acct["resident_block_total"] + acct["flat_vector_bytes"]
-    fe_bytes = acct["resident_block_bytes"]["fixed"]
-    # above the FE shard (no auto-stream), below the total (rotation on)
-    budget = max(int(data_bytes * 0.8),
-                 int((fe_bytes + acct["flat_vector_bytes"]) * 1.05))
-    assert budget < data_bytes
+    budget = _rotation_budget(resident.residency)
     budgeted = GameEstimator(_config(budget=budget)).fit(train, val)
     b_acct = budgeted.residency
     assert b_acct["evict_inactive"] is True
@@ -549,11 +554,7 @@ def test_budgeted_fit_through_store_matches_resident_f64(rng):
 def test_training_rotation_store_fetch_site_fires(rng):
     train, val = _glmix(rng, n=1500, num_users=30)
     resident = GameEstimator(_config(iters=2)).fit(train, val)
-    acct = resident.residency
-    data_bytes = acct["resident_block_total"] + acct["flat_vector_bytes"]
-    fe_bytes = acct["resident_block_bytes"]["fixed"]
-    budget = max(int(data_bytes * 0.8),
-                 int((fe_bytes + acct["flat_vector_bytes"]) * 1.05))
+    budget = _rotation_budget(resident.residency)
     plan = faults.FaultPlan([{"site": "store.fetch", "action": "transient",
                               "hits": [1]},
                              {"site": "store.fetch", "action": "fatal",
@@ -571,11 +572,7 @@ def test_zero_fresh_traces_warm_budgeted_refit(rng):
     traces NOTHING new — steady-state fetch/evict is pure data movement."""
     train, val = _glmix(rng, n=1500, num_users=30)
     resident = GameEstimator(_config(iters=2)).fit(train, val)
-    acct = resident.residency
-    data_bytes = acct["resident_block_total"] + acct["flat_vector_bytes"]
-    fe_bytes = acct["resident_block_bytes"]["fixed"]
-    budget = max(int(data_bytes * 0.8),
-                 int((fe_bytes + acct["flat_vector_bytes"]) * 1.05))
+    budget = _rotation_budget(resident.residency)
     GameEstimator(_config(iters=2, budget=budget)).fit(train, val)
     with _compile_counting() as counter:
         res = GameEstimator(_config(iters=2, budget=budget)).fit(train, val)

@@ -11,7 +11,7 @@ cli.train and its model through cli.serve) plus BASELINE config 1's solve
 and the Pallas kernel: the jitted callables the product itself builds,
 lowered from `jax.ShapeDtypeStruct`s.  The shapes of the GLMix fit were read
 off the real fit (seed 11: 950,051 training rows; per-user buckets E x S of
-3663 x 512, 2232 x 64, 141 x 8, 2 x 1).
+878 x 512, 1011 x 272, 1506 x 144, 2643 x 72).
 
 Rules of this file (on-chip-measurement guide, section 2): the topology is
 described inside a module-scoped fixture — never at import, never in
@@ -41,8 +41,13 @@ from photon_ml_tpu.parallel.mesh import DATA_AXIS, FEATURE_AXIS
 F32 = jnp.float32
 L2 = RegularizationContext(RegularizationType.L2)
 GLMIX_ROWS, D_GLOBAL, D_USER, USERS = 950_051, 31, 19, 6040
-# the benchmark's largest per-user bucket (glmix-ml20m, both cells): E x S x d
-ML20M_BIG_BUCKET = (30_518, 512, 21)
+# the benchmark's per-user buckets (glmix-ml20m, both cells) and the per-item
+# buckets of glmix-ml20m-user-item, E x S x d; the largest comes first
+ML20M_USER_BUCKETS = [(6_384, 512, 21), (8_769, 280, 21), (15_365, 144, 21),
+                      (24_860, 64, 21)]
+ML20M_ITEM_BUCKETS = [(4_350, 512, 13), (2_291, 248, 13), (4_319, 80, 13),
+                      (11_697, 16, 13)]
+ML20M_BIG_BUCKET = ML20M_USER_BUCKETS[0]
 CONFIG1_SHAPE = (1_643_520, 124)
 V5E_HBM_BYTES = 16 * 1024 ** 3
 
@@ -141,7 +146,7 @@ def _bucket_solve(one_chip, E, S, d, config):
 
 
 @pytest.mark.parametrize("entities,samples",
-                         [(3663, 512), (2232, 64), (141, 8), (2, 1)])
+                         [(878, 512), (1011, 272), (1506, 144), (2643, 72)])
 def test_random_effect_bucket_solve_compiles(one_chip, entities, samples):
     """The vmapped per-entity solver (parallel/random_effect.
     _cached_batched_solver) at every S-bucket of the GLMix fit: rows capped
@@ -184,6 +189,33 @@ def test_random_effect_big_bucket_solve_holds_no_slot_axis(one_chip):
         padded = _hbm_bytes(dims, tuple(map(int, order.split(","))),
                             tuple(map(int, tile)))
         assert padded <= 2 * 4 * E * d, (dims, order, tile)
+
+
+@pytest.mark.parametrize("bucket", ML20M_USER_BUCKETS[1:]
+                         + ML20M_ITEM_BUCKETS[-1:], ids=str)
+def test_random_effect_bucket_sample_axis_pads_to_the_granule(one_chip,
+                                                              bucket):
+    """What `data/batching.py`'s `_SAMPLE_GRANULE` rests on.  At the
+    benchmark's bucket shapes whose S is a multiple of 8 and not of 128, the
+    feature block and the four `[E, S]` operands of `jit_re_bucket_solve`
+    arrive with the sample axis on sublanes (lanes minor: E is what pads to
+    128), so a bucket's arguments cost its cells and no more."""
+    from photon_ml_tpu.data.batching import _SAMPLE_GRANULE
+    E, S, d = bucket
+    assert S % _SAMPLE_GRANULE == 0 and S % 128
+    text = _bucket_solve(one_chip, E, S, d,
+                         OptimizerConfig(max_iterations=100)).as_text()
+    entry = text[:text.index("->")]             # the arguments' layouts
+    operands = re.findall(
+        rf"f32\[({E},{S}(?:,{d})?)\]\{{([0-9,]+):T\(([0-9]+),([0-9]+)\)",
+        entry)
+    assert len(operands) == 5
+    for dims, order, *tile in operands:
+        dims = tuple(map(int, dims.split(",")))
+        order = tuple(map(int, order.split(",")))
+        assert order[0] == 0 and order[1] == 1, (dims, order)
+        padded = _hbm_bytes(dims, order, tuple(map(int, tile)))
+        assert padded <= 1.02 * 4 * math.prod(dims), (dims, order, tile)
 
 
 @pytest.mark.parametrize("bucket", [8, 1024], ids=["smallest", "largest"])
@@ -268,7 +300,7 @@ def test_random_effect_bucket_solve_shards_over_four_chips(topo):
     from photon_ml_tpu.parallel.random_effect import _cached_batched_solver
     mesh = Mesh(np.asarray(topo.devices).reshape(4, 1),
                 (DATA_AXIS, FEATURE_AXIS))
-    E, S, d = 2232, 64, D_USER
+    E, S, d = 2644, 72, D_USER     # 2643 lanes padded to the mesh's four
     lanes = lambda ndim: NamedSharding(
         mesh, P(DATA_AXIS, *([None] * (ndim - 1))))
     cells = _sds((E, S), F32, lanes(2))
