@@ -94,13 +94,21 @@ class GameDataset:
     # coordinate update
     _device_shards: Dict[str, object] = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    # what the pack of a SPARSE shard's device copy made (pack_sparse's
+    # counts, `pack_s` among them); dropped with the copy
+    shard_build: Dict[str, dict] = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
     # scoring-side memos (entity-lane maps etc.), keyed by consumer
     _scoring_cache: Dict[object, object] = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
 
-    def device_shard(self, shard: str, *, release_host: bool = False):
+    def device_shard(self, shard: str, *, with_csc: bool = False,
+                     release_host: bool = False):
         """Device FeatureMatrix view of a shard (dense -> jnp array, scipy
-        sparse -> PaddedSparse), built once and shared.
+        sparse -> PaddedSparse), built once and shared.  `with_csc` asks a
+        wide sparse shard for its column-sorted gradient view as well
+        (`ops/features.py::pack_sparse`, which packs what the cached copy
+        lacks and nothing twice); what a pack made is `shard_build[shard]`.
 
         NOTE the memory doubling: the host numpy shard and the device copy
         both stay alive for the whole fit (every byte of feature data
@@ -110,14 +118,25 @@ class GameDataset:
         resident single-fit jobs qualify.  Streaming mode does the inverse
         (release_device_shard): chunks stage from the host copy and a full
         device copy would defeat the HBM budget."""
-        if shard not in self._device_shards:
-            from photon_ml_tpu.ops.features import as_feature_matrix
-            host = self.feature_shards[shard]
-            if isinstance(host, ReleasedHostShard):
+        from photon_ml_tpu.ops import features as fops
+        cached = self._device_shards.get(shard)
+        host = self.feature_shards[shard]
+        if isinstance(host, ReleasedHostShard):
+            if cached is None:
                 raise ValueError(
                     f"host shard {shard!r} was released (release_host_shard) "
                     "and no device copy survives; rebuild the dataset")
-            self._device_shards[shard] = as_feature_matrix(host)
+            host = None
+        elif isinstance(host, fops.PaddedSparse):   # handed over packed
+            cached, host = (host if cached is None else cached), None
+        if fops.is_scipy_sparse(host) or isinstance(cached,
+                                                    fops.PaddedSparse):
+            x, counts = fops.pack_sparse(host, cached, with_csc)
+            self._device_shards[shard] = x
+            if counts is not None:
+                self.shard_build[shard] = counts
+        elif cached is None:
+            self._device_shards[shard] = fops.as_feature_matrix(host)
         if release_host:
             self.release_host_shard(shard)
         return self._device_shards[shard]
@@ -144,6 +163,7 @@ class GameDataset:
         the source of truth).  Used by streaming mode's staging path and by
         the coordinate residency manager's eviction rotation."""
         self._device_shards.pop(shard, None)
+        self.shard_build.pop(shard, None)
 
     @property
     def num_rows(self) -> int:

@@ -303,16 +303,37 @@ class FixedEffectCoordinate:
         wide-model product path, ops/features.py); single-device solves
         also carry the column-sorted gradient stream (no scatter).  The
         device copy comes from (and is stored back into) the dataset's
-        shared shard cache so scoring/diagnostics never re-transfer it."""
+        shared shard cache (`GameDataset.device_shard`) so
+        scoring/diagnostics never re-transfer it and a sparse shard is
+        packed ONCE a dataset; `build_stats` says what that pack made."""
         if self.streamed:
             raise RuntimeError(f"coordinate {self.name!r} is streamed: its "
                                "feature shard is never fully device-resident")
         if self._x is None:
-            self._x = fops.as_feature_matrix(
-                self._dataset.device_shard(self.config.feature_shard),
-                with_csc=(self.mesh is None or self.mesh.size == 1))
-            self._dataset._device_shards[self.config.feature_shard] = self._x
+            ds, shard = self._dataset, self.config.feature_shard
+            before = ds.shard_build.get(shard)
+            self._x = ds.device_shard(
+                shard, with_csc=self.mesh is None or self.mesh.size == 1)
+            built = ds.shard_build.get(shard)
+            if built is not None:
+                # a sparse shard: what its pack made, and the seconds only
+                # where it ran for THIS coordinate
+                packed_here = built is not before
+                self.build_stats = dict(
+                    built, pack_s=built["pack_s"] if packed_here else 0.0)
+                if packed_here:
+                    for key, value in built.items():
+                        gauge(f"train.fe_build.{self.name}.{key}").set(value)
         return self._x
+
+    #: per coordinate over a sparse shard, what the pack of its device copy
+    #: made (`ops/features.py::pack_sparse`'s counts, for the fit's summary
+    #: `GameResult.coordinate_build`; where this coordinate packed it, the
+    #: same numbers are the `train.fe_build.<coordinate>.*` gauges) and the
+    #: host seconds THIS coordinate spent on it (0 where the dataset's
+    #: cache had it).  None for a dense shard, and until a lazy shard is
+    #: first materialized
+    build_stats: Optional[dict] = None
 
     def device_block_bytes(self) -> int:
         """Evictable device bytes (the shard; flat [n] labels/weights stay

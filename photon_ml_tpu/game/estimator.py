@@ -69,7 +69,10 @@ class GameResult:
     # per entity-keyed coordinate, what its build did with the rows:
     # entities, active / passive / discarded rows, capped entities, padded
     # cells and bucket shapes (RandomEffectDataset.build_counts; the
-    # same numbers are the `train.re_build.<coordinate>.*` gauges)
+    # same numbers are the `train.re_build.<coordinate>.*` gauges); per
+    # fixed-effect coordinate over a SPARSE shard, what its device shard
+    # holds and the host seconds this fit spent packing it
+    # (FixedEffectCoordinate.build_stats, `train.fe_build.<coordinate>.*`)
     coordinate_build: Dict[str, dict] = dataclasses.field(
         default_factory=dict)
 
@@ -279,7 +282,7 @@ class GameEstimator:
                           coordinate_build={
                               name: c.build_stats
                               for name, c in coords.items()
-                              if hasattr(c, "build_stats")})
+                              if getattr(c, "build_stats", None)})
 
     def fit_grid(
         self,

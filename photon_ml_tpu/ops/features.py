@@ -14,7 +14,7 @@ are pytrees, so they flow through jit/vmap/shard_map unchanged.
 from __future__ import annotations
 
 import dataclasses
-from typing import Union
+from typing import Optional, Union
 
 import jax
 import jax.numpy as jnp
@@ -77,13 +77,23 @@ class PaddedSparse:
     >200k-feature depth switch GameEstimator.scala:667-669).
 
     The optional `csc_*` arrays are a SECOND, column-sorted view of the same
-    nonzeros for the gradient product X^T u: TPU scatter-add serializes and
-    ran at ~0.1% of HBM roofline (measured, round 4), so `rmatvec` instead
-    gathers u by row, multiplies, cumsums the column-sorted stream, and
-    differences the cumulative sums at column boundaries — gather, multiply,
-    prefix-scan, gather: no scatter anywhere.  Built by `with_csc()`
-    (single-device solves); the GSPMD multi-device path strips them and
-    keeps the row-shardable scatter+psum formulation.
+    nonzeros for the gradient product X^T u: `rmatvec` gathers u by row,
+    multiplies, cumsums the column-sorted stream, and differences the
+    cumulative sums at column boundaries — gather, multiply, prefix-scan,
+    gather: no scatter anywhere (the scatter-add it replaces serializes on
+    the TPU and has not been timed on this chip).  Built by `with_csc()` or
+    `from_scipy(with_csc=True)` (single-device solves); the GSPMD
+    multi-device path strips them and keeps the row-shardable scatter+psum
+    formulation.
+
+    What this costs on a v5e (cell `criteo-hashed-1m.fit`: 2.85 M rows x 39
+    non-zeros, 1 M columns, PERF.md section 5): both element gathers,
+    `w[indices]` in `matvec` and `u[rows]` in `_csc_segment_sum`, run at
+    8.0 ns a non-zero, 0.89 s each a pass of 111 M, and are 89% of a
+    value+gradient pass of 2.00 s; reading every non-zero once at the HBM
+    peak would take 0.056% of that pass (`fe_sparse_roofline.fit`).  The
+    multiplies, the chunked prefix scan and the boundary gathers are the
+    other 11%.
     """
 
     indices: jax.Array   # [n, k] int32, padding = 0
@@ -196,10 +206,64 @@ FeatureMatrix = Union[jax.Array, jsparse.BCOO, KroneckerDesign, PaddedSparse]
 
 
 # below this width the scatter-add accumulator is small enough that the
-# scatter path wins outright, and the csc stream would only add host->device
-# transfer.  Not measured on this chip (ROADMAP S5: keep a cell on each side
-# of this width)
+# scatter path is expected to win outright, and the csc stream would only add
+# host->device transfer.  The csc side is measured (cell
+# `criteo-hashed-1m.fit`, 1 M columns); the scatter side and the width
+# itself are not (ROADMAP S5; both sides are held to float64 in
+# tests/test_benchmark_sparse_fe.py)
 CSC_MIN_COLS = 100_000
+
+
+def is_scipy_sparse(x) -> bool:
+    try:
+        import scipy.sparse as sp
+    except ImportError:
+        return False
+    return sp.issparse(x)
+
+
+def pack_sparse(host=None, cached: Optional["PaddedSparse"] = None,
+                with_csc: bool = False):
+    """The one place a sparse shard is packed for the device, and the owner
+    of its span: `(matrix, counts)`, `counts` None where `cached` already
+    is what is asked for and nothing ran.
+
+    `host` is the scipy matrix (None once released), `cached` a device copy
+    made earlier.  A matrix of `CSC_MIN_COLS` columns or more gets the
+    column-sorted view when `with_csc` asks for it.  Whatever is missing is
+    packed from `host` (ELL rows plus scipy's own column-sorted view: the
+    stream holds the stored non-zeros only) inside a `photon/fe/pack`
+    annotation, so the stream does not depend on who touched the shard
+    first; only where the host copy is gone is the view sorted out of the
+    cached rows read back from the device (its stream keeps their padding
+    slots, which add nothing to a segment sum).  `counts` is what was
+    made, from the arrays in hand on the host: rows, cols, nnz (stored
+    values that are not zero), ell_width, padded_slots, csc, device_bytes,
+    pack_s."""
+    import numpy as np
+    from photon_ml_tpu.telemetry import annotate, clock
+    num_cols = cached.num_cols if host is None else host.shape[1]
+    want_csc = with_csc and num_cols >= CSC_MIN_COLS
+    if cached is not None and (cached.has_csc or not want_csc):
+        return cached, None
+    t0 = clock()
+    with annotate("fe/pack"):
+        if host is None:
+            x = cached.with_csc()
+            nnz = np.count_nonzero(np.asarray(cached.values))
+        else:
+            csr = host.tocsr()
+            csr.sum_duplicates()
+            x = PaddedSparse.from_scipy(csr, with_csc=want_csc)
+            nnz = np.count_nonzero(csr.data)
+    rows, width = x.indices.shape
+    return x, {
+        "rows": int(rows), "cols": int(num_cols), "nnz": int(nnz),
+        "ell_width": int(width), "padded_slots": int(rows * width - nnz),
+        "csc": int(x.has_csc),
+        "device_bytes": sum(int(leaf.nbytes)
+                            for leaf in jax.tree_util.tree_leaves(x)),
+        "pack_s": float(clock() - t0)}
 
 
 def as_feature_matrix(x, with_csc: bool = False) -> FeatureMatrix:
@@ -208,17 +272,11 @@ def as_feature_matrix(x, with_csc: bool = False) -> FeatureMatrix:
     column-sorted gradient view to WIDE sparse inputs (single-device
     solves, >= CSC_MIN_COLS features)."""
     if isinstance(x, PaddedSparse):
-        return (x.with_csc() if with_csc and x.num_cols >= CSC_MIN_COLS
-                else x)
+        return pack_sparse(cached=x, with_csc=with_csc)[0]
     if isinstance(x, (jsparse.BCOO, KroneckerDesign)):
         return x
-    try:
-        import scipy.sparse as sp
-        if sp.issparse(x):
-            return PaddedSparse.from_scipy(
-                x, with_csc=with_csc and x.shape[1] >= CSC_MIN_COLS)
-    except ImportError:
-        pass
+    if is_scipy_sparse(x):
+        return pack_sparse(x, with_csc=with_csc)[0]
     return jnp.asarray(x)
 
 
@@ -256,9 +314,12 @@ def _csc_segment_sum(vals: jax.Array, rows: jax.Array, end: jax.Array,
     """sum_j vals_j * u[rows_j] per column, for a column-sorted stream.
 
     Formulated as gather -> multiply -> CHUNKED prefix-scan -> boundary
-    gather — every op is a TPU-parallel primitive; the scatter-add this
-    replaces serializes on TPU (an earlier round's record put it near 0.1%
-    of the HBM roofline; not re-measured on this round's chip, ROADMAP S5).
+    gather — every op is a TPU-parallel primitive.  On a v5e the gather
+    `u[rows]` is most of it: 8.0 ns an element, 0.89 s a call over 111 M
+    non-zeros, where the scan and its neighbours take about 0.1 s and the
+    boundary gathers over 1 M columns 0.03 s (cell `criteo-hashed-1m.fit`,
+    PERF.md section 5).  The scatter-add this replaces has no chip record
+    (ROADMAP S5).
 
     Chunking is a precision device, not a speed one: a single global
     cumsum accumulates ~eps*sqrt(nnz) rounding noise into every boundary

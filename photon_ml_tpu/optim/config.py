@@ -137,8 +137,10 @@ class OptimizerConfig:
                                    box_lower=lower, box_upper=upper)
 
     def resolved(self) -> "OptimizerConfig":
-        # explicit 0 / 0.0 are legitimate (e.g. tolerance=0 disables the
-        # check); only None takes the default
+        # explicit 0 / 0.0 are legitimate (tolerance=0 disables the
+        # function-value check, which is a strict `<` in optim/lbfgs.py
+        # and optim/streaming.py, and leaves the gradient check an exact
+        # zero to find); only None takes the default
         d_iter, d_tol = ((15, 1e-5) if self.optimizer == OptimizerType.TRON
                          else (100, 1e-7))
         return dataclasses.replace(

@@ -364,8 +364,11 @@ def lbfgs(
         num_pairs = st.num_pairs + jnp.where(store, 1, 0)
 
         gnorm_new = jnp.linalg.norm(steer_grad(x_new, g_new))
-        # convergence checks (reference Optimizer.scala:136-150 reasons)
-        f_small_now = jnp.abs(st.f - f_new) <= tolerance * jnp.maximum(
+        # convergence checks (reference Optimizer.scala:136-150 reasons).
+        # Strictly under: tolerance 0 switches the function-value check off
+        # (a float32 objective at its floor repeats exactly, and a fit that
+        # states its iteration count must not end on that)
+        f_small_now = jnp.abs(st.f - f_new) < tolerance * jnp.maximum(
             jnp.maximum(jnp.abs(st.f), jnp.abs(f_new)), 1.0)
         f_small = jnp.where(f_small_now, st.f_small + 1, 0)
         f_conv = f_small >= _F_CONV_PERSISTENCE

@@ -203,7 +203,8 @@ def host_lbfgs(
             gnorm_new = float(jnp.linalg.norm(steer_grad(xt, gt)))
             f_new = float(ft)
             f_prev = float(f)
-            f_small_now = abs(f_prev - f_new) <= tolerance * max(
+            # strictly under, as optim/lbfgs.py: tolerance 0 is "off"
+            f_small_now = abs(f_prev - f_new) < tolerance * max(
                 abs(f_prev), abs(f_new), 1.0)
             f_small = f_small + 1 if f_small_now else 0
             if gnorm_new <= gtol:

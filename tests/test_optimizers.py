@@ -403,6 +403,40 @@ def test_carried_margins_do_not_drift_over_a_long_float32_solve(
     assert float(on_margins.value) <= float(generic.value) * (1 + 1e-3)
 
 
+@pytest.mark.parametrize("path", ["margins", "generic", "host"])
+def test_tolerance_zero_switches_the_function_value_check_off(path):
+    """A float32 solve run far past its floor: the objective repeats
+    exactly three times and more in a row, which a test of `<=` at
+    tolerance 0 ended on (FUNCTION_VALUES_CONVERGED after 3 repeats); with
+    the check strict the solve uses the iterations it was given. The
+    default tolerance still ends the same solve early."""
+    from photon_ml_tpu.optim.streaming import host_lbfgs
+    rng = np.random.default_rng(11)
+    x, y, _, _ = make_glm_data(rng, n=400, d=6, task="logistic")
+    dtype, lam, cap = jnp.float32, 5.0, 60
+    obj = GLMObjective(LOGISTIC, jnp.asarray(x, dtype), jnp.asarray(y, dtype))
+    l2 = obj.with_l2(jnp.asarray(lam, dtype))
+    x0 = jnp.zeros(6, dtype)
+
+    def run(tolerance):
+        if path == "margins":
+            cfg = OptimizerConfig(max_iterations=cap, tolerance=tolerance)
+            return jax.jit(lambda o: solve(o, x0, cfg, _L2, lam))(obj)
+        minimize_ = lbfgs if path == "generic" else host_lbfgs
+        return minimize_(l2.value_and_gradient, x0, max_iterations=cap,
+                         tolerance=tolerance)
+
+    fixed = run(0.0)
+    assert int(fixed.iterations) == cap
+    assert int(fixed.reason) == ConvergenceReason.MAX_ITERATIONS
+    repeats = np.diff(np.asarray(fixed.loss_history)[:cap + 1]) == 0
+    assert (repeats[:-2] & repeats[1:-1] & repeats[2:]).any()
+    default = run(1e-7)
+    assert int(default.iterations) < cap
+    assert int(default.reason) in (ConvergenceReason.FUNCTION_VALUES_CONVERGED,
+                                   ConvergenceReason.GRADIENT_CONVERGED)
+
+
 def _while_eqns(jaxpr, depth=0):
     """(nesting depth among `while`s, eqn) for every while in a jaxpr."""
     for eqn in jaxpr.eqns:
