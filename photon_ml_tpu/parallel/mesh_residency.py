@@ -232,6 +232,10 @@ def _stage_tree(mesh, tree, fill, spec: str):
     # stats, [d]-shaped) have no row axis to pad — just place the leaves.
     padded = tree
     if hasattr(tree, "shape"):
+        if isinstance(tree, fops.PaddedSparse) and mesh.size > 1:
+            # the VMEM table gather's flat streams are one device's: rows
+            # shard over a mesh as the XLA forms' `[n, k]`
+            tree = tree.xla_forms()
         rem = (-tree.shape[0]) % mesh.shape[DATA_AXIS]
         padded = fops.pad_rows(tree, rem)
     staged = jax.tree_util.tree_map(lambda l: _put_leaf(mesh, l, spec),

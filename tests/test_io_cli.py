@@ -176,6 +176,11 @@ def test_cli_sparse_train_and_score(tmp_path, rng):
     assert r.returncode == 0, r.stderr[-2000:]
     summary = json.loads(r.stdout.strip().splitlines()[-1])
     assert summary["validation"]["AUC"] > 0.75
+    # the pack's counters of the sparse shard, the kernel's among them: no
+    # product fetches from a VMEM table on the CPU, or on a mesh of eight
+    with open(os.path.join(out_dir, "training-summary.json")) as f:
+        built = json.load(f)["coordinate_build"]["fixed"]
+    assert built["vmem_gather"] == 0 and built["rows"] == n
 
     score_p = str(tmp_path / "sp_scores.npz")
     r2 = _run_cli("photon_ml_tpu.cli.score",

@@ -99,11 +99,13 @@ def _fits(compiled) -> int:
     return m.argument_size_in_bytes
 
 
-def _trainer_objective(n, d, rows, replicated):
+def _trainer_objective(n, d, rows, replicated, x=None):
     """The objective FixedEffectCoordinate.update hands fit_fixed_effect on
     the mesh path (no weights or normalization in the GLMix fit; offsets
-    are the other coordinates' scores, the mask marks real rows)."""
-    return GLMObjective(LOGISTIC, _sds((n, d), F32, rows(2)),
+    are the other coordinates' scores, the mask marks real rows).  `x`: a
+    feature matrix of shapes in place of the dense `[n, d]`."""
+    return GLMObjective(LOGISTIC,
+                        _sds((n, d), F32, rows(2)) if x is None else x,
                         _sds((n,), F32, rows(1)), weights=None,
                         offsets=_sds((n,), F32, rows(1)),
                         mask=_sds((n,), F32, rows(1)), norm=None,
@@ -128,6 +130,62 @@ def test_fixed_effect_solve_compiles(one_chip, shape, optimizer):
     args = _fits(compiled)
     n, d = shape
     assert args >= n * d * 4          # the design matrix is an argument
+
+
+# criteo-hashed-1m.fit: 2,850,000 rows x 39 slots, 1,000,000 columns (721,687
+# stored), 111,150,000 non-zeros; and a shape whose `w` is over the VMEM budget
+CRITEO = (2_850_000, 39, 1_000_000, 111_150_000, 721_687)
+OVER_BUDGET = (100_000, 8, 13_000_000, 800_000, 500_000)
+
+
+def _packed_sparse_shapes(shape, one_chip, fops):
+    """A `PaddedSparse` of shapes as `pack_sparse` lays it out for a TPU:
+    the kernel's streams where its rule takes the shape, else the XLA
+    forms' `[n, k]` rows and the column-sorted stream."""
+    n, k, d, nnz, stored = shape
+    i32 = jnp.int32
+    if fops._vmem_gather_fits(n, d, k, np.float32):
+        block, group = fops._VG_BLOCK, fops._VG_GROUP
+        slots = -(-n // block) * block * k
+        # every stored column's run ends in half a group of padding
+        groups = -(-(nnz + stored * group // 2) // group)
+        stream = -(-groups // block) * block * group
+        return fops.PaddedSparse(
+            _sds((slots,), i32, one_chip), _sds((slots,), F32, one_chip), d,
+            _sds((stream,), i32, one_chip), _sds((stream,), F32, one_chip),
+            _sds((d + 1,), i32, one_chip),
+            vmem_gather=fops.VmemGather(n, k))
+    return fops.PaddedSparse(
+        _sds((n, k), i32, one_chip), _sds((n, k), F32, one_chip), d,
+        _sds((nnz,), i32, one_chip), _sds((nnz,), F32, one_chip),
+        _sds((d + 1,), i32, one_chip))
+
+
+@pytest.mark.parametrize("shape,kernel", [(CRITEO, True),
+                                          (OVER_BUDGET, False)],
+                         ids=["criteo-2850000x39", "table-over-budget"])
+def test_sparse_fixed_effect_solve_compiles(one_chip, monkeypatch, shape,
+                                            kernel):
+    """The FE solve the trainer jits over the packed sparse shard of
+    `criteo-hashed-1m.fit`: both products of a pass fetch their random
+    operand from a table in VMEM (`ops/features.py::_vmem_segment_sums`,
+    a Mosaic kernel), float32, within the chip's memory.  A shard whose
+    `w` table is over `VMEM_TABLE_BYTES` compiles to the XLA forms.  The
+    test stands in for the chip where the program asks which backend it
+    packs and compiles for."""
+    from photon_ml_tpu.ops import features as fops
+    from photon_ml_tpu.parallel.fixed_effect import _cached_solver
+    monkeypatch.setattr(fops, "_on_tpu", lambda: True)
+    n, _, d, _, _ = shape
+    x = _packed_sparse_shapes(shape, one_chip, fops)
+    assert (x.vmem_gather is not None) == kernel and x.shape == (n, d)
+    obj, x0, lam = _trainer_objective(n, d, lambda ndim: one_chip, one_chip,
+                                      x=x)
+    cfg = OptimizerConfig(max_iterations=30, tolerance=0.0)
+    compiled = _cached_solver(cfg, L2).lower(obj, x0, lam).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel
+    _assert_float32(compiled)
+    _fits(compiled)
 
 
 def _bucket_solve(one_chip, E, S, d, config):
