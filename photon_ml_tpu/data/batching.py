@@ -256,6 +256,12 @@ class RandomEffectDataset:
     # that hold no row (`padded_cells`), and `buckets` as [[entities,
     # samples, real rows]] in lane order
     build_counts: Dict[str, object] = dataclasses.field(default_factory=dict)
+    # [E] the cap's weight rescale by lane, count / cap for an entity the
+    # reservoir cut and 1 for any other: with `row_ids` it gives back every
+    # cell's weight without the blocks (`flat_active_weights`)
+    lane_weight_scale: Optional[np.ndarray] = None
+    _flat_weights_dev: object = dataclasses.field(default=None, repr=False,
+                                                  compare=False)
     _global_blocks: Optional[EntityBlocks] = dataclasses.field(
         default=None, repr=False, compare=False)
     _global_row_ids: Optional[np.ndarray] = dataclasses.field(
@@ -290,7 +296,7 @@ class RandomEffectDataset:
     @property
     def blocks(self) -> EntityBlocks:
         """Single-S EntityBlocks view over all lanes (lazily materialized;
-        the factored-RE latent refit consumes one flat block set)."""
+        `game/anchored.py` solves on it)."""
         if self._global_blocks is None:
             S = self.max_samples
             def cat(get, fill):
@@ -308,21 +314,23 @@ class RandomEffectDataset:
                 offsets=cat(lambda b: b.offsets, 0.0))
         return self._global_blocks
 
-    _safe_ids_dev: object = dataclasses.field(default=None, repr=False,
-                                              compare=False)
-
-    def with_offsets_from_flat(self, flat_offsets) -> EntityBlocks:
-        """addScoresToOffsets (reference: RandomEffectDataSet.scala:68-88):
-        gather the canonical-order offset vector into block layout
-        (single-S view; bucketed consumers use EntityBucket's)."""
-        blocks = self.blocks
-        if self._safe_ids_dev is None:
-            self._safe_ids_dev = jnp.asarray(
-                np.maximum(self.active_row_ids, 0).astype(np.int32))
-        off = _gather_flat_offsets(jnp.asarray(flat_offsets),
-                                   self._safe_ids_dev, blocks.mask,
-                                   jnp.dtype(blocks.x.dtype).name)
-        return blocks.with_offsets(off)
+    def flat_active_weights(self, dataset: GameDataset) -> jnp.ndarray:
+        """[n] device vector, canonical row order: the weight a row trains
+        its entity at (its own weight times the cap's rescale, as its block
+        cell has it), 0 for a row that does not train (passive, discarded,
+        of no entity).  The flat view of the blocks' weights, for a consumer
+        that reads the rows where they lie (the factored refit's
+        `ProjectionRows`).  Made once a dataset, from the host's row ids."""
+        if self._flat_weights_dev is None:
+            flat = np.zeros(dataset.num_rows, np.dtype(self.dtype))
+            for b in self.buckets:
+                lane, slot = np.nonzero(b.row_ids >= 0)
+                rows = b.row_ids[lane, slot]
+                flat[rows] = self.lane_weight_scale[b.lane_start + lane]
+                if dataset.weights is not None:
+                    flat[rows] *= np.asarray(dataset.weights)[rows]
+            self._flat_weights_dev = jnp.asarray(flat)
+        return self._flat_weights_dev
 
     def scatter_to_global(self, local_coefficients) -> jnp.ndarray:
         """[E, d_local] local-space coefficients -> [E, d_global]
@@ -343,11 +351,11 @@ class RandomEffectDataset:
         for b in self.buckets:
             b.evict()
         self._global_blocks = None       # (_global_row_ids is host: kept)
-        self._safe_ids_dev = None
+        self._flat_weights_dev = None
 
     def device_bytes(self) -> int:
         """Device bytes of all bucket blocks (+ the single-S view when it
-        has been materialized — the factored-RE path holds both)."""
+        has been materialized)."""
         total = sum(b.device_bytes() for b in self.buckets)
         g = self._global_blocks
         if g is not None:
@@ -677,6 +685,7 @@ def _build_random_effect_dataset(
         projection=projection, global_dim=d_global,
         num_active=num_active, num_passive=num_passive,
         discarded_rows=discarded_rows, projection_matrix=proj_matrix,
+        lane_weight_scale=weight_scale[perm],
         build_counts={
             "entities": E, "active_rows": num_active,
             "passive_rows": num_passive,

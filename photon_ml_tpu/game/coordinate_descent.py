@@ -133,6 +133,12 @@ class TrackerSummary:
     # trial is a full pass: L1, box).
     data_passes: Optional[int] = None
     ls_trials: Optional[int] = None
+    # a factored coordinate's visit only: `data_passes` is the sum of its
+    # two halves, these are the halves.  The per-entity solves in the latent
+    # space (max over lanes) and the one-lane refit of the shared projection
+    # read different operands, so their passes cost differently.
+    latent_data_passes: Optional[int] = None
+    projection_data_passes: Optional[int] = None
 
 
 def _reason_counts(reason) -> Dict[str, int]:
@@ -171,10 +177,14 @@ def _summarize_tracker(tracker: object, wall_s: float,
     counted = [t for t in parts if getattr(t, "ls_trials", None) is not None]
     if counted:
         # the halves of a factored alternation run one after the other
-        summary.data_passes = sum(
-            int(np.max(np.asarray(t.fg_count), initial=0)) for t in counted)
+        passes = [int(np.max(np.asarray(t.fg_count), initial=0))
+                  for t in counted]
+        summary.data_passes = sum(passes)
         summary.ls_trials = sum(
             int(np.max(np.asarray(t.ls_trials), initial=0)) for t in counted)
+        if len(parts) == 2 and len(counted) == 2:
+            summary.latent_data_passes, summary.projection_data_passes = \
+                passes
     return summary
 
 
@@ -230,6 +240,11 @@ class CoordinateDescentResult:
             if t.ls_trials is not None:
                 d.setdefault("data_passes", []).append(t.data_passes)
                 d.setdefault("ls_trials", []).append(t.ls_trials)
+            if t.projection_data_passes is not None:
+                d.setdefault("latent_data_passes", []).append(
+                    t.latent_data_passes)
+                d.setdefault("projection_data_passes", []).append(
+                    t.projection_data_passes)
             if t.containment is not None:
                 d["containment"][t.containment] = \
                     d["containment"].get(t.containment, 0) + 1

@@ -40,7 +40,8 @@ from photon_ml_tpu.models.game import (
     FactoredRandomEffectModel, FixedEffectModel, RandomEffectModel,
 )
 from photon_ml_tpu.parallel.factored import (
-    FactoredSolveResult, fit_factored_random_effects, gaussian_projection_matrix,
+    FactoredSolveResult, ProjectionRows, fit_factored_random_effects,
+    gaussian_projection_matrix,
 )
 from photon_ml_tpu.models.glm import model_for_task
 from photon_ml_tpu.ops import TASK_LOSSES, GLMObjective
@@ -770,6 +771,38 @@ class FactoredRandomEffectCoordinate(_EntityCoordinateBase):
                          hbm_budget_bytes=hbm_budget_bytes)
         self.seed = seed
         self._key = jax.random.PRNGKey(seed + 1)
+        self._labels = None
+        # what `update` solves on, known from the build alone: the latent
+        # half on the S-buckets `train.re_build.*` counts (`cells`), the
+        # projection's refit on the shard's flat rows (`rows`), of either of
+        # which `real_rows` train; `padded_cells` is what both halves read
+        # a pass that trains nothing, `device_bytes` what an update makes
+        # anew: the projected blocks, the factors by row, the flat weights
+        red, rows = self.red, dataset.num_rows
+        cells = red.build_counts["cells"]
+        itemsize = jnp.dtype(jax.dtypes.canonicalize_dtype(red.dtype)).itemsize
+        k = config.latent_dim
+        self.build_stats = dict(red.build_counts, mf_build={
+            "entities": red.num_entities, "samples": red.max_samples,
+            "cells": cells, "rows": rows, "real_rows": red.num_active,
+            "padded_cells": cells + rows - 2 * red.num_active,
+            "latent_dim": k,
+            "device_bytes": (cells * k + rows * (k + 1)) * itemsize})
+        for key, value in self.build_stats["mf_build"].items():
+            gauge(f"train.mf_build.{name}.{key}").set(value)
+
+    @property
+    def labels(self):
+        """Device copy of the flat labels (the projection's refit reads the
+        rows where they lie), lazily re-streamed after an eviction."""
+        if self._labels is None:
+            self._labels = jnp.asarray(self._dataset.response,
+                                       self.red.dtype)
+        return self._labels
+
+    def evict_device_blocks(self) -> None:
+        super().evict_device_blocks()
+        self._labels = None
 
     def initial_model(self) -> FactoredRandomEffectModel:
         """Zero latent factors + Gaussian random projection (reference:
@@ -797,7 +830,9 @@ class FactoredRandomEffectCoordinate(_EntityCoordinateBase):
         subspace of the sibling's coefficient matrix — the directions
         per-entity effects actually vary in — so the first alternation
         refines a meaningful subspace instead of discovering one from
-        noise (the cold first MF solve is the cost ROADMAP S3 chases).
+        noise (what its visits cost on the chip from this start is in
+        PERF.md section 5, cell `game-ml20m-mf.fit`; a Gaussian start is
+        not measured).
 
         The latent FACTORS stay zero: the coordinate's initial score is
         unchanged, so the descent residual algebra sees no perturbation —
@@ -846,23 +881,34 @@ class FactoredRandomEffectCoordinate(_EntityCoordinateBase):
                 outer_iteration, num_outer_iterations, opt.optimizer)
             latent_budget = schedule.budget_for(
                 outer_iteration, num_outer_iterations, lat.optimizer)
-        blocks = self.red.with_offsets_from_flat(offsets)
+        # the latent solves run by S-bucket, as a plain random effect's do;
+        # the projection's refit reads the shard's flat rows (the ones that
+        # train at their block weights, the others at 0), under the
+        # descent's own offsets: no single-S view of all entities, no second
+        # copy of the features, no gather of the offsets for the refit
+        blocks = []
+        for bucket in self.red.buckets:
+            with annotate("re/offsets"):
+                blocks.append(bucket.with_offsets_from_flat(offsets))
+        rows = ProjectionRows(
+            x=self.flat_x, labels=self.labels, lanes=self.lanes,
+            weights=self.red.flat_active_weights(self._dataset),
+            offsets=offsets)
 
         latent_row_weights_fn = None
         if lat.downsampling_rate is not None:
-            E, S = blocks.labels.shape
-            flat_labels = blocks.labels.reshape(E * S)
             sampler = downsampler_for_task(self.task_type)
 
             def latent_row_weights_fn(it: int):
                 # fresh draw per inner iteration (reference: runWithSampling
                 # called inside each updateLatentProjectionMatrix)
                 self._key, sub = jax.random.split(self._key)
-                keep, w = sampler(sub, flat_labels, None, lat.downsampling_rate)
+                keep, w = sampler(sub, rows.labels, None,
+                                  lat.downsampling_rate)
                 return keep * w
 
         res = fit_factored_random_effects(
-            blocks, self.loss, self.mesh,
+            blocks, rows, self.loss, self.mesh,
             latent_coefficients=model.latent_coefficients,
             projection=model.projection,
             num_inner_iterations=self.config.num_inner_iterations,

@@ -197,7 +197,8 @@ class FactoredRandomEffectModel:
         return self.latent_coefficients.shape[1]
 
     def global_coefficients(self) -> jax.Array:
-        return self.latent_coefficients @ self.projection
+        return jnp.matmul(self.latent_coefficients, self.projection,
+                          precision=jax.lax.Precision.HIGHEST)
 
     def to_random_effect_model(self) -> RandomEffectModel:
         """Original-space view (reference: FactoredRandomEffectModel

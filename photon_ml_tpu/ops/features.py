@@ -23,6 +23,10 @@ from jax import lax
 from jax.experimental import sparse as jsparse
 
 
+#: float32 products as float32 (see `KroneckerDesign`)
+_EXACT = lax.Precision.HIGHEST
+
+
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class KroneckerDesign:
@@ -36,7 +40,15 @@ class KroneckerDesign:
     latent factors C [n, k]:
       matvec(P_flat)   = ((X @ P^T) * C).sum(-1)        — two MXU matmuls
       rmatvec(u)       = (C * u[:, None])^T @ X         — one MXU matmul
-    so the k*d matrix never exists and HBM traffic stays O(n(d+k))."""
+    so the k*d matrix never exists and HBM traffic stays O(n(d+k)).
+
+    The products are taken at `Precision.HIGHEST`: a TPU's default rounds a
+    float32 matmul's operands to bfloat16, which a matrix-vector product
+    (lowered to a multiply-reduce, exact) never meets and these
+    matrix-matrix products do — the refit would train, and the model score,
+    2e-2 from their float32 margins (PERF.md section 6, PR 35).  They are
+    k = rank columns wide and bound by reading X, so the passes cost
+    nothing that shows."""
 
     x: jax.Array        # [n, d]
     factors: jax.Array  # [n, k]
@@ -443,7 +455,8 @@ def matvec(x: FeatureMatrix, v: jax.Array) -> jax.Array:
     """X @ v -> [n].  The margin kernel."""
     if isinstance(x, KroneckerDesign):
         p = x._unflatten_coef(v)
-        return jnp.sum((x.x @ p.T) * x.factors, axis=-1)
+        return jnp.sum(jnp.matmul(x.x, p.T, precision=_EXACT) * x.factors,
+                       axis=-1)
     if isinstance(x, PaddedSparse):
         if _runs_vmem_gather(x, v):
             lay = x.vmem_gather
@@ -630,7 +643,8 @@ def _boundary_sums(contrib: jax.Array, end: jax.Array) -> jax.Array:
 def rmatvec(x: FeatureMatrix, u: jax.Array) -> jax.Array:
     """X^T @ u -> [d].  The gradient-assembly kernel."""
     if isinstance(x, KroneckerDesign):
-        return ((x.factors * u[:, None]).T @ x.x).reshape(-1)
+        return jnp.matmul((x.factors * u[:, None]).T, x.x,
+                          precision=_EXACT).reshape(-1)
     if isinstance(x, PaddedSparse):
         if _runs_vmem_gather(x, u):
             return _boundary_sums(_vmem_segment_sums(
@@ -658,7 +672,8 @@ def sq_rmatvec(x: FeatureMatrix, u: jax.Array) -> jax.Array:
     if isinstance(x, KroneckerDesign):
         # kron(c, x)^2 == kron(c^2, x^2)
         f2 = x.factors * x.factors
-        return ((f2 * u[:, None]).T @ (x.x * x.x)).reshape(-1)
+        return jnp.matmul((f2 * u[:, None]).T, x.x * x.x,
+                          precision=_EXACT).reshape(-1)
     if isinstance(x, PaddedSparse):
         if _runs_vmem_gather(x, u):
             return _boundary_sums(_vmem_segment_sums(

@@ -1,10 +1,12 @@
 """Inexact inner-solve budgets for block coordinate descent.
 
 The GAME outer loop re-perturbs every coordinate's problem on the next
-visit, so paying full-tolerance convergence on early visits is wasted work
-— an earlier round's chip record (since deleted; not re-measured on this
-round's chip, ROADMAP S3) showed a cold factored-MF solve taking three
-quarters of a fit whose warm revisit cost a fiftieth of it.  Running inner solves inexactly early and
+visit, so paying full-tolerance convergence on early visits is wasted work.
+What each coordinate's visits cost on the chip, the factored coordinate's
+two halves among them, is on record for the benchmark's cells (PERF.md
+sections 5 and 6; `game-ml20m-mf.fit` runs the factored one); no cell runs
+under a schedule yet, so what a schedule saves there is not measured.
+Running inner solves inexactly early and
 tightening geometrically toward the end is the standard cure (Trofimov &
 Genkin, arXiv:1611.02101; Snap ML's hierarchical local solvers,
 arXiv:1803.06333).

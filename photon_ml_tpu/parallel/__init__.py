@@ -14,6 +14,6 @@ from photon_ml_tpu.parallel.random_effect import (  # noqa: F401
     score_by_entity, score_entity_blocks,
 )
 from photon_ml_tpu.parallel.factored import (  # noqa: F401
-    FactoredSolveResult, fit_factored_random_effects, gaussian_projection_matrix,
-    project_blocks, refit_latent_projection,
+    FactoredSolveResult, ProjectionRows, fit_factored_random_effects,
+    gaussian_projection_matrix, project_blocks, refit_latent_projection,
 )

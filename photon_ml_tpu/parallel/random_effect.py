@@ -232,8 +232,12 @@ def score_entities_scatter(coefficients, projection, x, lanes, *,
 def score_entities_matmul(coefficients, projection_matrix, x,
                           lanes) -> jax.Array:
     """Dense-projection (random-projection / factored-latent) scoring as one
-    fused program: [E,k] @ [k,d] then entity gather + row dot."""
-    return score_by_entity(coefficients @ projection_matrix, x, lanes)
+    fused program: [E,k] @ [k,d] then entity gather + row dot.  The small
+    product is taken in float32 (`HIGHEST`): at a TPU's default it rounds
+    its operands to bfloat16 and every score with them."""
+    table = jnp.matmul(coefficients, projection_matrix,
+                       precision=jax.lax.Precision.HIGHEST)
+    return score_by_entity(table, x, lanes)
 
 
 @jax.jit

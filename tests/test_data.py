@@ -184,14 +184,16 @@ def test_offsets_from_flat(rng):
         ds, RandomEffectDataConfig("per_user", "g" if "g" in ds.feature_shards else "global",
                                    projector="identity"))
     flat = rng.normal(size=ds.num_rows)
-    blocks = red.with_offsets_from_flat(flat)
-    for e in range(red.num_entities):
-        for s in range(blocks.samples_per_entity):
-            r = red.active_row_ids[e, s]
-            if r >= 0:
-                assert float(blocks.offsets[e, s]) == pytest.approx(flat[r])
-            else:
-                assert float(blocks.offsets[e, s]) == 0.0
+    for bucket in red.buckets:
+        blocks = bucket.with_offsets_from_flat(flat)
+        for e in range(bucket.num_entities):
+            for s in range(blocks.samples_per_entity):
+                r = bucket.row_ids[e, s]
+                if r >= 0:
+                    assert float(blocks.offsets[e, s]) == pytest.approx(
+                        flat[r])
+                else:
+                    assert float(blocks.offsets[e, s]) == 0.0
 
 
 def _bucket_cells(samples, bounds):

@@ -26,7 +26,8 @@ from photon_ml_tpu.models.io import load_game_model, save_game_model
 from photon_ml_tpu.ops import GLMObjective, LOGISTIC, SQUARED, features as fops
 from photon_ml_tpu.optim import RegularizationContext, RegularizationType
 from photon_ml_tpu.parallel import (
-    gaussian_projection_matrix, fit_factored_random_effects, project_blocks,
+    ProjectionRows, gaussian_projection_matrix, fit_factored_random_effects,
+    project_blocks,
 )
 from photon_ml_tpu.parallel.random_effect import EntityBlocks
 
@@ -127,6 +128,10 @@ def test_alternation_decreases_objective(rng):
     y = z + 0.05 * rng.normal(size=(E, S))
     blocks = EntityBlocks(x=jnp.asarray(x), labels=jnp.asarray(y),
                           mask=jnp.ones((E, S)))
+    # the same cells as flat rows, for the projection's refit
+    rows = ProjectionRows(
+        x=blocks.x.reshape(E * S, d), labels=blocks.labels.reshape(E * S),
+        lanes=jnp.repeat(jnp.arange(E), S), weights=blocks.mask.reshape(-1))
     C0 = jnp.zeros((E, k))
     P0 = gaussian_projection_matrix(k, d, seed=11, dtype=jnp.float64)
 
@@ -137,12 +142,12 @@ def test_alternation_decreases_objective(rng):
 
     loss0 = total_loss(C0, P0)
     res1 = fit_factored_random_effects(
-        blocks, SQUARED, latent_coefficients=C0, projection=P0,
+        [blocks], rows, SQUARED, latent_coefficients=C0, projection=P0,
         num_inner_iterations=1, re_reg=L2, re_reg_weight=1e-3,
         latent_reg=L2, latent_reg_weight=1e-3)
     loss1 = total_loss(res1.latent_coefficients, res1.projection)
     res3 = fit_factored_random_effects(
-        blocks, SQUARED, latent_coefficients=C0, projection=P0,
+        [blocks], rows, SQUARED, latent_coefficients=C0, projection=P0,
         num_inner_iterations=3, re_reg=L2, re_reg_weight=1e-3,
         latent_reg=L2, latent_reg_weight=1e-3)
     loss3 = total_loss(res3.latent_coefficients, res3.projection)

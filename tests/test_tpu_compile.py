@@ -132,6 +132,44 @@ def test_fixed_effect_solve_compiles(one_chip, shape, optimizer):
     assert args >= n * d * 4          # the design matrix is an argument
 
 
+def test_factored_projection_refit_and_scoring_compile_at_the_cells_size(
+        one_chip):
+    """`game-ml20m-mf.fit`'s projection refit: the fixed effect's solver
+    over the implicit Kronecker design of the per-user shard's 7,600,100
+    flat rows (width 21, rank 8, the cell's 50 iterations under the
+    upstream's stopping rule), weights and offsets as `ProjectionRows`
+    hands them. It holds the shard and the factors by row as arguments and
+    well under a GB of temporaries. The scoring program every entity
+    coordinate runs has to fit its temporaries beside what the cell holds
+    resident, which the allocator's peak never counted (PERF.md section 7,
+    question 13)."""
+    from photon_ml_tpu.ops.features import KroneckerDesign
+    from photon_ml_tpu.parallel.fixed_effect import _cached_solver
+    from photon_ml_tpu.parallel.random_effect import score_entities_matmul
+    n, d, k, users = 7_600_100, 21, 8, 55_397
+    #: the cell's `memory_peak_bytes` (my chip runs, PR 35); the scoring
+    #: program's arguments are among them
+    resident = 7_520_929_280
+    rows = _sds((n,), F32, one_chip)
+    obj = GLMObjective(LOGISTIC, KroneckerDesign(_sds((n, d), F32, one_chip),
+                                                 _sds((n, k), F32, one_chip)),
+                       rows, weights=rows, offsets=rows)
+    cfg = OptimizerConfig(max_iterations=50)
+    compiled = _cached_solver(cfg, L2).lower(
+        obj, _sds((k * d,), F32, one_chip), _sds((), F32, one_chip),
+        None).compile()
+    _assert_float32(compiled)
+    assert _fits(compiled) >= n * (d + k + 3) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 1024 ** 3
+    scoring = score_entities_matmul.lower(
+        _sds((users, k), F32, one_chip), _sds((k, d), F32, one_chip),
+        _sds((n, d), F32, one_chip), _sds((n,), jnp.int32, one_chip)).compile()
+    _fits(scoring)
+    m = scoring.memory_analysis()
+    assert (resident + m.temp_size_in_bytes + m.output_size_in_bytes
+            < V5E_HBM_BYTES), m
+
+
 # criteo-hashed-1m.fit: 2,850,000 rows x 39 slots, 1,000,000 columns (721,687
 # stored), 111,150,000 non-zeros; and a shape whose `w` is over the VMEM budget
 CRITEO = (2_850_000, 39, 1_000_000, 111_150_000, 721_687)
