@@ -262,6 +262,8 @@ class RandomEffectDataset:
     lane_weight_scale: Optional[np.ndarray] = None
     _flat_weights_dev: object = dataclasses.field(default=None, repr=False,
                                                   compare=False)
+    _flat_lanes_dev: object = dataclasses.field(default=None, repr=False,
+                                                compare=False)
     _global_blocks: Optional[EntityBlocks] = dataclasses.field(
         default=None, repr=False, compare=False)
     _global_row_ids: Optional[np.ndarray] = dataclasses.field(
@@ -331,6 +333,19 @@ class RandomEffectDataset:
                     flat[rows] *= np.asarray(dataset.weights)[rows]
             self._flat_weights_dev = jnp.asarray(flat)
         return self._flat_weights_dev
+
+    def flat_train_lanes(self, dataset: GameDataset) -> jnp.ndarray:
+        """[n] device vector, canonical row order: the block lane of each
+        row of `dataset`, the one this was built from (-1 for a discarded
+        row or one of no entity): `flat_entity_lanes` of its own entity
+        column.  It depends on the build alone, so it is made at its first
+        use and kept with the memoised build: every coordinate of every fit
+        over one (dataset, config) reads ONE map, and it stays resident
+        under a budget (flat-vector class, like the labels)."""
+        if self._flat_lanes_dev is None:
+            self._flat_lanes_dev = jnp.asarray(self.flat_entity_lanes(
+                dataset.entity_indices[self.config.random_effect_type]))
+        return self._flat_lanes_dev
 
     def scatter_to_global(self, local_coefficients) -> jnp.ndarray:
         """[E, d_local] local-space coefficients -> [E, d_global]

@@ -98,6 +98,11 @@ class GameDataset:
     # counts, `pack_s` among them); dropped with the copy
     shard_build: Dict[str, dict] = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
+    # device copies of the flat [n] vectors (response, weights, offsets),
+    # one a (vector, device dtype), each beside the host array it was made
+    # from (`device_vector`)
+    _device_vectors: Dict[object, tuple] = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
     # scoring-side memos (entity-lane maps etc.), keyed by consumer
     _scoring_cache: Dict[object, object] = dataclasses.field(
         default_factory=dict, repr=False, compare=False)
@@ -109,6 +114,14 @@ class GameDataset:
         wide sparse shard for its column-sorted gradient view as well
         (`ops/features.py::pack_sparse`, which packs what the cached copy
         lacks and nothing twice); what a pack made is `shard_build[shard]`.
+
+        Every resident consumer reads this ONE copy: entity coordinates'
+        scoring and block gathers, validation rescoring, and on a mesh whose
+        data axis is one device the fixed-effect solve itself
+        (`FixedEffectCoordinate._mesh_x_source`), so no fit after the
+        dataset's first moves the shard over the host link.  A data axis
+        over several devices stages the host shard into its sharded layout
+        instead (`parallel/mesh_residency.py`).
 
         NOTE the memory doubling: the host numpy shard and the device copy
         both stay alive for the whole fit (every byte of feature data
@@ -140,6 +153,28 @@ class GameDataset:
         if release_host:
             self.release_host_shard(shard)
         return self._device_shards[shard]
+
+    def device_vector(self, name: str, dtype=None):
+        """Device copy of one flat [n] vector (`"response"`, `"weights"`,
+        `"offsets"`; None where the dataset has none) in `dtype` (default:
+        what `jnp.asarray` of the host array gives), transferred ONCE per
+        dataset and dtype and shared by every consumer: each coordinate of
+        each fit and the descent's own labels read one copy, where each
+        used to convert and upload its own.  The copy is held beside the
+        host array it was made from and made anew if the field is another
+        array; nothing donates or writes it."""
+        import jax
+        import jax.numpy as jnp
+        host = getattr(self, name)
+        if host is None:
+            return None
+        want = jnp.dtype(jax.dtypes.canonicalize_dtype(
+            host.dtype if dtype is None else dtype))
+        held = self._device_vectors.get((name, want))
+        if held is None or held[0] is not host:
+            held = (host, jnp.asarray(host, want))
+            self._device_vectors[(name, want)] = held
+        return held[1]
 
     def release_host_shard(self, shard: str) -> None:
         """Drop the host numpy copy of a shard, keeping only the device

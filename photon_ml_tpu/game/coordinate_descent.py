@@ -889,15 +889,17 @@ def run_coordinate_descent(
                         checkpoint_dir)
             checkpoint_dir = None
 
-    def _host_rows(a):
-        """[n] host vector -> device copy; on a multi-process mesh the copy
-        must be GLOBAL (data-sharded, assembled from per-process blocks) —
-        a local placement cannot feed a jit whose other operands span peer
-        processes' devices."""
+    def _dataset_rows(name):
+        """One of the dataset's [n] vectors -> its device copy, the one the
+        dataset holds for every fit (`GameDataset.device_vector`); on a
+        multi-process mesh the copy must be GLOBAL (data-sharded, assembled
+        from per-process blocks) — a local placement cannot feed a jit
+        whose other operands span peer processes' devices."""
         if _mh_mesh is not None:
             from photon_ml_tpu.parallel import multihost
-            return multihost.global_rows(_mh_mesh, np.asarray(a))
-        return jnp.asarray(a)
+            return multihost.global_rows(
+                _mh_mesh, np.asarray(getattr(dataset, name)))
+        return dataset.device_vector(name)
 
     def _zero_rows(n):
         if _mh_mesh is not None:
@@ -931,12 +933,12 @@ def run_coordinate_descent(
         return delta
     spans = PhaseTimings() if timings is None else timings
     with spans.span("init/transfer"):
-        labels = _host_rows(dataset.response)
+        labels = _dataset_rows("response")
         weights = (None if dataset.weights is None
-                   else _host_rows(dataset.weights))
+                   else _dataset_rows("weights"))
         base_offsets = (_zero_rows(dataset.num_rows)
                         if dataset.offsets is None
-                        else _host_rows(dataset.offsets))
+                        else _dataset_rows("offsets"))
         spans.add_blocked("init/transfer",
                           _sync(labels, weights, base_offsets))
 
@@ -1045,11 +1047,9 @@ def run_coordinate_descent(
             if pipelined:
                 # device copies for the jitted metric kernels (the host
                 # evaluators read the numpy arrays off the dataset instead)
-                val_labels_dev = jnp.asarray(validation_dataset.response)
-                val_weights_dev = (None if validation_dataset.weights is None
-                                   else jnp.asarray(validation_dataset.weights))
-                val_offsets_dev = (None if validation_dataset.offsets is None
-                                   else jnp.asarray(validation_dataset.offsets))
+                val_labels_dev = validation_dataset.device_vector("response")
+                val_weights_dev = validation_dataset.device_vector("weights")
+                val_offsets_dev = validation_dataset.device_vector("offsets")
             else:
                 spans.add_blocked("init/validation_score",
                                   _sync(*val_scores_by_coord.values()))

@@ -132,9 +132,8 @@ class FixedEffectCoordinate:
                     "streamed mode yet (the draw is a device-resident [n] "
                     "program); use memory_mode='resident'")
 
-        self.labels = jnp.asarray(dataset.response)
-        self.weights = (None if dataset.weights is None
-                        else jnp.asarray(dataset.weights))
+        self.labels = dataset.device_vector("response")
+        self.weights = dataset.device_vector("weights")
         self._key = jax.random.PRNGKey(seed)
         # shard coefficients over the mesh feature axis: explicit config wins,
         # otherwise automatic whenever the mesh carries a feature axis > 1
@@ -247,9 +246,10 @@ class FixedEffectCoordinate:
         elif hbm_budget_bytes is None:
             # no budget: materialize eagerly, exactly the pre-out-of-core
             # behavior (transfer cost lands in build/coordinates, not in the
-            # first solve span).  The mesh path stages its padded + sharded
-            # copy into the residency layer instead of a full single-device
-            # copy.
+            # first solve span).  A data axis over several devices stages
+            # its padded + sharded copy into the residency layer instead of
+            # a full single-device copy; over one device the dataset's own
+            # device copy IS that layout, and solve and score both read it.
             if self._admm_eligible:
                 # the ADMM lane trains AND scores through the column grid,
                 # so eager-stage that layout (the monolithic "x" entry only
@@ -287,12 +287,20 @@ class FixedEffectCoordinate:
 
     def _mesh_x_source(self):
         """Identity-stable source the mesh residency layer stages the
-        design matrix from.  A dense host shard stages DIRECTLY host ->
-        sharded devices (no intermediate full single-device copy); sparse
-        or host-released shards go through the shared device FeatureMatrix
-        (`self.x`)."""
+        design matrix from.  Where the mesh's data axis spans several
+        devices a dense host shard stages DIRECTLY host -> sharded devices
+        (no intermediate full single-device copy), as it does for the ADMM
+        column grid and under an HBM budget (whose device copy is lazy and
+        evictable).  Where the data axis is ONE device the sharded layout
+        is the single-device one, which the dataset already holds
+        (`GameDataset.device_shard`, kept across fits): the source is that
+        copy, `self.x`, the array `score` reads, so staging it moves
+        nothing and only the dataset's first fit uploads the shard.  Sparse
+        and host-released shards go through `self.x` on every mesh."""
         host = self._dataset.feature_shards[self.config.feature_shard]
-        if isinstance(host, np.ndarray):
+        if isinstance(host, np.ndarray) and (
+                self._data_div > 1 or self._admm_eligible
+                or self.hbm_budget_bytes is not None):
             return host
         return self.x
 
@@ -366,14 +374,15 @@ class FixedEffectCoordinate:
 
     def evict_device_blocks(self) -> None:
         """Residency-manager hook: drop the device shard between visits
-        (no-op when streamed — nothing is pinned).  The mesh path drops
-        ONLY this coordinate's staged sharded arrays (per-coordinate
-        invalidation; other coordinates' entries stay resident)."""
+        (no-op when streamed — nothing is pinned).  On a mesh, of any
+        shape, it drops ONLY this coordinate's staged arrays, which would
+        otherwise keep the shard pinned (per-coordinate invalidation; other
+        coordinates' entries stay resident)."""
         if self.streamed:
             return
         self._x = None
         self._dataset.release_device_shard(self.config.feature_shard)
-        if self._data_div > 1:
+        if self.mesh is not None:
             from photon_ml_tpu.parallel.mesh_residency import invalidate
             invalidate(self._mesh_key())
 
@@ -447,9 +456,9 @@ class FixedEffectCoordinate:
             x0 = self.norm.model_to_transformed_space(x0)
         if self.mesh is not None:
             # mesh-resident path: the objective's static arrays stage ONCE
-            # per coordinate through the residency layer (dense host shards
-            # stage straight into their sharded layout — no intermediate
-            # full-device copy); a warm visit moves only offsets and x0
+            # per coordinate through the residency layer (the design matrix
+            # from wherever `_mesh_x_source` says it lies); a warm visit
+            # moves only offsets and x0
             obj = GLMObjective(self.loss, self._mesh_x_source(), self.labels,
                                weights=weights, offsets=offsets,
                                norm=self.norm)
@@ -588,8 +597,7 @@ class _EntityCoordinateBase:
         self._proj_dev = None
         if hbm_budget_bytes is None:
             self._flat_x = dataset.device_shard(config.feature_shard)
-        self.lanes = jnp.asarray(self.red.flat_entity_lanes(
-            dataset.entity_indices[config.random_effect_type]))
+        self.lanes = self.red.flat_train_lanes(dataset)
         self.entity_id_values = np.asarray(
             dataset.entity_vocabs[config.random_effect_type])[self.red.entity_ids]
 
@@ -771,7 +779,6 @@ class FactoredRandomEffectCoordinate(_EntityCoordinateBase):
                          hbm_budget_bytes=hbm_budget_bytes)
         self.seed = seed
         self._key = jax.random.PRNGKey(seed + 1)
-        self._labels = None
         # what `update` solves on, known from the build alone: the latent
         # half on the S-buckets `train.re_build.*` counts (`cells`), the
         # projection's refit on the shard's flat rows (`rows`), of either of
@@ -793,16 +800,9 @@ class FactoredRandomEffectCoordinate(_EntityCoordinateBase):
 
     @property
     def labels(self):
-        """Device copy of the flat labels (the projection's refit reads the
-        rows where they lie), lazily re-streamed after an eviction."""
-        if self._labels is None:
-            self._labels = jnp.asarray(self._dataset.response,
-                                       self.red.dtype)
-        return self._labels
-
-    def evict_device_blocks(self) -> None:
-        super().evict_device_blocks()
-        self._labels = None
+        """The dataset's device copy of the flat labels (the projection's
+        refit reads the rows where they lie), in the blocks' dtype."""
+        return self._dataset.device_vector("response", self.red.dtype)
 
     def initial_model(self) -> FactoredRandomEffectModel:
         """Zero latent factors + Gaussian random projection (reference:

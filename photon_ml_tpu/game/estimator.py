@@ -62,9 +62,10 @@ class GameResult:
     checkpoint_recovery: Optional[dict] = None
     # mesh transfer accounting over this fit (TransferStats delta from
     # parallel/mesh_residency.py): bytes staged cold (static coordinate
-    # data, once per residency) vs warm (per-visit offsets/x0) — the
-    # observable no-retransfer property (tests/test_mesh_residency.py).  None when the
-    # fit ran without a multi-device mesh.
+    # data, once per residency) vs warm (per-visit offsets/x0), and of
+    # either kind those whose source was a host array (`host_bytes`: what
+    # crossed the host link) — the observable no-retransfer property
+    # (tests/test_mesh_residency.py).  None when the fit ran without a mesh.
     mesh_transfer: Optional[dict] = None
     # per entity-keyed coordinate, what its build did with the rows:
     # entities, active / passive / discarded rows, capped entities, padded
@@ -220,7 +221,7 @@ class GameEstimator:
         # snapshot BEFORE the build: eager mesh staging of FE shards happens
         # inside _build_coordinates and belongs to this fit's cold bytes
         mesh_snap0 = None
-        if self.mesh is not None and self.mesh.size > 1:
+        if self.mesh is not None:
             from photon_ml_tpu.parallel.mesh_residency import transfer_snapshot
             mesh_snap0 = transfer_snapshot()
         # coordinate construction includes the RE dataset bucketing — a real

@@ -19,7 +19,15 @@ and residuals; this module is that discipline for the GSPMD mesh path:
   * `TransferStats` counts every staged byte, split COLD (static data,
     staged once per residency) vs WARM (per-visit operands), so the
     no-retransfer property is observable: tests/test_mesh_residency.py
-    gates "zero cold bytes across warm outer iterations" on it.
+    gates "zero cold bytes across warm outer iterations" on it.  Beside
+    both it counts HOST bytes, those whose source was a host array.  A
+    source that is already a device array of the mesh costs none: on a
+    mesh whose data axis is ONE device the sharded layout is the
+    single-device one, `device_put` hands back the source's own buffer,
+    and a coordinate stages the copies its dataset holds across fits
+    (`GameDataset.device_shard`, `.device_vector`) at no transfer.  Only
+    where the data axis spans devices does a dense host shard go host ->
+    sharded devices, with no full single-device copy on the way.
   * staging runs under the same transient/fatal fault classification as
     the streaming Prefetcher: the `mesh.stage` injection site
     (utils/faults.py) fires before each transfer, transient failures retry
@@ -73,20 +81,26 @@ class TransferStats:
     no-retransfer property.  COLD bytes are static coordinate data (feature
     blocks, labels, masks) staged once per residency; WARM bytes are the
     per-visit operands (residual offsets, x0) that legitimately move every
-    update.  Thread-safe: scoring may stage from worker threads."""
+    update.  HOST bytes are those of either kind whose SOURCE was a host
+    array, the ones that crossed the host link: a stage whose source is
+    already a device array of the mesh (a one-device mesh reads the
+    dataset's own copy where it lies) counts cold or warm and no host
+    bytes.  Thread-safe: scoring may stage from worker threads."""
 
     def __init__(self):
         self._lock = locktrace.tracked(threading.Lock(),
                                        "TransferStats._lock")
         self.cold_bytes = 0
         self.warm_bytes = 0
+        self.host_bytes = 0
         self.cold_stages = 0
         self.warm_stages = 0
         self.invalidations = 0
         self.evictions = 0          # FIFO capacity evictions, not eviction-API
         self.retries = 0
 
-    def note_stage(self, nbytes: int, warm: bool) -> None:
+    def note_stage(self, nbytes: int, warm: bool,
+                   host_nbytes: int = 0) -> None:
         with self._lock:
             if warm:
                 self.warm_bytes += nbytes
@@ -94,11 +108,13 @@ class TransferStats:
             else:
                 self.cold_bytes += nbytes
                 self.cold_stages += 1
+            self.host_bytes += host_nbytes
         # registry mirror: telemetry.snapshot() carries the cold/warm split
         # without reaching into the residency singleton
         kind = "warm" if warm else "cold"
         telemetry.counter(f"mesh.{kind}_bytes").inc(nbytes)
         telemetry.counter(f"mesh.{kind}_stages").inc()
+        telemetry.counter("mesh.host_bytes").inc(host_nbytes)
 
     def note_invalidation(self, count: int = 1) -> None:
         with self._lock:
@@ -119,6 +135,7 @@ class TransferStats:
         with self._lock:
             return {"cold_bytes": self.cold_bytes,
                     "warm_bytes": self.warm_bytes,
+                    "host_bytes": self.host_bytes,
                     "cold_stages": self.cold_stages,
                     "warm_stages": self.warm_stages,
                     "invalidations": self.invalidations,
@@ -214,18 +231,21 @@ def _leaf_nbytes(staged) -> int:
 
 def _stage_tree(mesh, tree, fill, spec: str):
     """Pad (data-spec leaves, leading axis to a mesh multiple) + shard one
-    array or FeatureMatrix pytree.  Returns (staged, nbytes)."""
+    array or FeatureMatrix pytree.  Returns (staged, nbytes, host_nbytes):
+    the last is the part of nbytes whose source leaf was no device array."""
     from photon_ml_tpu.ops import features as fops
     if tree is None:
-        return None, 0
+        return None, 0, 0
     if isinstance(tree, (np.ndarray, jnp.ndarray, jax.Array)) \
             or not hasattr(tree, "tree_flatten"):
         a = tree if hasattr(tree, "shape") else np.asarray(tree)
+        from_host = not isinstance(a, jax.Array)
         if spec in ("data", "grid"):
             rem = (-a.shape[0]) % mesh.shape[DATA_AXIS]
             a = _pad_axis0(a, rem, fill)
         staged = _put_leaf(mesh, a, spec)
-        return staged, _leaf_nbytes(staged)
+        nbytes = _leaf_nbytes(staged)
+        return staged, nbytes, nbytes if from_host else 0
     # FeatureMatrix pytree (PaddedSparse / KroneckerDesign): pad via the
     # shared pad_rows, then shard every array leaf on its leading axis.
     # Row-shaped pytrees carry a .shape; others (NormalizationContext
@@ -240,8 +260,11 @@ def _stage_tree(mesh, tree, fill, spec: str):
         padded = fops.pad_rows(tree, rem)
     staged = jax.tree_util.tree_map(lambda l: _put_leaf(mesh, l, spec),
                                     padded)
-    nbytes = sum(_leaf_nbytes(l) for l in jax.tree_util.tree_leaves(staged))
-    return staged, nbytes
+    sizes = [(_leaf_nbytes(s), isinstance(l, jax.Array)) for l, s in
+             zip(jax.tree_util.tree_leaves(padded),
+                 jax.tree_util.tree_leaves(staged))]
+    return (staged, sum(b for b, _ in sizes),
+            sum(b for b, on_device in sizes if not on_device))
 
 
 def _mesh_fingerprint(mesh) -> tuple:
@@ -286,8 +309,9 @@ class MeshResidency:
                                 warm=warm):
                 src = (host_or_build() if callable(host_or_build)
                        else host_or_build)
-                staged, nbytes = _stage_tree(mesh, src, fill, spec)
-            self.stats.note_stage(nbytes, warm=warm)
+                staged, nbytes, host_nbytes = _stage_tree(mesh, src, fill,
+                                                          spec)
+            self.stats.note_stage(nbytes, warm=warm, host_nbytes=host_nbytes)
             return staged, nbytes
 
         return with_retries(

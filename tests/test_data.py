@@ -507,3 +507,85 @@ def test_sparse_summary_matches_dense(rng):
                       "norm_l1", "norm_l2", "mean_abs"):
             np.testing.assert_allclose(getattr(a, field), getattr(b, field),
                                        rtol=1e-10, atol=1e-12, err_msg=field)
+
+
+# -- what a fit's prologue derives from the dataset alone is kept with it -----
+
+def _lane_dataset(rng, n=240, users=12):
+    x = rng.normal(size=(n, 5)); x[:, -1] = 1.0
+    ids = np.asarray([f"u{rng.integers(users):02d}" for _ in range(n)])
+    y = (rng.uniform(size=n) > 0.5).astype(float)
+    return build_game_dataset(y, {"g": x}, entity_ids={"userId": ids},
+                              weights=rng.uniform(0.5, 2.0, size=n))
+
+
+def _lane_coordinate(ds, cap, seed=7):
+    from photon_ml_tpu.game import (GLMOptimizationConfig,
+                                    RandomEffectCoordinateConfig)
+    from photon_ml_tpu.game.coordinates import RandomEffectCoordinate
+    cfg = RandomEffectCoordinateConfig("userId", "g", GLMOptimizationConfig(),
+                                       projector="identity",
+                                       active_data_upper_bound=cap)
+    return RandomEffectCoordinate("perUser", ds, cfg, "logistic_regression",
+                                  seed=seed)
+
+
+@pytest.mark.parametrize("other", ["same", "subset", "validation", "cap",
+                                   "seed"])
+def test_lane_map_is_made_once_a_dataset_and_configuration(rng, other):
+    """Two coordinates over one dataset and one data configuration read ONE
+    row -> lane map, the memoised build's; another dataset (a subset, a
+    validation split) or another configuration (cap, seed) gets its own, and
+    every one of them is `flat_entity_lanes` of its own entity column."""
+    ds = _lane_dataset(rng)
+    first = _lane_coordinate(ds, 16)
+    if other == "same":
+        second = _lane_coordinate(ds, 16)
+        assert second.red is first.red
+        assert second.lanes is first.lanes
+    elif other in ("subset", "validation"):
+        rows = (np.arange(0, ds.num_rows, 2) if other == "subset"
+                else np.arange(ds.num_rows - 60, ds.num_rows))
+        part = ds.subset(rows)
+        second = _lane_coordinate(part, 16)
+        assert second.lanes is not first.lanes
+        assert second.lanes.shape == (len(rows),)
+        ds = part
+    elif other == "cap":
+        second = _lane_coordinate(ds, 8)
+        assert second.red is not first.red
+        assert second.lanes is not first.lanes
+    else:
+        second = _lane_coordinate(ds, 16, seed=11)
+        assert second.red is not first.red
+        assert second.lanes is not first.lanes
+    np.testing.assert_array_equal(
+        np.asarray(second.lanes),
+        second.red.flat_entity_lanes(ds.entity_indices["userId"]))
+
+
+@pytest.mark.parametrize("name", ["response", "weights", "offsets"])
+def test_device_vector_is_one_copy_a_dataset_and_dtype(rng, name):
+    """The flat labels / weights / offsets a coordinate or the descent reads
+    are ONE device copy a (dataset, dtype): the same object at every call,
+    another one a dtype, made anew when the field is another array, None
+    where the dataset has no such vector, and a subset's own."""
+    ds = _lane_dataset(rng)
+    host = getattr(ds, name)
+    if host is None:
+        assert ds.device_vector(name) is None
+        return
+    dev = ds.device_vector(name)
+    assert ds.device_vector(name) is dev
+    assert dev.dtype == jax.dtypes.canonicalize_dtype(host.dtype)
+    np.testing.assert_array_equal(np.asarray(dev), host.astype(dev.dtype))
+    single = ds.device_vector(name, jnp.float32)
+    assert single.dtype == jnp.float32
+    assert ds.device_vector(name, jnp.float32) is single
+    assert (single is dev) == (dev.dtype == jnp.float32)
+    assert ds.subset(np.arange(10)).device_vector(name) is not dev
+    setattr(ds, name, host * 2.0)
+    again = ds.device_vector(name)
+    assert again is not dev
+    np.testing.assert_array_equal(np.asarray(again),
+                                  (host * 2.0).astype(dev.dtype))

@@ -240,6 +240,88 @@ def test_eviction_invalidates_only_the_evicted_coordinate(rng):
     assert delta["cold_bytes"] > 0
 
 
+# -- the design matrix: where a solve reads it from (ISSUE 36) ----------------
+
+@pytest.mark.parametrize("data_devices", [1, 8])
+def test_design_matrix_stages_from_where_the_mesh_says(rng, data_devices):
+    """Data axis over ONE device: the solve's operand is the dataset's own
+    device copy, the buffer `score` reads, and staging it moves no host
+    byte.  Data axis over several: the dense HOST shard stages host ->
+    sharded devices, every byte from the host, and no full single-device
+    copy of it is ever made.  On either mesh an evicted fixed-effect
+    coordinate leaves no entry of its own in the registry."""
+    import jax
+    from photon_ml_tpu.parallel import mesh_residency
+    from photon_ml_tpu.parallel.fixed_effect import staged_fixed_effect_x
+    train, _ = _glmix(rng, n=800, num_users=32)
+    cfg = _config(iters=4)
+    mesh = make_mesh(devices=jax.devices()[:data_devices])
+    mesh_residency.clear()
+    before = transfer_snapshot()
+    coords = GameEstimator(cfg, mesh=mesh)._build_coordinates(train)
+    fe = coords["fixed"]
+    model, _ = fe.update(fe.initial_model(), jnp.zeros(train.num_rows))
+    scores = fe.score(model)
+    delta = TransferStats.delta(before, transfer_snapshot())
+    n, x_dev = staged_fixed_effect_x(fe._mesh_key(), mesh,
+                                     fe._mesh_x_source())
+    assert TransferStats.delta(before, transfer_snapshot()) == delta, (
+        "update, score and a third reader did not share one staged entry")
+    host = train.feature_shards["global"]
+    flat = n * host.itemsize
+    assert scores.shape == (n,) and n == train.num_rows
+    # what either mesh staged before this PR, to the byte: the matrix, the
+    # labels and the mask cold, the offsets and x0 warm
+    assert delta["cold_bytes"] == host.nbytes + 2 * flat
+    assert delta["warm_bytes"] == flat + host.shape[1] * host.itemsize
+    if data_devices == 1:
+        assert fe._mesh_x_source() is fe.x is train.device_shard("global")
+        assert x_dev.unsafe_buffer_pointer() == fe.x.unsafe_buffer_pointer()
+        assert delta["host_bytes"] == flat       # the mask of ones alone
+    else:
+        assert fe._mesh_x_source() is host
+        assert "global" not in train._device_shards
+        assert len(x_dev.sharding.device_set) == data_devices
+        assert x_dev.addressable_shards[0].data.shape == (n // data_devices,
+                                                          host.shape[1])
+        assert delta["host_bytes"] == host.nbytes + flat
+    prefix = fe._mesh_key()
+    held = lambda: [k for k in default_residency().keys()
+                    if k[0][: len(prefix)] == prefix]
+    assert held()
+    fe.evict_device_blocks()
+    assert not held(), "an evicted coordinate left its staged matrix pinned"
+    assert "global" not in train._device_shards
+
+
+def test_one_device_mesh_refits_move_no_design_matrix(rng):
+    """What `cli.train --mesh auto` and the benchmark's cells run: whole
+    fits of one dataset on a one-device mesh, the registry cleared between
+    them.  Every fit reports its transfer delta, no fit's host bytes reach
+    the design matrix's (the first fit's one upload is the dataset's, not a
+    stage), a warm fit's are [n] vectors and x0, and every fit walks the
+    first's objective history bit for bit."""
+    import jax
+    from photon_ml_tpu.parallel import mesh_residency
+    train, val = _glmix(rng, n=800, num_users=32)
+    cfg = _config(iters=4)
+    mesh = make_mesh(devices=jax.devices()[:1])
+    fits = []
+    for _ in range(3):
+        mesh_residency.clear()
+        fits.append(GameEstimator(cfg, mesh=mesh).fit(train, val))
+    matrix = train.feature_shards["global"].nbytes
+    item = train.feature_shards["global"].itemsize
+    for fit in fits:
+        assert fit.objective_history == fits[0].objective_history
+        assert isinstance(fit.mesh_transfer, dict)
+        assert fit.mesh_transfer["cold_bytes"] > matrix
+        # the fixed effect's mask of ones is the one host-made [n] vector
+        assert fit.mesh_transfer["host_bytes"] <= (
+            2 * train.num_rows + 2 * 10) * item < matrix
+    assert fits[1].mesh_transfer == fits[2].mesh_transfer
+
+
 def test_default_residency_singleton_under_thread_race():
     """Regression for the PH013 bare lazy init: racing first calls must
     all get ONE registry (two would split the TransferStats the mesh
