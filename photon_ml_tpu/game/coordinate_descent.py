@@ -33,6 +33,7 @@ from photon_ml_tpu.game import quarantine as quarantine_mod
 from photon_ml_tpu.game.coordinates import Coordinate
 from photon_ml_tpu.models.game import GameModel
 from photon_ml_tpu.ops import TASK_LOSSES
+from photon_ml_tpu.optim.types import LOCKSTEP
 from photon_ml_tpu.telemetry.timings import PhaseTimings, clock
 from photon_ml_tpu.utils import faults
 from photon_ml_tpu.utils import durable
@@ -123,22 +124,31 @@ class TrackerSummary:
     # examples_per_staged_byte — the stochastic lane's win is this ratio
     # going up by ~the local epoch count.  None on resident coordinates.
     stream: Optional[Dict[str, object]] = None
-    # LBFGS/OWLQN solves only (SolveResult.fg_count / .ls_trials).  The
-    # lanes of a vmapped solve run in lock step, so what the device ran is
-    # the MAX over lanes of fg_count: full value+gradient passes, two reads
-    # of the features each.  `ls_trials` is the max over lanes of the line
-    # search's trial points (the lock-step search ran at least as many);
+    # LBFGS/OWLQN solves only (SolveResult.fg_count / .ls_trials /
+    # .lockstep).  `data_passes`: full value+gradient passes, two reads of
+    # the features each.  An entity coordinate's visit runs one lock step a
+    # bucket, each as long as its slowest lane, one after the other: the
+    # SUM over its runs of each run's most passes of any lane.
+    # `ls_trials`: the line search's trial values the device evaluated, for
+    # an entity coordinate the sum of its runs' `lockstep_trials`, which
+    # counts the trials the batched search ran for lanes that had ended.
     # 1 - data_passes / (1 + ls_trials) is the share of evaluations served
     # from cached margins without reading the features (0 where every
     # trial is a full pass: L1, box).
     data_passes: Optional[int] = None
     ls_trials: Optional[int] = None
+    # an entity coordinate's visit only: its runs' lock-step counts,
+    # {LOCKSTEP column: [one int a run, in bucket order]}
+    # (optim/types.py LOCKSTEP says what each counts)
+    lockstep: Optional[Dict[str, List[int]]] = None
     # a factored coordinate's visit only: `data_passes` is the sum of its
     # two halves, these are the halves.  The per-entity solves in the latent
-    # space (max over lanes) and the one-lane refit of the shared projection
-    # read different operands, so their passes cost differently.
+    # space (summed over their runs) and the one-lane refit of the shared
+    # projection read different operands, so their passes cost differently.
+    # `latent_lockstep` is `lockstep` of the latent half's runs
     latent_data_passes: Optional[int] = None
     projection_data_passes: Optional[int] = None
+    latent_lockstep: Optional[Dict[str, List[int]]] = None
 
 
 def _reason_counts(reason) -> Dict[str, int]:
@@ -157,8 +167,18 @@ def _reason_counts(reason) -> Dict[str, int]:
     return out
 
 
-def _summarize_tracker(tracker: object, wall_s: float,
-                       budget=None) -> TrackerSummary:
+def _lock_step_of(tracker: object):
+    """The lock-step rows of a visit's batched per-entity solves
+    (SolveResult.lockstep, a device array), or None: a fixed effect, TRON,
+    a frozen coordinate."""
+    part = getattr(tracker, "random_effect_result", tracker)
+    return getattr(part, "lockstep", None)
+
+
+def _summarize_tracker(tracker: object, wall_s: float, budget=None,
+                       lockstep=None) -> TrackerSummary:
+    """`lockstep`: the tracker's lock-step rows as the flush's batched read
+    fetched them; read here (a sync) where it did not."""
     # a factored-MF tracker carries one SolveResult per half of the
     # alternation; merge both instead of dropping them on the floor
     parts = [t for t in (getattr(tracker, "random_effect_result", None),
@@ -174,18 +194,45 @@ def _summarize_tracker(tracker: object, wall_s: float,
     cap, tol = (None, None) if budget is None else budget
     summary = TrackerSummary(iterations=count, wall_s=wall_s, reasons=reasons,
                              iteration_cap=cap, tolerance=tol)
+    if lockstep is None:
+        lockstep = _lock_step_of(tracker)
+    runs = (None if lockstep is None else
+            {k: [int(v) for v in column]
+             for k, column in zip(LOCKSTEP, np.asarray(lockstep).T)})
     counted = [t for t in parts if getattr(t, "ls_trials", None) is not None]
     if counted:
-        # the halves of a factored alternation run one after the other
-        passes = [int(np.max(np.asarray(t.fg_count), initial=0))
-                  for t in counted]
-        summary.data_passes = sum(passes)
-        summary.ls_trials = sum(
-            int(np.max(np.asarray(t.ls_trials), initial=0)) for t in counted)
+        # the halves of a factored alternation run one after the other, and
+        # so do an entity coordinate's runs, one a bucket
+        passes, trials = [], []
+        for t in counted:
+            if t is parts[0] and runs is not None:
+                passes.append(sum(runs["data_passes"]))
+                trials.append(sum(runs["lockstep_trials"]))
+            else:       # one lane
+                passes.append(int(np.max(np.asarray(t.fg_count), initial=0)))
+                trials.append(int(np.max(np.asarray(t.ls_trials),
+                                         initial=0)))
+        summary.data_passes, summary.ls_trials = sum(passes), sum(trials)
         if len(parts) == 2 and len(counted) == 2:
             summary.latent_data_passes, summary.projection_data_passes = \
                 passes
+    if len(parts) == 2:
+        summary.latent_lockstep = runs
+    else:
+        summary.lockstep = runs
     return summary
+
+
+def _mark_lock_step(coordinate: str, visit: int,
+                    summary: TrackerSummary) -> None:
+    """One zero-length `photon/re/lockstep` profiler event a run of the
+    visit's per-entity solve, the run's counts as its arguments: on the
+    profiler's clock, inside the fit it belongs to.  A flag check a run
+    while no profiler is on."""
+    runs = summary.lockstep or summary.latent_lockstep
+    for k, row in enumerate(zip(*(runs or {}).values())):
+        telemetry.mark("re/lockstep", coordinate=coordinate, visit=visit,
+                       run=k, **dict(zip(runs, row)))
 
 
 @dataclasses.dataclass
@@ -221,7 +268,9 @@ class CoordinateDescentResult:
         the budget trajectory (iteration caps per visit, None entries =
         strict full solves), for LBFGS/OWLQN the data passes the device ran
         and the line-search trials per visit (TrackerSummary.data_passes /
-        .ls_trials), host-blocked seconds attributed to the
+        .ls_trials), for an entity coordinate its runs' lock-step counts
+        per visit (`lockstep`, the factored one's `latent_lockstep`),
+        host-blocked seconds attributed to the
         coordinate's spans, and — when the telemetry compile watch was
         armed — fresh traces per coordinate.  reference: the per-update
         OptimizationStatesTracker logs the GAME driver prints."""
@@ -240,11 +289,16 @@ class CoordinateDescentResult:
             if t.ls_trials is not None:
                 d.setdefault("data_passes", []).append(t.data_passes)
                 d.setdefault("ls_trials", []).append(t.ls_trials)
+            if t.lockstep is not None:
+                d.setdefault("lockstep", []).append(t.lockstep)
             if t.projection_data_passes is not None:
                 d.setdefault("latent_data_passes", []).append(
                     t.latent_data_passes)
                 d.setdefault("projection_data_passes", []).append(
                     t.projection_data_passes)
+            if t.latent_lockstep is not None:
+                d.setdefault("latent_lockstep", []).append(
+                    t.latent_lockstep)
             if t.containment is not None:
                 d["containment"][t.containment] = \
                     d["containment"].get(t.containment, 0) + 1
@@ -1137,17 +1191,19 @@ def run_coordinate_descent(
 
     def flush_pending() -> None:  # photonlint: flush-point
         """ONE batched device_get for every objective + metric + HEALTH
-        scalar of the outer iteration, then the deferred host bookkeeping
-        (history appends, tracker summaries, best-model tracking, logging,
+        scalar of the outer iteration and every entity coordinate's
+        lock-step counts (a row of scalars a run), then the deferred host
+        bookkeeping (history appends, tracker summaries and their
+        `photon/re/lockstep` marks, best-model tracking, logging,
         quarantine containment)."""
         nonlocal best_metric, best_model
         if not pending:
             return
         fetched = jax.device_get(
-            [[p["objective"], p["health"], list(p["metrics"].values())]
-             for p in pending])
+            [[p["objective"], p["health"], list(p["metrics"].values()),
+              _lock_step_of(p["tracker"])] for p in pending])
         divergent = []
-        for p, (obj, health, metric_vals) in zip(pending, fetched):
+        for p, (obj, health, metric_vals, lockstep) in zip(pending, fetched):
             obj = float(obj)
             healthy = bool(health)
             key = f"{p['it']}/{p['name']}"
@@ -1160,7 +1216,8 @@ def run_coordinate_descent(
                 divergent.append(p)
             objective_history.append(obj)
             trackers[key] = _summarize_tracker(
-                p["tracker"], spans[p["solve_key"]], p["budget"])
+                p["tracker"], spans[p["solve_key"]], p["budget"], lockstep)
+            _mark_lock_step(p["name"], p["it"], trackers[key])
             trackers[key].containment = ("rolled_back" if not healthy
                                          else p["containment"])
             trackers[key].staged_bytes = p["staged"]
@@ -1279,6 +1336,7 @@ def run_coordinate_descent(
                     # per-update sync pipelined mode defers to the flush
                     trackers[f"{it}/{name}"] = _summarize_tracker(
                         tracker, spans[solve_key], budget_diag)
+                    _mark_lock_step(name, it, trackers[f"{it}/{name}"])
                     if frozen:
                         trackers[f"{it}/{name}"].containment = "frozen"
 

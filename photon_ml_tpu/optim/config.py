@@ -156,6 +156,7 @@ def solve(
     reg: RegularizationContext = RegularizationContext(),
     reg_weight: jax.Array | float = 0.0,
     budget=None,
+    lane_axis=None,
 ) -> SolveResult:
     """Run one GLM solve: objective + config -> SolveResult.
 
@@ -171,6 +172,9 @@ def solve(
     tests the dynamic cap, and a per-outer-iteration budget schedule
     compiles nothing new.  `budget=None` keeps the config's static values,
     which is the identical arithmetic.
+
+    `lane_axis`: the vmap axis of a batched per-entity solve, whose lock
+    step an L-BFGS/OWLQN solve then counts (optim/lbfgs.py); TRON ignores it.
 
     `reg_weight` may be an optim.schedule.RegWeights: then BOTH penalty
     weights ride as traced operands (bypassing `reg.split`'s static
@@ -223,4 +227,5 @@ def solve(
                  lower=lower, upper=upper,
                  track_coefficients=cfg.track_coefficients,
                  iteration_cap=iteration_cap,
-                 margin_surface=obj if affine_trials else None)
+                 margin_surface=obj if affine_trials else None,
+                 lane_axis=lane_axis)

@@ -80,6 +80,11 @@ class _State(NamedTuple):
     gnorm_hist: jax.Array
     coef_hist: "jax.Array | None"   # [max_iter+1, d] when tracking, else None
     z: "jax.Array | None" = None    # margins at x on the margin path, else None
+    # under a lane axis: the trials the lock step ran and those its running
+    # lanes needed, summed over the trips this lane ran; else None.  Two
+    # scalars and not one [2]: vmapped, a [E, 2] carry is tiled to [E, 128]
+    ran: "jax.Array | None" = None
+    needed: "jax.Array | None" = None
 
 
 # In float32 a single step's progress can round to an exact zero f-change
@@ -151,6 +156,7 @@ def lbfgs(
     track_coefficients: bool = False,
     iteration_cap: Optional[jax.Array] = None,
     margin_surface=None,
+    lane_axis: Optional[str] = None,
 ) -> SolveResult:
     """Minimize f (+ optional l1*|x|_1, making this OWLQN) from x0.
 
@@ -190,6 +196,20 @@ def lbfgs(
     rounding of z + t u against X (x + t p).  The value and gradient norm
     RETURNED are recomputed from fresh margins X x at the final x, so a
     drift of the carried z never reaches a caller.
+
+    `lane_axis` names the `jax.vmap` axis this solve runs under in lock step
+    with other lanes (parallel/random_effect.py).  There the loop's
+    predicate is batched: the body runs for every lane on every trip, the
+    search inside it while ANY lane's search condition holds (an ended
+    lane's too, on its frozen state), and a select keeps only the running
+    lanes' carry, so no lane's own `ls_trials` counts what the device ran.
+    Each trip therefore adds to a carried pair the lanes' largest
+    backtrack count (`lax.pmax`) and the largest among the lanes still
+    running, each plus the first trial; the last lane to end has added
+    every trip's.  `lockstep` returns the two and the passes the lock
+    step read as this lane saw it: `fg_count` on cached margins (the lane
+    that ran most trips read trips + 2), else one a lock-step trial and the
+    first.  Nothing else reads it.
     """
     use_l1 = l1_weight is not None
     use_box = lower is not None or upper is not None
@@ -272,6 +292,8 @@ def lbfgs(
         coef_hist=(jnp.full((max_iterations + 1, d), nan).at[0].set(x0)
                    if track_coefficients else None),
         z=z0,
+        ran=None if lane_axis is None else jnp.asarray(0, jnp.int32),
+        needed=None if lane_axis is None else jnp.asarray(0, jnp.int32),
     )
 
     def cond(st: _State):
@@ -341,6 +363,11 @@ def lbfgs(
         t, ls_n, ls_ok, f_new, kept = lax.while_loop(
             ls_cond, ls_body,
             (t0, jnp.asarray(0, jnp.int32), armijo_ok(xt0, ft0), ft0, kept0))
+        ran = needed = None
+        if lane_axis is not None:
+            ran = st.ran + 1 + lax.pmax(ls_n, lane_axis)
+            needed = st.needed + 1 + lax.pmax(jnp.where(cond(st), ls_n, 0),
+                                              lane_axis)
         if use_margins:
             x_new = trial(t)
             z_new = st.z + t * u
@@ -399,6 +426,8 @@ def lbfgs(
             coef_hist=(None if st.coef_hist is None
                        else st.coef_hist.at[k].set(x_new)),
             z=z_new,
+            ran=ran,
+            needed=needed,
         )
 
     st = lax.while_loop(cond, body, init)
@@ -413,11 +442,16 @@ def lbfgs(
     else:
         value, gnorm_final = st.f, st.gnorm_hist[st.k]
         fg_count = st.ls_trials + 1     # f0/g0 and every trial
+    lockstep = None
+    if lane_axis is not None:
+        lockstep = (st.ran, st.needed,
+                    fg_count if use_margins else st.ran + 1)
     return SolveResult(x=st.x, value=value, gradient_norm=gnorm_final,
                        iterations=st.k, reason=reason,
                        loss_history=st.loss_hist, gnorm_history=st.gnorm_hist,
                        coefficient_history=st.coef_hist,
-                       fg_count=fg_count, ls_trials=st.ls_trials)
+                       fg_count=fg_count, ls_trials=st.ls_trials,
+                       lockstep=lockstep)
 
 
 def owlqn(value_and_grad: ValueAndGrad, x0: jax.Array, *, l1_weight,

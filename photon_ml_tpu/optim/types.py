@@ -33,6 +33,20 @@ class ConvergenceReason(enum.IntEnum):
     TRUST_REGION_EXHAUSTED = 5      # TRON: max step-failures (TRON.scala:258)
 
 
+#: the columns of `SolveResult.lockstep`, one row a run of a batched solve:
+#: the program's lanes and samples a lane (its [E, S]), the lanes that hold
+#: a row, the trips of the vmapped loop (the most iterations of any lane),
+#: the real lanes' own iterations summed, the trial values the device
+#: evaluated (a trip's first trial and every run of the batched line
+#: search, which runs while ANY lane's condition holds, ended lanes
+#: included), those the lanes still running at a trip needed, and the
+#: value+gradient passes the device read: the most of any lane (`fg_count`,
+#: trips + 2) on cached margins, one a lock-step trial and the first where
+#: every trial is a pass (L1, box)
+LOCKSTEP = ("entities", "samples", "lanes", "trips", "lane_iterations",
+            "lockstep_trials", "running_trials", "data_passes")
+
+
 class SolveResult(NamedTuple):
     """Solution + the states-tracker table.
 
@@ -70,6 +84,13 @@ class SolveResult(NamedTuple):
     # 1 - fg_count / (1 + ls_trials) is the share of evaluations that the
     # margins served
     ls_trials: "jax.Array | None" = None
+    # LBFGS/OWLQN under a batched per-entity solve only: the lock step's
+    # own count of what it ran.  The batched program's result holds int32
+    # [runs, len(LOCKSTEP)], one row a run with LOCKSTEP's columns, rows in
+    # bucket order (parallel/random_effect.py); inside one of its lanes,
+    # before the lanes are reduced, lbfgs's three scalars (optim/lbfgs.py
+    # `lane_axis`)
+    lockstep: "jax.Array | tuple | None" = None
 
     @property
     def converged(self) -> jax.Array:

@@ -113,7 +113,9 @@ def principal_subspace_projection(w: jax.Array,
 class FactoredSolveResult:
     latent_coefficients: jax.Array   # [E, k]
     projection: jax.Array            # [k, d]
-    random_effect_result: Optional[SolveResult]  # last inner iteration, [E]-leading
+    # last inner iteration, [E]-leading; its `lockstep` has a row for every
+    # latent run of every inner iteration
+    random_effect_result: Optional[SolveResult]
     latent_result: Optional[SolveResult]         # last inner iteration
 
 
@@ -254,6 +256,7 @@ def fit_factored_random_effects(
     C, P = latent_coefficients, projection
     re_res = lat_res = None
     as_tuple = cache_key if isinstance(cache_key, tuple) else (cache_key,)
+    counted = []    # every latent run's lock-step row, in the order run
     for it in range(num_inner_iterations):
         results, lane = [], 0
         for bucket in buckets:
@@ -269,12 +272,16 @@ def fit_factored_random_effects(
             lane += bucket.num_entities
         re_res = jax.tree_util.tree_map(
             lambda *a: concat_rows_safe(mesh, a, axis=0), *results)
+        counted.append(re_res.lockstep)
         C = re_res.x
         rw = latent_row_weights_fn(it) if latent_row_weights_fn else None
         P, lat_res = refit_latent_projection(
             rows, C, P, loss, mesh, latent_config, latent_reg,
             latent_reg_weight, row_weights=rw, budget=latent_budget,
             cache_key=cache_key)
+    if len(counted) > 1 and counted[0] is not None:
+        re_res = re_res._replace(
+            lockstep=concat_rows_safe(mesh, counted, axis=0))
     return FactoredSolveResult(latent_coefficients=C, projection=P,
                                random_effect_result=re_res,
                                latent_result=lat_res)

@@ -32,6 +32,7 @@ from jax.sharding import Mesh
 from photon_ml_tpu.ops import GLMObjective
 from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.optim import OptimizerConfig, RegularizationContext, SolveResult, solve
+from photon_ml_tpu.optim.types import LOCKSTEP
 from photon_ml_tpu.telemetry import annotate
 
 
@@ -101,18 +102,52 @@ def _cached_batched_solver(loss: PointwiseLoss, config: OptimizerConfig,
 
     The jitted function's name is the program's: the XLA module is
     `jit_re_bucket_solve`, which is how a profiler trace tells this layer's
-    device time from every other program's (see `_cached_solver`)."""
+    device time from every other program's (see `_cached_solver`).
+
+    An L-BFGS/OWLQN program also counts its own lock step: `lockstep` of
+    its result is one int32 row of LOCKSTEP's columns, reduced over the
+    lanes inside the program (`_count_lock_step`), so a reader fetches a
+    few scalars a run and no [E] array."""
 
     def solve_one(x, labels, mask, weights, offsets, x0_e, lam, budget):
         obj = GLMObjective(loss, x, labels, weights=weights, offsets=offsets,
                            mask=mask)
-        return solve(obj, x0_e, config, reg, lam, budget=budget)
+        return solve(obj, x0_e, config, reg, lam, budget=budget,
+                     lane_axis=_LANES)
 
-    re_bucket_solve = jax.vmap(
+    lanes = jax.vmap(
         solve_one, in_axes=(0, 0, 0, 0 if has_weights else None,
-                            0 if has_offsets else None, 0, None, None))
-    re_bucket_solve.__name__ = re_bucket_solve.__qualname__ = "re_bucket_solve"
+                            0 if has_offsets else None, 0, None, None),
+        axis_name=_LANES)
+
+    def re_bucket_solve(x, labels, mask, weights, offsets, x0, lam, budget):
+        res = lanes(x, labels, mask, weights, offsets, x0, lam, budget)
+        if res.lockstep is None:
+            return res
+        return res._replace(lockstep=_count_lock_step(res, mask))
+
     return jax.jit(re_bucket_solve, donate_argnums=(5,) if donate else ())
+
+
+#: the vmap axis of a batched per-entity solve (optim/lbfgs.py `lane_axis`)
+_LANES = "lanes"
+
+
+def _count_lock_step(res: SolveResult, mask: jax.Array) -> jax.Array:
+    """[1, len(LOCKSTEP)] int32: the run's row of lock-step counts from its
+    lanes' results.  A lane with no row (mesh padding) runs as every lane
+    does but is left out of `lanes` and `lane_iterations`; it ends at its
+    first trip with one trial, so it raises no maximum."""
+    real = jnp.any(mask > 0, axis=1)
+    E, S = mask.shape
+    ran, needed, passes = (jnp.max(lane) for lane in res.lockstep)
+    counts = dict(
+        entities=E, samples=S, lanes=jnp.sum(real),
+        trips=jnp.max(res.iterations),
+        lane_iterations=jnp.sum(jnp.where(real, res.iterations, 0)),
+        lockstep_trials=ran, running_trials=needed, data_passes=passes)
+    return jnp.stack([jnp.asarray(counts[k], jnp.int32)
+                      for k in LOCKSTEP])[None]
 
 
 def fit_random_effects(

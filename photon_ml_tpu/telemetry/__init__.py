@@ -41,8 +41,8 @@ package (PhaseTimings / `timings.clock()`), never raw
 """
 from photon_ml_tpu.telemetry.core import (  # noqa: F401
     MAX_RECORDS, NOOP_SPAN, SpanRecord, Tracer, active_tracer, annotate,
-    armed, current_span_id, enabled, event, install, last_tracer, pop, push,
-    retrace_count, set_observer, shutdown, span,
+    armed, current_span_id, enabled, event, install, last_tracer, mark, pop,
+    push, retrace_count, set_observer, shutdown, span,
 )
 from photon_ml_tpu.telemetry.export import (  # noqa: F401
     CHROME_REQUIRED_KEYS, chrome_trace_events, prometheus_text,

@@ -96,16 +96,12 @@ NOOP_SPAN = _NoopSpan()
 #: prefix of every annotation this program writes into a profiler trace
 ANNOTATION_PREFIX = "photon/"
 
-# jax.profiler.TraceAnnotation, looked up on the first annotate(); False
+# jax.profiler.TraceAnnotation, looked up on the first use; False
 # where JAX cannot be imported
 _TRACE_ANNOTATION = None
 
 
-def annotate(name: str):
-    """Context manager that puts `photon/<name>` on the host plane of a
-    JAX profiler trace, on the profiler's clock and so beside the device's
-    programs.  Always on: a TraceMe is a flag check while no profiler
-    session is active, and it reads host values only."""
+def _annotation_class():
     global _TRACE_ANNOTATION
     cls = _TRACE_ANNOTATION
     if cls is None:
@@ -114,7 +110,27 @@ def annotate(name: str):
         except ImportError:
             cls = False
         _TRACE_ANNOTATION = cls
+    return cls
+
+
+def annotate(name: str):
+    """Context manager that puts `photon/<name>` on the host plane of a
+    JAX profiler trace, on the profiler's clock and so beside the device's
+    programs.  Always on: a TraceMe is a flag check while no profiler
+    session is active, and it reads host values only."""
+    cls = _annotation_class()
     return cls(ANNOTATION_PREFIX + name) if cls else NOOP_SPAN
+
+
+def mark(name: str, **args) -> None:
+    """A zero-length `photon/<name>` event with `args` (host ints and
+    strings) as its arguments, on the host plane of the JAX profiler trace
+    being recorded: a count put on the profiler's clock where it was read.
+    A flag check while no profiler session is on."""
+    cls = _annotation_class()
+    if cls and cls.is_enabled():
+        with cls(ANNOTATION_PREFIX + name, **args):
+            pass
 
 
 class _Span:

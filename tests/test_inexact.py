@@ -279,9 +279,12 @@ def test_strict_vs_scheduled_final_parity_f64(rng):
 @pytest.mark.parametrize("per_user_reg", ["l2", "l1"])
 def test_solver_diagnostics_count_data_passes_and_trials(rng, per_user_reg):
     """What the device ran, per visit: `data_passes` full value+gradient
-    passes (max over the lock-step lanes) and the line search's trials.
-    On cached margins (L2) a pass count follows the iterations however the
-    search backtracks; under L1 every trial is a pass."""
+    passes and `ls_trials` the line search's trial values.  An entity
+    coordinate runs one lock step a bucket, one after the other: both are
+    sums over its runs, each run as long as its slowest lane and its search
+    as long as any lane's, ended lanes included.  On cached margins (L2) a
+    run's passes follow its trips however the search backtracks; under L1
+    every lock-step trial is a pass."""
     train, val = _glmix(rng)
     cfg = _convex_config(2)
     if per_user_reg == "l1":
@@ -295,15 +298,27 @@ def test_solver_diagnostics_count_data_passes_and_trials(rng, per_user_reg):
     for name in ("fixed", "perUser"):
         assert (len(diag[name]["data_passes"]) == len(diag[name]["ls_trials"])
                 == diag[name]["solves"] == 2)
+    assert len(diag["perUser"]["lockstep"]) == 2
+    assert "lockstep" not in diag["fixed"]
     for key, t in descent.trackers.items():
         if key.endswith("/fixed"):      # one lane, on margins
             assert t.data_passes == t.iterations + 2
             assert t.ls_trials >= t.iterations
-        elif per_user_reg == "l2":      # 25 lanes in lock step, on margins
-            assert 2 <= t.data_passes <= min(t.iterations, 100) + 2
-            assert t.ls_trials >= t.data_passes - 2
-        else:                           # every trial a fused value+gradient
-            assert t.ls_trials == t.data_passes - 1
+            assert t.lockstep is None
+            continue
+        runs = t.lockstep
+        assert t.data_passes == sum(runs["data_passes"])
+        assert t.ls_trials == sum(runs["lockstep_trials"])
+        assert sum(runs["lane_iterations"]) == t.iterations
+        assert sum(runs["lanes"]) == 25
+        for trips, ran, needed, passes in zip(
+                runs["trips"], runs["lockstep_trials"],
+                runs["running_trials"], runs["data_passes"]):
+            assert trips <= needed <= ran
+            if per_user_reg == "l2":
+                assert passes == trips + 2
+            else:
+                assert passes == ran + 1
 
 
 def test_scheduled_resume_reproduces_trajectory(rng, tmp_path):

@@ -392,7 +392,10 @@ def test_fixed_effect_solve_shards_over_four_chips(topo, one_chip):
 def test_random_effect_bucket_solve_shards_over_four_chips(topo):
     """The other program of the --multichip fit: one S-bucket's vmapped
     solves with the ENTITY axis sharded over the data=4 mesh.  Entities are
-    independent, so the program needs no collective at all."""
+    independent, so the program moves no lane's data between chips: its
+    collectives are scalars over the data axis (the loops' predicates and
+    the lock step's own count, PR 37) and the run's row of counts."""
+    from photon_ml_tpu.optim.types import LOCKSTEP
     from photon_ml_tpu.parallel.random_effect import _cached_batched_solver
     mesh = Mesh(np.asarray(topo.devices).reshape(4, 1),
                 (DATA_AXIS, FEATURE_AXIS))
@@ -410,5 +413,7 @@ def test_random_effect_bucket_solve_shards_over_four_chips(topo):
     _assert_float32(compiled)
     assert _fits(compiled) <= 0.3 * (E * S * (d + 4) + E * d) * 4
     summary = collective_summary(compiled.as_text(), mesh)
-    assert not any(e for lane in summary.values() for e in lane
-                   if e[0] >= 1), summary
+    assert not (summary["feature"] or summary["global"]
+                or summary["other"]), summary
+    assert max(nbytes for _, nbytes in summary["data"]) <= 4 * len(
+        LOCKSTEP), summary
