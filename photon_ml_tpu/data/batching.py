@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.data.game_data import GameDataset
+from photon_ml_tpu.ops import features as fops
 from photon_ml_tpu.parallel.random_effect import EntityBlocks
 from photon_ml_tpu.utils.math import ceil_pow2 as _ceil_pow2
 
@@ -97,12 +98,50 @@ class FixedEffectDataset:
             feature_shard=config.feature_shard)
 
 
-@functools.partial(jax.jit, static_argnames=("dtype",))
-def _gather_flat_offsets(flat, safe_ids, mask, dtype):
-    """Canonical-order offsets -> [Eb, Sb] block layout, one fused program
-    (addScoresToOffsets runs per bucket per coordinate update; op-by-op it
-    would be several dispatches and compiled programs per shape)."""
-    return (flat[safe_ids] * mask).astype(dtype)
+#: the chip's (sublane, lane) tile of a float32 array
+_TILE = (8, 128)
+
+
+def _tiled(entities: int, samples: int) -> Tuple[int, int]:
+    """A bucket's `[S, E]` transpose padded to whole tiles: (S, E) rounded
+    up to multiples of `_TILE`."""
+    return (-(-samples // _TILE[0]) * _TILE[0],
+            -(-entities // _TILE[1]) * _TILE[1])
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "shapes", "interpret"))
+def _gather_flat_offsets(flat, ids, masks, *, dtype, shapes=None,
+                         interpret=False):
+    """Canonical-order offsets -> every bucket's [Eb, Sb] block layout, ONE
+    program a coordinate visit (addScoresToOffsets runs per bucket per
+    coordinate update; op-by-op, or a program a bucket, it would be several
+    dispatches, and a program a bucket shape to trace and lower).
+
+    Without `shapes`, XLA's element gather a bucket, `flat[safe_ids] *
+    mask` (`ids` and `masks` one a bucket).  With `shapes` (the buckets'
+    (Eb, Sb)), `ids` is the visit's one index stream
+    (`RandomEffectDataset.offsets_stream`), which `fops.vmem_take` reads
+    from the offsets held in VMEM, and the blocks are cut from its cells:
+    a padded cell reads the zero past the table's end where the mask would
+    make `flat[0] * 0`, so the two differ at most in the sign of a zero in
+    cells that train nothing.  The stream holds a bucket as the tiles of
+    its `[S, E]` transpose, so a block is a slice of whole rows of 128
+    cells that the compiler lays out by a bitcast (cut from cells in row
+    order, it compiled to 3.6 MB of element shuffles on a TPU v5e)."""
+    if shapes is None:
+        return tuple((flat[i] * m).astype(dtype) for i, m in zip(ids, masks))
+    rows = fops.vmem_take(flat, ids, interpret=interpret).reshape(
+        -1, _TILE[1])
+    blocks, start = [], 0
+    for entities, samples in shapes:
+        s_pad, e_pad = _tiled(entities, samples)
+        end = start + s_pad * e_pad // _TILE[1]
+        tiles = rows[start:end].reshape(s_pad // _TILE[0], e_pad // _TILE[1],
+                                        *_TILE)
+        blocks.append(tiles.transpose(0, 2, 1, 3).reshape(s_pad, e_pad)
+                      .T[:entities, :samples].astype(dtype))
+        start = end
+    return tuple(blocks)
 
 
 @dataclasses.dataclass
@@ -200,18 +239,12 @@ class EntityBucket:
         return total
 
     def safe_ids_dev(self) -> jnp.ndarray:
-        """Device copy of clamped row ids, transferred once per bucket."""
+        """Device copy of clamped row ids, transferred once per bucket
+        (XLA's form of the offsets gather, and the vectorized sweep)."""
         if self._safe_ids_dev is None:
             self._safe_ids_dev = jnp.asarray(
                 np.maximum(self.row_ids, 0).astype(np.int32))
         return self._safe_ids_dev
-
-    def with_offsets_from_flat(self, flat_offsets) -> EntityBlocks:
-        blocks = self.blocks
-        off = _gather_flat_offsets(jnp.asarray(flat_offsets),
-                                   self.safe_ids_dev(), blocks.mask,
-                                   jnp.dtype(blocks.x.dtype).name)
-        return blocks.with_offsets(off)
 
 
 @dataclasses.dataclass
@@ -264,6 +297,8 @@ class RandomEffectDataset:
                                                   compare=False)
     _flat_lanes_dev: object = dataclasses.field(default=None, repr=False,
                                                 compare=False)
+    _offsets_stream_dev: object = dataclasses.field(default=None, repr=False,
+                                                    compare=False)
     _global_blocks: Optional[EntityBlocks] = dataclasses.field(
         default=None, repr=False, compare=False)
     _global_row_ids: Optional[np.ndarray] = dataclasses.field(
@@ -346,6 +381,68 @@ class RandomEffectDataset:
             self._flat_lanes_dev = jnp.asarray(self.flat_entity_lanes(
                 dataset.entity_indices[self.config.random_effect_type]))
         return self._flat_lanes_dev
+
+    def vmem_offsets(self, num_rows: int, one_device: bool) -> bool:
+        """Whether a coordinate on this build gathers its offsets from a
+        table held in VMEM (`fops.vmem_take`): where its arrays land on a
+        TPU, on `one_device`, the blocks are float32, the `num_rows` flat
+        offsets fit `fops.VMEM_TABLE_BYTES`, and the blocks stay resident
+        (no host copies kept for an HBM budget's evictions).  Anything else
+        runs XLA's element gather.  Decided once, at the coordinate's
+        build, from what it can see; no option chooses it."""
+        return (one_device and not self.config.keep_host_blocks
+                and fops._on_tpu() and fops.vmem_take_fits(num_rows,
+                                                           self.dtype))
+
+    def offsets_stream(self, num_rows: int) -> jnp.ndarray:
+        """[L] int32 device vector, the index stream `fops.vmem_take` reads
+        for the offsets gather: bucket after bucket in lane order, the
+        canonical row id of each cell, as the `[8, 128]` tiles of the
+        bucket's `[S, E]` transpose padded to whole tiles (`_tiled`), tile
+        rows in order; `num_rows` (the zero past the table's end) for a
+        padded cell, a tile's padding and the tail to whole grid steps
+        (`fops.vmem_take_cells`).  It depends on the build alone: made at
+        its first use and kept with the memoised build, as
+        `flat_train_lanes` is."""
+        if self._offsets_stream_dev is None:
+            parts = []
+            for b in self.buckets:
+                s_pad, e_pad = _tiled(b.num_entities, b.samples_per_entity)
+                ids = np.full((e_pad, s_pad), num_rows, np.int32)
+                ids[:b.num_entities, :b.samples_per_entity] = np.where(
+                    b.row_ids >= 0, b.row_ids, num_rows)
+                parts.append(ids.T.reshape(
+                    s_pad // _TILE[0], _TILE[0], e_pad // _TILE[1], _TILE[1])
+                    .transpose(0, 2, 1, 3).reshape(-1))
+            cells = sum(len(p) for p in parts)
+            parts.append(np.full(fops.vmem_take_cells(cells) - cells,
+                                 num_rows, np.int32))
+            self._offsets_stream_dev = jnp.asarray(np.concatenate(parts))
+        return self._offsets_stream_dev
+
+    def blocks_with_offsets(self, flat_offsets,
+                            vmem: bool = False) -> List[EntityBlocks]:
+        """Every bucket's device blocks with its cells' offsets taken from
+        `flat_offsets` ([n], canonical row order; 0 in a padded cell), by
+        ONE `_gather_flat_offsets` program: `fops.vmem_take` over
+        `offsets_stream` where `vmem` (the coordinate's `vmem_offsets`)
+        holds and the vector is float32 on one device, else XLA's element
+        gather a bucket."""
+        flat = jnp.asarray(flat_offsets)
+        blocks = [b.blocks for b in self.buckets]
+        dtype = jnp.dtype(blocks[0].x.dtype).name
+        if (vmem and flat.dtype == jnp.float32
+                and len(flat.sharding.device_set) == 1):
+            offsets = _gather_flat_offsets(
+                flat, self.offsets_stream(flat.shape[0]), None, dtype=dtype,
+                shapes=tuple((b.num_entities, b.samples_per_entity)
+                             for b in self.buckets),
+                interpret=not fops._on_tpu())
+        else:
+            offsets = _gather_flat_offsets(
+                flat, tuple(b.safe_ids_dev() for b in self.buckets),
+                tuple(blk.mask for blk in blocks), dtype=dtype)
+        return [blk.with_offsets(off) for blk, off in zip(blocks, offsets)]
 
     def scatter_to_global(self, local_coefficients) -> jnp.ndarray:
         """[E, d_local] local-space coefficients -> [E, d_global]

@@ -583,9 +583,15 @@ class _EntityCoordinateBase:
         self.red: RandomEffectDataset = build_random_effect_dataset(
             dataset, config.data_config(
                 seed, keep_host_blocks=hbm_budget_bytes is not None))
+        # whether a visit gathers its offsets from the flat scores held in
+        # VMEM (the rule: `RandomEffectDataset.vmem_offsets`)
+        self.vmem_offsets = self.red.vmem_offsets(
+            dataset.num_rows, one_device=mesh is None or mesh.size == 1)
         # what the build did with the rows, under the coordinate's name:
-        # gauges for telemetry.snapshot(), the dict for the fit's summary
-        self.build_stats = self.red.build_counts
+        # gauges for telemetry.snapshot(), the dict for the fit's summary;
+        # `vmem_offsets` counts the gathers a visit that run the kernel
+        self.build_stats = dict(self.red.build_counts,
+                                vmem_offsets=int(self.vmem_offsets))
         for key, value in self.build_stats.items():
             if key != "buckets":
                 gauge(f"train.re_build.{name}.{key}").set(value)
@@ -714,9 +720,10 @@ class RandomEffectCoordinate(_EntityCoordinateBase):
                num_outer_iterations: int = 1
                ) -> Tuple[RandomEffectModel, SolveResult]:
         """reference: RandomEffectCoordinate.updateModel — the 3-way join +
-        per-entity local solves become one gather + one batched solve per
-        S-bucket (each size class runs its own compiled program; lanes are
-        contiguous so results concatenate straight back into [E, d]).
+        per-entity local solves become one gather of every bucket's offsets
+        + one batched solve per S-bucket (each size class runs its own
+        compiled program; lanes are contiguous so results concatenate
+        straight back into [E, d]).
 
         EVERY bucket's solve is dispatched before any result is touched —
         the concatenate below consumes nothing until all size classes are
@@ -728,9 +735,10 @@ class RandomEffectCoordinate(_EntityCoordinateBase):
         budget = (None if schedule is None else schedule.budget_for(
             outer_iteration, num_outer_iterations, opt.optimizer))
         results = []
-        for bucket in self.red.buckets:
-            with annotate("re/offsets"):
-                blocks = bucket.with_offsets_from_flat(offsets)
+        with annotate("re/offsets"):
+            bucket_blocks = self.red.blocks_with_offsets(offsets,
+                                                         self.vmem_offsets)
+        for bucket, blocks in zip(self.red.buckets, bucket_blocks):
             with annotate("re/x0"):
                 lo = bucket.lane_start
                 x0 = model.coefficients[lo: lo + bucket.num_entities]
@@ -789,7 +797,7 @@ class FactoredRandomEffectCoordinate(_EntityCoordinateBase):
         cells = red.build_counts["cells"]
         itemsize = jnp.dtype(jax.dtypes.canonicalize_dtype(red.dtype)).itemsize
         k = config.latent_dim
-        self.build_stats = dict(red.build_counts, mf_build={
+        self.build_stats = dict(self.build_stats, mf_build={
             "entities": red.num_entities, "samples": red.max_samples,
             "cells": cells, "rows": rows, "real_rows": red.num_active,
             "padded_cells": cells + rows - 2 * red.num_active,
@@ -886,10 +894,8 @@ class FactoredRandomEffectCoordinate(_EntityCoordinateBase):
         # train at their block weights, the others at 0), under the
         # descent's own offsets: no single-S view of all entities, no second
         # copy of the features, no gather of the offsets for the refit
-        blocks = []
-        for bucket in self.red.buckets:
-            with annotate("re/offsets"):
-                blocks.append(bucket.with_offsets_from_flat(offsets))
+        with annotate("re/offsets"):
+            blocks = self.red.blocks_with_offsets(offsets, self.vmem_offsets)
         rows = ProjectionRows(
             x=self.flat_x, labels=self.labels, lanes=self.lanes,
             weights=self.red.flat_active_weights(self._dataset),

@@ -568,6 +568,99 @@ def _vmem_segment_sums_program(idx, val, table, *, width: int,
     return out.reshape(-1)
 
 
+# The VMEM table gather of single elements (`vmem_take`): cells a grid step,
+# and cells a trip of its loop, one lane-dense output row
+_VT_BLOCK = 8192
+_VT_TRIP = _LANES
+
+
+def vmem_take_fits(m: int, dtype) -> bool:
+    """Whether `vmem_take` can hold a table of `m` entries of `dtype`:
+    float32, and the table with the zero entry past its end within
+    `VMEM_TABLE_BYTES` as whole rows of 128 lanes."""
+    import numpy as np
+    return (np.dtype(dtype) == np.float32
+            and (m // _LANES + 1) * _LANES * 4 <= VMEM_TABLE_BYTES)
+
+
+def vmem_take_cells(cells: int) -> int:
+    """The length of an index stream `vmem_take` reads for `cells` cells:
+    whole grid steps."""
+    return -(-cells // _VT_BLOCK) * _VT_BLOCK
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def vmem_take(table: jax.Array, idx: jax.Array, *,
+              interpret: bool) -> jax.Array:
+    """out[c] = table[idx[c]], float32; an index of `len(table)` reads 0.
+
+    A gather of single elements (an entity coordinate's offsets put into
+    bucket layout), in the form of `_vmem_segment_sums_program`, whose
+    lane partials summed on the MXU would cost every element what a
+    segment of 8-39 shares:
+    the table is whole in VMEM as `[m // 128 + 1, 128]` (zero past its
+    end), `idx` is a flat stream in SMEM blocks of `_VT_BLOCK` cells
+    (`vmem_take_cells` long), an element is a scalar index, an ordinary
+    vector load of one `(1, 128)` row at a dynamic sublane offset and its
+    lane `i & 127` kept by a compare.  No product places the elements: the
+    kept rows of a trip's 128 cells fill a `[128, 128]` tile, summed over
+    lanes (one non-zero a row) and transposed into the trip's output row,
+    lane-dense.  On a TPU v5e (streams of 3.3-9.5 M cells over 7.6 M rows,
+    PERF.md section 6) 2.55 ns a cell, where XLA's gather takes 6.66 and the
+    other placements 2.65 (the tile transposed, then summed over
+    sublanes), 2.78 (a float32 product with ones on the MXU) and 2.92 (a
+    lane roll a cell), and the fetch alone, placed nowhere, 1.43; the grid
+    step's length (1,024 to 32,768 cells) moves nothing.  Interpreted off
+    the TPU."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    cells = idx.shape[0]
+    assert cells % _VT_BLOCK == 0, cells
+    m = table.shape[0]
+    height = m // _LANES + 1
+    tab = jnp.pad(table, (0, height * _LANES - m)).reshape(height, _LANES)
+    i32 = jnp.int32
+
+    def kernel(idx_ref, tab_ref, out_ref, part_ref):
+        # the trip's 128 cells are traced one by one: in `lax` terms, whose
+        # binds cost a fraction of `jnp`'s, since every process traces and
+        # lowers the kernel anew for each coordinate (no cache saves that)
+        lane = lax.broadcasted_iota(i32, (1, _LANES), 1)
+        lane_bits = jnp.full((1, _LANES), _LANES - 1, i32)
+        zero = jnp.zeros((1, _LANES), jnp.float32)
+
+        def trip(t, carry):
+            base = lax.mul(t, i32(_VT_TRIP))
+            for j in range(_VT_TRIP):
+                i = idx_ref[lax.add(base, i32(j))]
+                row = tab_ref[pl.ds(lax.shift_right_arithmetic(i, i32(7)), 1),
+                              :]
+                hit = lax.eq(lane, lax.bitwise_and(
+                    lax.broadcast(i, (1, _LANES)), lane_bits))
+                part_ref[j:j + 1, :] = lax.select(hit, row, zero)
+            out_ref[pl.ds(t, 1), :] = jnp.sum(part_ref[...], axis=1,
+                                              keepdims=True).T
+            return carry
+
+        lax.fori_loop(i32(0), i32(_VT_BLOCK // _VT_TRIP), trip, i32(0))
+
+    rows = _VT_BLOCK // _LANES
+    out = pl.pallas_call(
+        kernel, grid=(cells // _VT_BLOCK,),
+        in_specs=[pl.BlockSpec((_VT_BLOCK,), lambda b: (b,),
+                               memory_space=pltpu.SMEM),
+                  pl.BlockSpec((height, _LANES), lambda b: (0, 0))],
+        out_specs=pl.BlockSpec((rows, _LANES), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((cells // _LANES, _LANES),
+                                       jnp.float32),
+        scratch_shapes=[pltpu.VMEM((_VT_TRIP, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * height * _LANES * 4 + _VMEM_HEADROOM_BYTES),
+        interpret=interpret, name="vmem_take")(idx, tab)
+    return out.reshape(-1)
+
+
 _CSC_CHUNK = 1 << 16
 
 

@@ -398,10 +398,16 @@ def test_every_solve_of_the_factored_update_is_called_in_its_span(
             if inside(call, mf_solves)]
     assert len(mine) == 2 * 4       # four S-buckets a visit
     assert all(inside(call, re_spans) == 1 for call in mine)
-    for name in ("photon/re/offsets", "photon/re/x0",
-                 "photon/re/solve_call"):
+    for name in ("photon/re/x0", "photon/re/solve_call"):
         assert sum(inside(span, mf_solves) for span in named(name)) == 8, \
             name
+    # the offsets of all four buckets: one gather, one span, a visit
+    offsets = named("photon/re/offsets")
+    assert sum(inside(span, mf_solves) for span in offsets) == 2
+    gathers = [call for call in calls("_gather_flat_offsets")
+               if inside(call, mf_solves)]
+    assert len(gathers) == 2
+    assert all(inside(call, offsets) == 1 for call in gathers)
 
 
 def test_one_lane_solve_seconds_are_split_by_the_span_the_call_was_made_in():

@@ -320,11 +320,13 @@ def test_cli_train_gives_the_estimators_model(float64_fit, tmp_path):
             want[name].global_coefficients(), 1e-6), name
     with open(os.path.join(out_dir, "training-summary.json")) as f:
         summary = json.load(f)
-    assert summary["coordinate_build"]["perItem"] == \
-        blocks_of(ds, cfg, "perItem").build_counts
+    # with the coordinate's own count: on the CPU no gather runs the kernel
+    assert summary["coordinate_build"]["perItem"] == dict(
+        blocks_of(ds, cfg, "perItem").build_counts, vmem_offsets=0)
     gauges = summary["telemetry"]["metrics"]["gauges"]
-    assert gauges["train.re_build.perItem.passive_rows"] == \
-        summary["coordinate_build"]["perItem"]["passive_rows"]
+    for key in ("passive_rows", "vmem_offsets"):
+        assert gauges[f"train.re_build.perItem.{key}"] == \
+            summary["coordinate_build"]["perItem"][key]
 
 
 def test_build_counters_equal_a_direct_count():

@@ -184,8 +184,7 @@ def test_offsets_from_flat(rng):
         ds, RandomEffectDataConfig("per_user", "g" if "g" in ds.feature_shards else "global",
                                    projector="identity"))
     flat = rng.normal(size=ds.num_rows)
-    for bucket in red.buckets:
-        blocks = bucket.with_offsets_from_flat(flat)
+    for bucket, blocks in zip(red.buckets, red.blocks_with_offsets(flat)):
         for e in range(bucket.num_entities):
             for s in range(blocks.samples_per_entity):
                 r = bucket.row_ids[e, s]

@@ -226,6 +226,53 @@ def test_sparse_fixed_effect_solve_compiles(one_chip, monkeypatch, shape,
     _fits(compiled)
 
 
+#: the factored coordinate's per-user buckets in game-ml20m-mf (cap 256)
+ML20M_MF_BUCKETS = [(13_484, 256), (8_999, 160), (13_505, 96), (19_390, 48)]
+ML20M_ROWS = 7_600_100
+
+
+@pytest.mark.parametrize("rows,buckets,kernel", [
+    (ML20M_ROWS, [(e, s) for e, s, _ in ML20M_USER_BUCKETS], True),
+    (ML20M_ROWS, ML20M_MF_BUCKETS, True),
+    (20_000_263, [(e, s) for e, s, _ in ML20M_USER_BUCKETS], False)],
+    ids=["perUser-9527528", "perUserMF-7118944", "table-over-budget"])
+def test_offsets_gather_compiles_at_the_cells_size(one_chip, rows, buckets,
+                                                   kernel):
+    """`jit__gather_flat_offsets`, a coordinate visit's one gather of every
+    bucket's offsets (data/batching.py): where the flat offsets fit
+    `VMEM_TABLE_BYTES` it is the VMEM table gather (`ops/features.py::
+    vmem_take`, a Mosaic kernel) over the visit's one index stream, within
+    the chip's fast memory and float32; the whole 20 M-row corpus's offsets
+    (80 MB) do not fit, and get XLA's element gather a bucket."""
+    from photon_ml_tpu.data.batching import _gather_flat_offsets, _tiled
+    from photon_ml_tpu.ops import features as fops
+    assert fops.vmem_take_fits(rows, np.float32) == kernel
+    flat = _sds((rows,), F32, one_chip)
+    if kernel:
+        cells = fops.vmem_take_cells(sum(math.prod(_tiled(e, s))
+                                         for e, s in buckets))
+        assert (rows // 128 + 1) * 128 * 4 * 2 + fops._VMEM_HEADROOM_BYTES \
+            <= 128 * 1024 ** 2
+        lowered = _gather_flat_offsets.lower(
+            flat, _sds((cells,), jnp.int32, one_chip), None, dtype="float32",
+            shapes=tuple(buckets), interpret=False)
+    else:
+        lowered = _gather_flat_offsets.lower(
+            flat, tuple(_sds(b, jnp.int32, one_chip) for b in buckets),
+            tuple(_sds(b, F32, one_chip) for b in buckets), dtype="float32")
+    compiled = lowered.compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == kernel
+    assert "jit__gather_flat_offsets" in lowered.as_text()
+    _assert_float32(compiled)
+    _fits(compiled)
+    if kernel:
+        # the blocks are cut from the stream by slices and bitcasts: cut
+        # from cells in row order they were 3.6 MB of element shuffles,
+        # some 6 s of compile in every process
+        assert compiled.memory_analysis().generated_code_size_in_bytes \
+            < 1024 ** 2
+
+
 def _bucket_solve(one_chip, E, S, d, config):
     """`jit_re_bucket_solve` (parallel/random_effect._cached_batched_solver)
     compiled for one E x S x d bucket with weights and offsets, as the
